@@ -166,6 +166,14 @@ def tiled_propagate(step_fn: Callable, nt: int, T: int, state,
 # Trapezoidal VMEM time-tiling cost model (DESIGN.md §2)
 # ---------------------------------------------------------------------------
 
+# Scoped-VMEM budget of one TB kernel call on a TPU v5e (128 MiB physical
+# VMEM per core); the autotuner plans against it and the kernel hands it to
+# Mosaic as `vmem_limit_bytes`, so a plan that fits here compiles there.
+VMEM_BUDGET = 96 * 2 ** 20
+# x-planes the kernel's in-VMEM step advances per slab (`stencil_tb`)
+SLAB = 8
+
+
 @dataclasses.dataclass(frozen=True)
 class TBPlan:
     """A (tile_x, tile_y, T) choice for the Pallas TB kernel."""
@@ -216,6 +224,24 @@ class TBPlan:
         take the count from `PHYSICS_COSTS[physics].fields`."""
         wx, wy, wz = self.window(nz)
         return wx * wy * wz * dtype_bytes * fields
+
+    def kernel_vmem_bytes(self, nz: int, fields: int, out_fields: int,
+                          slab_fields: int, dtype_bytes: int = 4) -> int:
+        """Scoped VMEM the Pallas kernel (`stencil_tb._tb_kernel`) needs
+        for one tile: `fields` resident windows, the double-buffered
+        centre blocks of the `out_fields` written back, and the live
+        temporaries of one x-slab update (`SLAB` + 2*radius planes),
+        budgeted at one slab-sized array per field it reads
+        (`slab_fields`) plus three.  That last term bounds what Mosaic
+        allocated for the three physics at 512^3 on a v5e: about 4.4
+        slab-sized arrays for acoustic, 12.5 for TTI, 9.4 for elastic."""
+        wx, wy, _ = self.window(nz)
+        wy = -(-wy // 8) * 8            # the kernel's window is 8-row aligned
+        tx, ty = self.tile
+        slab = min(SLAB + 2 * self.radius, wx)
+        planes = (fields * wx * wy + 2 * out_fields * tx * ty
+                  + (slab_fields + 3) * slab * wy)
+        return planes * nz * dtype_bytes
 
     def hbm_bytes_per_point_step(self, nz: int, read_fields: int = 4,
                                  write_fields: int = 1,
@@ -334,11 +360,11 @@ class SweepLog(dict):
     best_key = None
 
 
-def autotune_plan(nz: int, radius: int, vmem_budget: int = 96 * 2 ** 20,
+def autotune_plan(nz: int, radius: int, vmem_budget: int = VMEM_BUDGET,
                   tiles=(16, 32, 64, 128, 256), depths=(1, 2, 4, 8, 16),
-                  fields: int = 5, dtype_bytes: int = 4,
+                  fields: int = 6, dtype_bytes: int = 4,
                   flops_per_point: float = 40.0,
-                  read_fields: int = None, write_fields: int = None,
+                  read_fields: int = 4, write_fields: int = 1,
                   peak_flops: float = 197e12, hbm_bw: float = 819e9,
                   mesh_block: Tuple[int, int] = None,
                   link_bw: float = 45e9, link_latency: float = 1.5e-6,
@@ -399,8 +425,6 @@ def autotune_plan(nz: int, radius: int, vmem_budget: int = 96 * 2 ** 20,
     shallower strip.  `link_bw`/`link_latency` default to one ICI link
     (~45 GB/s).
     """
-    read_fields = fields - 1 if read_fields is None else read_fields
-    write_fields = 1 if write_fields is None else write_fields
     exchange_fields = (write_fields if exchange_fields is None
                        else exchange_fields)
     if outer_depths is not None and mesh_block is None:
@@ -411,7 +435,10 @@ def autotune_plan(nz: int, radius: int, vmem_budget: int = 96 * 2 ** 20,
         for ty in tiles:
             for T in depths:
                 plan = TBPlan((tx, ty), T, radius)
-                vmem = plan.vmem_bytes(nz, fields, dtype_bytes)
+                # (+1 window: the sharded layer's domain mask)
+                vmem = plan.kernel_vmem_bytes(
+                    nz, fields + (mesh_block is not None), write_fields,
+                    read_fields, dtype_bytes)
                 if vmem > vmem_budget:
                     continue
                 if mesh_block is not None and (
@@ -536,10 +563,11 @@ class PhysicsCost:
 
     @property
     def fields(self) -> int:
-        """VMEM-resident windows: every state+param field plus one scratch
-        (the acoustic value 5 = u0, u1, m, damp, scratch is the historical
-        default of `autotune_plan`)."""
-        return self.state_fields + self.param_fields + 1
+        """VMEM-resident windows of the kernel (`stencil_tb._tb_kernel`):
+        two sets of state windows (its step loop ping-pongs between them)
+        plus one per param field — the acoustic 6 = 2 x (u_prev, u) + m,
+        damp is the default of `autotune_plan`."""
+        return 2 * self.state_fields + self.param_fields
 
     @property
     def read_fields(self) -> int:

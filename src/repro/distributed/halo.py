@@ -80,11 +80,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.38 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 import numpy as np
 
 from repro.core import sources as src_mod
@@ -96,16 +91,10 @@ from repro.kernels import tb_physics as phys
 from repro.telemetry import spans as _spans
 
 
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.4.38
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # classic static-size idiom
-
-
 def _shift_from_low(x, h: int, axis_name: str, dim: int):
     """Every device sends its LAST h slices to the next device (axis order);
     device 0's halo comes back as zeros (Dirichlet)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     sl = [slice(None)] * x.ndim
     sl[dim] = slice(x.shape[dim] - h, None)
     piece = x[tuple(sl)]
@@ -116,7 +105,7 @@ def _shift_from_low(x, h: int, axis_name: str, dim: int):
 
 
 def _shift_from_high(x, h: int, axis_name: str, dim: int):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     sl = [slice(None)] * x.ndim
     sl[dim] = slice(0, h)
     piece = x[tuple(sl)]
@@ -697,7 +686,7 @@ def _depth_setup(plan: DistTBPlan, T_depth: int,
             # one), no ppermute at all
             d = prepped[2] - h
 
-            @functools.partial(_shard_map, mesh=plan.mesh,
+            @functools.partial(jax.shard_map, mesh=plan.mesh,
                                in_specs=(spec3,) * (npar + 1),
                                out_specs=(spec3,) * (npar + 1))
             def reslice(*ps):
@@ -708,7 +697,7 @@ def _depth_setup(plan: DistTBPlan, T_depth: int,
             resliced = reslice(*prepped[0], prepped[1])
             param_pads, dom_pad = resliced[:npar], resliced[npar]
         else:
-            @functools.partial(_shard_map, mesh=plan.mesh,
+            @functools.partial(jax.shard_map, mesh=plan.mesh,
                                in_specs=(spec3,) * npar,
                                out_specs=(spec3,) * (npar + 1))
             def prepare(*ps):
@@ -748,10 +737,10 @@ def _depth_setup(plan: DistTBPlan, T_depth: int,
         return jnp.transpose(sv, tuple(range(1, ndim - 1)) + (0, ndim - 1)
                              ).astype(dtype)
 
-    # check_rep=False: the replication checker has no rule for pallas_call
+    # check_vma=False: the varying-axes checker has no rule for pallas_call
     # (the inner="pallas" path); every output is explicitly sharded anyway.
-    @functools.partial(_shard_map, mesh=plan.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=plan.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def tile(*args):
         sblocks = args[:ns]
         ppads = args[ns:ns + npar]
@@ -825,7 +814,7 @@ def sharded_tb_propagate(plan: DistTBPlan, nt: int,
                          params: Dict[str, jnp.ndarray],
                          g: Optional[src_mod.GriddedSources] = None,
                          receivers: Optional[src_mod.GriddedReceivers] = None,
-                         *, interpret: bool = True):
+                         *, interpret: Optional[bool] = None):
     """Temporally-blocked sharded propagation of any registered physics.
 
     Semantics identical to the matching `kernels.ref.*_reference` (tested):

@@ -296,7 +296,7 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
                           param_pads, rparam_pads,
                           src_dcmp: jnp.ndarray, src_tab, rec_tab,
                           rsrc_tab, rrec_tab, nrec: int,
-                          interpret: bool = True,
+                          interpret: Optional[bool] = None,
                           executor: str = "pallas"):
     """The traced core of `_tb_propagate`: scan over depth-T time tiles
     plus the shallower `nt % T` remainder tile, AFTER all host-side table
@@ -354,7 +354,7 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
                   receivers: Optional[src_mod.GriddedReceivers],
                   plan: TBPlan, order: int, dt,
                   spacing: Tuple[float, float, float],
-                  interpret: bool = True, executor: str = "pallas"):
+                  interpret: Optional[bool] = None, executor: str = "pallas"):
     """Propagate nt timesteps of `physics` with the temporally-blocked kernel.
 
     Semantics identical to the reference propagator in `core/propagators/`
@@ -400,10 +400,14 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
         rem = nt % spec.T
         rspec = rsrc_tab = rrec_tab = rparam_pads = None
         if rem > 0:
-            rspec = specced(src_cap, rec_cap, T=rem)
             # remainder tables must be rebuilt: halo depth changes with T
-            rsrc_tab, rrec_tab = build_tables(rspec, g, receivers, params,
-                                              physics)
+            # (and with it the entries per tile, so caps as well)
+            rsrc_tab, rrec_tab = build_tables(specced(1, 1, T=rem), g,
+                                              receivers, params, physics)
+            rspec = specced(
+                rsrc_tab.cap if rsrc_tab is not None else 1,
+                rrec_tab.coords.shape[1] if rrec_tab is not None else 1,
+                T=rem)
             rparam_pads = tuple(_pad_xy(params[f], rspec.halo, "edge")
                                 for f in physics.param_fields)
 
@@ -428,7 +432,7 @@ def acoustic_tb_propagate(nt: int, u0, u1, m, damp,
                           receivers: Optional[src_mod.GriddedReceivers],
                           plan: TBPlan, order: int, dt,
                           spacing: Tuple[float, float, float],
-                          interpret: bool = True,
+                          interpret: Optional[bool] = None,
                           executor: str = "pallas"):
     """Acoustic TB propagation.  Returns ((u_prev, u), rec (nt, nrec) | None).
 
@@ -444,7 +448,8 @@ def acoustic_tb_propagate(nt: int, u0, u1, m, damp,
 def tti_tb_propagate(nt: int, state, params, g, receivers,
                      plan: TBPlan, order: int, dt,
                      spacing: Tuple[float, float, float],
-                     interpret: bool = True, executor: str = "pallas"):
+                     interpret: Optional[bool] = None,
+                     executor: str = "pallas"):
     """TTI TB propagation.
 
     `state` is a `propagators.tti.TTIState`; `params` a `TTIParams`.
@@ -464,7 +469,7 @@ def tti_tb_propagate(nt: int, state, params, g, receivers,
 def elastic_tb_propagate(nt: int, state, params, g, receivers,
                          plan: TBPlan, order: int, dt,
                          spacing: Tuple[float, float, float],
-                         interpret: bool = True,
+                         interpret: Optional[bool] = None,
                          executor: str = "pallas"):
     """Elastic TB propagation.
 
@@ -484,7 +489,7 @@ def elastic_tb_propagate(nt: int, state, params, g, receivers,
 
 def acoustic_sb_propagate(nt: int, u0, u1, m, damp, g, receivers,
                           tile: Tuple[int, int], order: int, dt,
-                          spacing, interpret: bool = True):
+                          spacing, interpret: Optional[bool] = None):
     """The paper's baseline: spatially-blocked only (T = 1)."""
     plan = TBPlan(tile=tile, T=1, radius=order // 2)
     return acoustic_tb_propagate(nt, u0, u1, m, damp, g, receivers, plan,

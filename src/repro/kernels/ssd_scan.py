@@ -23,11 +23,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,7 +85,8 @@ def _ssd_kernel(spec: SSDSpec, x_ref, dt_ref, b_ref, c_ref, a_ref,
     hout_ref[0, 0, :, :] = h_scr[...].astype(jnp.float32)
 
 
-def ssd_scan(spec: SSDSpec, x, dtv, Bm, Cm, A, *, interpret: bool = True):
+def ssd_scan(spec: SSDSpec, x, dtv, Bm, Cm, A, *,
+             interpret: Optional[bool] = None):
     """Chunked SSD scan via Pallas.
 
     x: (B, S, H, P); dtv: (B, S, H) post-softplus; Bm/Cm: (B, S, G, N);
@@ -113,7 +117,7 @@ def ssd_scan(spec: SSDSpec, x, dtv, Bm, Cm, A, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Bsz, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dtv, Bm, Cm, A)
 
 

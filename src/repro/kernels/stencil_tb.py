@@ -29,18 +29,24 @@ Kernel layout
 
   inputs (ANY/HBM, manually DMA'd):   state fields then param fields,
                                       each padded by H = T*r_step
-  inputs (blocked, small):            per-tile source/receiver tables
+  inputs (SMEM, one block per tile):  flattened source/receiver tables
   outputs (blocked):                  per-state-field centre regions;
-                                      receiver partials
-                                      (ntx, nty, T, capr, rec_channels)
+                                      receiver partials, one
+                                      (T*rec_channels, capr) vector per
+                                      tile
 
 TPU notes: the z (minor) dimension is kept whole and should be a multiple
-of 128; tiles (tx, ty) should be multiples of 8.  Scatter/gather of the
-sparse points is realized with broadcasted-iota masks (predicated vector
+of 128; tiles (tx, ty) should be multiples of 8 (the window's y extent is
+rounded up to 8 rows).  Each in-VMEM step runs as a loop over 8-plane x
+slabs, so Mosaic's unrolled code and temporaries scale with a slab, not
+the window.  Scatter/gather of the sparse points is realized with
+broadcasted-iota masks over the slab holding the point (predicated vector
 ops — the VPU-friendly analogue of the paper's z-column nnz loop, see
-DESIGN.md §2 table).  Validated in interpret mode on CPU
-(tests/test_kernel_stencil_tb.py, tests/test_kernel_multiphysics.py);
-`kernel_cost` metadata below feeds the roofline model.
+DESIGN.md §2 table).  Parity runs in interpret mode on CPU
+(tests/test_kernel_stencil_tb.py, tests/test_kernel_multiphysics.py) and
+natively on the chip (chip_smoke.py); tests/test_tpu_compile.py compiles
+it for a described v5e at 512^3.  `kernel_cost` below feeds the roofline
+model.
 """
 from __future__ import annotations
 
@@ -55,7 +61,9 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core import stencil as st
+from repro.core.temporal_blocking import SLAB, VMEM_BUDGET
 from repro.kernels import tb_physics as phys
+from repro.kernels.platform import resolve_interpret
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,8 +95,12 @@ class TBKernelSpec:
 
     @property
     def window(self) -> Tuple[int, int, int]:
-        return (self.tile[0] + 2 * self.halo, self.tile[1] + 2 * self.halo,
-                self.nz)
+        """VMEM window of one tile: the tile plus its halo, with y (the
+        sublane dimension) rounded up to the 8-row tiling so the window
+        DMA is aligned; the extra rows lie past the halo and never reach
+        the centre."""
+        wy = self.tile[1] + 2 * self.halo
+        return (self.tile[0] + 2 * self.halo, -(-wy // 8) * 8, self.nz)
 
     @property
     def ntiles(self) -> Tuple[int, int]:
@@ -98,22 +110,33 @@ class TBKernelSpec:
                 f"grid ({self.nx},{self.ny}) must divide by tile {self.tile}")
         return (self.nx // tx, self.ny // ty)
 
-    def vmem_bytes(self, nwindows: int = 4) -> int:
-        """Resident bytes of `nwindows` window-sized VMEM buffers (one per
-        state/param field; 4 = the acoustic kernel's u_a, u_b, m, damp)."""
+    def vmem_bytes(self, nwindows: int = 6) -> int:
+        """Resident bytes of `nwindows` window-sized VMEM buffers (a pair
+        per state field and one per param field; 6 = the acoustic kernel's
+        two (u_prev, u) sets plus m and damp)."""
         wx, wy, wz = self.window
         return wx * wy * wz * jnp.dtype(self.dtype).itemsize * nwindows
 
 
-def _domain_mask(spec: TBKernelSpec, ti, tj):
-    """1.0 inside the physical domain, 0.0 in the halo padding — enforces
-    the Dirichlet boundary at every in-VMEM step (matches the oracle's
-    zero-fill convention)."""
-    wx, wy, wz = spec.window
+def _slab_planes(spec: TBKernelSpec, step_radius: int) -> Tuple[int, int]:
+    """(planes per slab, slabs per step): each step updates window planes
+    [r, wx - r) — all that the depth-T trapezoid needs — in slabs of
+    `SLAB` planes, the last one shifted back to end at wx - r."""
+    nout = spec.window[0] - 2 * step_radius
+    b = min(SLAB, nout)
+    return b, -(-nout // b)
+
+
+def _domain_mask(spec: TBKernelSpec, ti, tj, x0, nplanes: int):
+    """1.0 inside the physical domain, 0.0 in the halo padding, over window
+    planes [x0, x0 + nplanes) — enforces the Dirichlet boundary at every
+    in-VMEM step (matches the oracle's zero-fill convention)."""
+    _, wy, wz = spec.window
     tx, ty = spec.tile
     h = spec.halo
-    gx = ti * tx - h + jax.lax.broadcasted_iota(jnp.int32, (wx, wy, wz), 0)
-    gy = tj * ty - h + jax.lax.broadcasted_iota(jnp.int32, (wx, wy, wz), 1)
+    shape = (nplanes, wy, wz)
+    gx = ti * tx - h + x0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    gy = tj * ty - h + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     ok = ((gx >= 0) & (gx < spec.nx) & (gy >= 0) & (gy < spec.ny))
     return ok.astype(spec.dtype)
 
@@ -132,9 +155,21 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
 
     Ref layout (positional, in pallas_call order):
       inputs:  n_state + n_param HBM refs (+ a domain-mask HBM ref when
-               `external_dom`), then src_coords, src_vals, rec_coords, rec_w
-      outputs: n_state centre refs, then rec partials
-      scratch: one VMEM window per HBM ref, then a DMA semaphore array
+               `external_dom`), then this tile's src_coords, src_vals,
+               rec_coords, rec_w rows, flattened (1, 1, n) blocks in SMEM
+      outputs: n_state centre refs, then rec partials (1, T*chan, capr)
+      scratch: one VMEM window per HBM ref — a pair of them per state
+               field, between which the step loop ping-pongs — then a DMA
+               semaphore array
+
+    Each in-VMEM step runs as a loop over x-slabs: the physics update is
+    applied to `b + 2*r_step` window planes and its middle `b` planes are
+    kept (x is the untiled major dimension, so the slab is a cheap ref
+    slice).  The update is local with radius r_step, so the kept planes are
+    exactly what a whole-window update computes there; the slab loop keeps
+    Mosaic's unrolled code, and its live temporaries, at slab size rather
+    than window size.  Planes within r_step of the window's x faces are
+    not advanced: the depth-T trapezoid never reads them into the centre.
 
     `external_dom` is how the sharded execution layer reuses this kernel
     unchanged (DESIGN.md §4): on a single device the domain mask is an iota
@@ -145,81 +180,134 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
     ns = len(physics.state_fields)
     nw = physics.num_windows + (1 if external_dom else 0)
     hbm = refs[:nw]
-    src_coords_ref, src_vals_ref, rec_coords_ref, rec_w_ref = refs[nw:nw + 4]
-    out_refs = refs[nw + 4:nw + 4 + ns]
-    rec_out_ref = refs[nw + 4 + ns]
-    wins = refs[nw + 5 + ns:nw + 5 + ns + nw]
-    sems = refs[nw + 5 + ns + nw]
+    src_coords_ref, src_vals_ref, rec_coords_ref, rec_w_ref = \
+        refs[nw:nw + 4]
+    out_refs = refs[4 + nw:4 + nw + ns]
+    rec_out_ref = refs[4 + nw + ns]
+    wins = refs[5 + nw + ns:5 + 2 * nw + ns]
+    sems = refs[5 + 2 * nw + ns]
 
     ti = pl.program_id(0)
     tj = pl.program_id(1)
     tx, ty = spec.tile
     wx, wy, wz = spec.window
     h = spec.halo
+    r = spec.halo // spec.T
+    b, nslab = _slab_planes(spec, r)
+    nch = physics.rec_channels
 
     # ---- DMA one window per field HBM -> VMEM ------------------------------
+    # (state windows land in slot 0 of their (2, wx, wy, wz) pair)
     def win(ref):
         return ref.at[pl.ds(ti * tx, wx), pl.ds(tj * ty, wy), :]
 
-    copies = [pltpu.make_async_copy(win(hbm[i]), wins[i], sems.at[i])
-              for i in range(nw)]
+    copies = [pltpu.make_async_copy(
+        win(hbm[i]), wins[i].at[0] if i < ns else wins[i], sems.at[i])
+        for i in range(nw)]
     for c in copies:
         c.start()
     for c in copies:
         c.wait()
 
-    dom = wins[nw - 1][...] if external_dom else _domain_mask(spec, ti, tj)
-    mask_fn = lambda a: a * dom  # noqa: E731
+    states, par_refs = wins[:ns], wins[ns:physics.num_windows]
+    rec_out_ref[...] = jnp.zeros(rec_out_ref.shape, rec_out_ref.dtype)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (spec.T * nch, spec.rec_cap),
+                                    0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (spec.T * nch, spec.rec_cap),
+                                     1)
+    inj = [physics.state_fields.index(f) for f in physics.inject_fields]
 
-    state = {f: wins[i][...] for i, f in enumerate(physics.state_fields)}
-    params = {f: wins[ns + i][...]
-              for i, f in enumerate(physics.param_fields)}
+    # ---- T in-VMEM timesteps: step k reads slot k % 2, writes the other ----
+    def step(k, carry):
+        cur = [st.at[k % 2] for st in states]
+        nxt = [st.at[1 - k % 2] for st in states]
+        # the faces the step does not advance keep their (stale) values
+        for i in range(ns):
+            nxt[i][0:r] = cur[i][0:r]
+            nxt[i][wx - r:wx] = cur[i][wx - r:wx]
 
-    # ---- T in-VMEM timesteps (static unroll; T is small) -------------------
-    for k in range(spec.T):
-        new = physics.update(state, params, spec, mask_fn)
-        # Dirichlet outside the physical domain for the freshly computed
-        # fields (carried prev-copies and update-premasked fields are
-        # already masked)
-        for f in physics.evolved_fields:
-            if f not in physics.premasked_fields:
-                new[f] = new[f] * dom
+        def slab(s, carry):
+            lo = r + s * b                       # first plane this slab owns
+            x0 = jnp.minimum(lo, wx - r - b)     # first plane it computes
+            rd = pl.ds(x0 - r, b + 2 * r)
+            st_in = {f: cur[i][rd] for i, f in enumerate(physics.state_fields)}
+            pr_in = {f: par_refs[i][rd]
+                     for i, f in enumerate(physics.param_fields)}
+            dom = (wins[nw - 1][rd] if external_dom
+                   else _domain_mask(spec, ti, tj, x0 - r, b + 2 * r))
+            new = physics.update(st_in, pr_in, spec, lambda a: a * dom)
+            # (computed, not sliced out of `dom`: Mosaic's strided-slice
+            # rule mis-handles the iota mask's lane-replicated layout)
+            dom_c = (wins[nw - 1][pl.ds(x0, b)] if external_dom
+                     else _domain_mask(spec, ti, tj, x0, b))
+            for i, f in enumerate(physics.state_fields):
+                v = new[f][r:r + b]
+                if f in physics.evolved_fields and \
+                        f not in physics.premasked_fields:
+                    v = v * dom_c
+                nxt[i][pl.ds(x0, b)] = v.astype(spec.dtype)
 
-        # fused grid-aligned source injection (paper Listing 4/5 -> masked
-        # vector adds; padding slots carry val = 0)
-        for p in range(spec.src_cap):
-            x = src_coords_ref[0, p, 0]
-            y = src_coords_ref[0, p, 1]
-            z = src_coords_ref[0, p, 2]
-            val = src_vals_ref[0, k, p]
-            mask = _point_mask((wx, wy, wz), x, y, z)
-            add = jnp.where(mask, val, 0.0)
-            for f in physics.inject_fields:
-                new[f] = new[f] + add.astype(new[f].dtype)
+            # fused grid-aligned source injection (paper Listing 4/5 ->
+            # masked vector adds; padding slots carry val = 0)
+            def inject(p, c):
+                x = src_coords_ref[0, 0, 3 * p] - x0
 
-        # fused receiver interpolation partials (paper Fig. 3b)
-        rec_arrays = physics.record(new)
-        for p in range(spec.rec_cap):
-            x = rec_coords_ref[0, p, 0]
-            y = rec_coords_ref[0, p, 1]
-            z = rec_coords_ref[0, p, 2]
-            w = rec_w_ref[0, p]
-            mask = _point_mask((wx, wy, wz), x, y, z)
-            for c, arr in enumerate(rec_arrays):
-                sample = jnp.sum(jnp.where(mask, arr, 0.0))
-                rec_out_ref[0, 0, k, p, c] = (w * sample).astype(spec.dtype)
+                @pl.when((x >= 0) & (x < b))
+                def _():
+                    val = src_vals_ref[0, 0, k * spec.src_cap + p]
+                    mask = _point_mask((b, wy, wz), x,
+                                       src_coords_ref[0, 0, 3 * p + 1],
+                                       src_coords_ref[0, 0, 3 * p + 2])
+                    add = jnp.where(mask, val, 0.0).astype(spec.dtype)
+                    for i in inj:
+                        nxt[i][pl.ds(x0, b)] = nxt[i][pl.ds(x0, b)] + add
+                return c
 
-        state = new
+            jax.lax.fori_loop(0, spec.src_cap, inject, 0)
+
+            # fused receiver interpolation partials (paper Fig. 3b); a
+            # plane recomputed by the shifted last slab is owned by the
+            # slab before it, so every entry is sampled exactly once
+            rec_arrays = physics.record(
+                {f: nxt[i][pl.ds(x0, b)]
+                 for i, f in enumerate(physics.state_fields)})
+
+            def record(p, c):
+                x = rec_coords_ref[0, 0, 3 * p] - x0
+
+                @pl.when((x >= lo - x0) & (x < b))
+                def _():
+                    w = rec_w_ref[0, 0, p]
+                    mask = _point_mask((b, wy, wz), x,
+                                       rec_coords_ref[0, 0, 3 * p + 1],
+                                       rec_coords_ref[0, 0, 3 * p + 2])
+                    acc = rec_out_ref[0]
+                    for ch, arr in enumerate(rec_arrays):
+                        smp = jnp.sum(jnp.where(mask, arr, 0.0), axis=0)
+                        smp = jnp.sum(jnp.sum(smp, axis=0, keepdims=True),
+                                      axis=1, keepdims=True)
+                        acc = jnp.where((rows == k * nch + ch) & (lanes == p),
+                                        (w * smp).astype(spec.dtype), acc)
+                    rec_out_ref[0] = acc
+                return c
+
+            jax.lax.fori_loop(0, spec.rec_cap, record, 0)
+            return carry
+
+        jax.lax.fori_loop(0, nslab, slab, 0)
+        return carry
+
+    jax.lax.fori_loop(0, spec.T, step, 0)
 
     # ---- write back the valid centre ---------------------------------------
-    for i, f in enumerate(physics.state_fields):
-        out_refs[i][...] = state[f][h:h + tx, h:h + ty, :]
+    for i in range(ns):
+        out_refs[i][...] = states[i][spec.T % 2, h:h + tx, h:h + ty, :]
 
 
 def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
                  state_pads, param_pads,
                  src_coords, src_vals, rec_coords, rec_w,
-                 *, dom_pad=None, interpret: bool = True):
+                 *, dom_pad=None, interpret: Optional[bool] = None):
     """One depth-T time tile over the whole grid (one pallas_call).
 
     Args:
@@ -244,56 +332,115 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
                   depth (`ops.pass_inner_spec`: grid = block + 2*d_out
                   rounded up to the tile, halo = inner_T * r_step) —
                   dom_pad then also masks the round-up garbage band.
+      interpret:  None picks by backend (`platform.resolve_interpret`).
+
+    Each tile's rows of the four tables reach SMEM as flattened blocks:
+    the kernel reads them one scalar at a time, and SMEM pads only a
+    block's minor dimension.
     Returns (new_states tuple, rec_partials) with fields (nx, ny, nz) and
     rec_partials (ntx, nty, T, capr, rec_channels).
     """
+    ntx, nty = spec.ntiles
+    tables = (src_coords.shape, src_vals.shape, rec_coords.shape, rec_w.shape)
+    want = ((ntx * nty, spec.src_cap, 3), (ntx * nty, spec.T, spec.src_cap),
+            (ntx * nty, spec.rec_cap, 3), (ntx * nty, spec.rec_cap))
+    if tables != want:
+        raise ValueError(f"tile tables {tables} do not match the spec's "
+                         f"{want}")
+    call = functools.partial(_tb_call, spec, physics,
+                             resolve_interpret(interpret))
+    return _batch_as_loop(call)(
+        (tuple(state_pads), tuple(param_pads),
+         () if dom_pad is None else (dom_pad,),
+         (src_coords, src_vals, rec_coords, rec_w)))
+
+
+def _batch_as_loop(fn):
+    """`fn` of one pytree argument, run as a loop over the batch under
+    `jax.vmap` (the survey engine's shot axis): Mosaic lowers no batch
+    dimension on the kernel's manually DMA'd (ANY) operands, and the grid
+    steps of a batched call would run one after another anyway."""
+    @jax.custom_batching.custom_vmap
+    def run(args):
+        return fn(args)
+
+    @run.def_vmap
+    def _(axis_size, in_batched, args):
+        leaves, tree = jax.tree.flatten(args)
+        flags = jax.tree.leaves(in_batched)
+
+        def one(items):
+            items = iter(items)
+            return run(jax.tree.unflatten(
+                tree, [next(items) if f else a for a, f in zip(leaves,
+                                                                flags)]))
+
+        outs = jax.lax.map(one, [a for a, f in zip(leaves, flags) if f])
+        return outs, jax.tree.map(lambda _: True, outs)
+
+    return run
+
+
+def _tb_call(spec: TBKernelSpec, physics: phys.TBPhysics, interpret: bool,
+             args):
+    """The `pallas_call` behind `tb_time_tile` (arguments grouped as
+    (state_pads, param_pads, (dom_pad,) or (), tables))."""
+    state_pads, param_pads, dom_pads, tables = args
     ns = len(physics.state_fields)
-    external_dom = dom_pad is not None
+    external_dom = bool(dom_pads)
     nw = physics.num_windows + (1 if external_dom else 0)
     ntx, nty = spec.ntiles
     wx, wy, wz = spec.window
+    nch = physics.rec_channels
     kern = functools.partial(_tb_kernel, spec, physics, external_dom)
-    flat = lambda i, j: (i * nty + j, 0, 0)  # noqa: E731
+    # the last tile row's window reaches past the halo when y is rounded
+    # up to the tiling; edge values there stay finite and off the centre
+    hbm = (*state_pads, *param_pads, *dom_pads)
+    extra = (nty - 1) * spec.tile[1] + wy - hbm[0].shape[1]
+    if extra > 0:
+        hbm = [jnp.pad(a, ((0, 0), (0, extra), (0, 0)), mode="edge")
+               for a in hbm]
+    tile_idx = lambda i, j: (i * nty + j, 0, 0)  # noqa: E731
+    # one flattened row per tile, so the SMEM block pads only its length
+    rows = [t.astype(dt).reshape(ntx * nty, 1, -1)
+            for t, dt in zip(tables, (jnp.int32, jnp.float32, jnp.int32,
+                                      jnp.float32))]
 
-    field_out_spec = pl.BlockSpec((spec.tile[0], spec.tile[1], spec.nz),
-                                  lambda i, j: (i, j, 0))
-    field_out_shape = jax.ShapeDtypeStruct((spec.nx, spec.ny, spec.nz),
-                                           spec.dtype)
+    rec_shape = (ntx * nty, spec.T * nch, spec.rec_cap)
     outs = pl.pallas_call(
         kern,
         grid=(ntx, nty),
         in_specs=(
             [pl.BlockSpec(memory_space=pl.ANY)] * nw
-            + [pl.BlockSpec((1, spec.src_cap, 3), flat),
-               pl.BlockSpec((1, spec.T, spec.src_cap), flat),
-               pl.BlockSpec((1, spec.rec_cap, 3), flat),
-               pl.BlockSpec((1, spec.rec_cap), lambda i, j: (i * nty + j, 0))]
+            + [pl.BlockSpec((1, 1, t.shape[2]), tile_idx,
+                            memory_space=pltpu.SMEM) for t in rows]
         ),
         out_specs=(
-            [field_out_spec] * ns
-            + [pl.BlockSpec((1, 1, spec.T, spec.rec_cap, spec.rec_channels),
-                            lambda i, j: (i, j, 0, 0, 0))]
+            [pl.BlockSpec((spec.tile[0], spec.tile[1], spec.nz),
+                          lambda i, j: (i, j, 0))] * ns
+            + [pl.BlockSpec((1,) + rec_shape[1:], tile_idx)]
         ),
         out_shape=(
-            [field_out_shape] * ns
-            + [jax.ShapeDtypeStruct(
-                (ntx, nty, spec.T, spec.rec_cap, spec.rec_channels),
-                spec.dtype)]
+            [jax.ShapeDtypeStruct((spec.nx, spec.ny, spec.nz),
+                                  spec.dtype)] * ns
+            + [jax.ShapeDtypeStruct(rec_shape, spec.dtype)]
         ),
         scratch_shapes=(
-            [pltpu.VMEM((wx, wy, wz), spec.dtype)] * nw
+            [pltpu.VMEM((2, wx, wy, wz), spec.dtype)] * ns
+            + [pltpu.VMEM((wx, wy, wz), spec.dtype)] * (nw - ns)
             + [pltpu.SemaphoreType.DMA((nw,))]
         ),
+        # the budget the autotuner planned this tile against
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
-    )(*state_pads, *param_pads,
-      *((dom_pad,) if external_dom else ()),
-      src_coords, src_vals, rec_coords, rec_w)
-    return tuple(outs[:ns]), outs[ns]
+    )(*hbm, *rows)
+    rec = outs[ns].reshape(ntx, nty, spec.T, nch, spec.rec_cap)
+    return tuple(outs[:ns]), jnp.swapaxes(rec, 3, 4)
 
 
 def acoustic_tb_time_tile(spec: TBKernelSpec, u0_pad, u1_pad, m_pad, damp_pad,
                           src_coords, src_vals, rec_coords, rec_w,
-                          *, interpret: bool = True):
+                          *, interpret: Optional[bool] = None):
     """Acoustic wrapper kept for compatibility: returns
     (u0', u1', rec_partials (ntx, nty, T, capr))."""
     (u0n, u1n), rec = tb_time_tile(
@@ -307,11 +454,16 @@ def kernel_cost(spec: TBKernelSpec,
     """Analytic per-call cost of the kernel (feeds §Roofline / benchmarks).
 
     Reads one window per state+param field, writes back the centre of every
-    state field; sparse-term flops are the masked vector adds of the fused
-    injection/interpolation.
+    state field.  Stencil flops count what each step computes: `nslab`
+    slabs of `b + 2*r_step` planes (`_tb_kernel`); sparse-term flops are
+    the masked vector adds of the fused injection/interpolation over the
+    one slab that holds each entry.  `vmem_bytes` is the resident windows,
+    a pair per state field.
     """
     ntx, nty = spec.ntiles
     wx, wy, wz = spec.window
+    r = spec.halo // spec.T
+    b, nslab = _slab_planes(spec, r)
     if physics.name == "acoustic":
         stencil_flops = st.stencil_flops_per_point(spec.order, 3) + 9
     else:
@@ -319,9 +471,10 @@ def kernel_cost(spec: TBKernelSpec,
         mod = {"elastic": elastic, "tti": tti}[physics.name]
         stencil_flops = mod.model_flops_per_step((1, 1, 1), spec.order)
     window_pts = wx * wy * wz
+    step_pts = nslab * (b + 2 * r) * wy * wz
     sparse_flops = (len(physics.inject_fields) * spec.src_cap
-                    + 2 * physics.rec_channels * spec.rec_cap) * window_pts
-    flops = ntx * nty * spec.T * (window_pts * stencil_flops + sparse_flops)
+                    + 2 * physics.rec_channels * spec.rec_cap) * b * wy * wz
+    flops = ntx * nty * spec.T * (step_pts * stencil_flops + sparse_flops)
     itemsize = jnp.dtype(spec.dtype).itemsize
     nw = physics.num_windows
     ns = len(physics.state_fields)
@@ -331,4 +484,4 @@ def kernel_cost(spec: TBKernelSpec,
             "hbm_bytes": float(hbm_read + hbm_write),
             "useful_flops": float(spec.nx * spec.ny * spec.nz * spec.T
                                   * stencil_flops),
-            "vmem_bytes": spec.vmem_bytes(nw)}
+            "vmem_bytes": spec.vmem_bytes(nw + ns)}

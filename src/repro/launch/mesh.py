@@ -6,11 +6,7 @@ jax device state (the dry-run must set XLA_FLAGS before the first jax call).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.4.38; older releases have no explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,8 +18,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

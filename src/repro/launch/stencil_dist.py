@@ -176,6 +176,7 @@ def main():
     import numpy as np
 
     from repro import telemetry as tele
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.core import interp as interp_mod
     from repro.core import sources as S
     from repro.core.grid import Grid
@@ -186,6 +187,7 @@ def main():
     from repro.launch import mesh as mesh_lib
     from repro.survey.plan_cache import cached_plan_hierarchy
 
+    print("compile cache:", enable_compile_cache())
     telemetry_path = None
     if args.telemetry is not None:
         tele.enable()
@@ -334,7 +336,7 @@ def main():
 
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.halo import _shard_map, exchange_to_depth
+        from repro.distributed.halo import exchange_to_depth
 
         bx, by = plan.block
         nz = shape[2]
@@ -351,7 +353,7 @@ def main():
         spec3 = P(plan.ax_x, plan.ax_y, None)
 
         @jax.jit
-        @functools.partial(_shard_map, mesh=plan.mesh,
+        @functools.partial(jax.shard_map, mesh=plan.mesh,
                            in_specs=(spec3,) * ns, out_specs=(spec3,) * ns)
         def exchange_only(*fields):
             pads = tuple(exchange_to_depth(f, d, h, plan.ax_x, plan.ax_y)

@@ -150,10 +150,12 @@ def main():
     import numpy as np
 
     from repro import telemetry as tele
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.core import interp as interp_mod
     from repro.core.grid import Grid
     from repro.survey import PlanCache, SurveyEngine
 
+    print("compile cache:", enable_compile_cache())
     telemetry_path = None
     if args.telemetry is not None:
         tele.enable()

@@ -44,6 +44,7 @@ from repro.core.grid import Grid
 from repro.core.temporal_blocking import TBPlan
 from repro.kernels import ops as ops_mod
 from repro.kernels import tb_physics as phys
+from repro.kernels.platform import resolve_interpret
 from repro.survey.plan_cache import (CacheInfo, PlanCache,
                                      cached_plan_for_physics, default_cache)
 from repro.survey.shots import Shot, Survey, bucket_shots
@@ -110,7 +111,7 @@ class SurveyEngine:
       nt:         timesteps per shot (uniform across the survey).
       dt:         timestep.
       order:      space order.
-      executor:   "pallas" (the TB kernel; interpret off-TPU) or "jnp"
+      executor:   "pallas" (the TB kernel; interpreted on CPU) or "jnp"
                   (the same window schedule in pure jnp).
       plan:       a TBPlan to skip planning; default consults the plan
                   cache (ONE sweep per configuration).
@@ -118,7 +119,8 @@ class SurveyEngine:
       bucket_cap: compiled batch size — every dispatch has exactly this
                   many shots (partial batches pad with null shots), so a
                   bucket never re-traces.
-      interpret:  Pallas interpret mode; default True off-TPU.
+      interpret:  Pallas interpret mode; None picks by backend
+                  (`kernels.platform.resolve_interpret`).
       interp:     interpolation kernel — an `interp.InterpSpec`, or the
                   kernel name ("linear" / "sinc") resolved together with
                   `interp_order` (the support radius r) by
@@ -155,8 +157,7 @@ class SurveyEngine:
         self.bucket_cap = int(bucket_cap)
         if self.bucket_cap < 1:
             raise ValueError("bucket_cap must be >= 1")
-        self.interpret = (jax.devices()[0].platform != "tpu"
-                          if interpret is None else interpret)
+        self.interpret = resolve_interpret(interpret)
         self.cache = plan_cache or default_cache()
         self.cache_info: Optional[CacheInfo] = None
         self.plan_entry: Optional[dict] = None

@@ -313,8 +313,10 @@ def test_serialized_exchange_is_additive():
 
 def test_physics_costs_registry():
     ac, ti, el = (PHYSICS_COSTS[k] for k in ("acoustic", "tti", "elastic"))
-    # acoustic reproduces the historical autotune_plan defaults
-    assert (ac.fields, ac.read_fields) == (5, 4)
+    # acoustic reproduces the autotune_plan defaults: the kernel holds a
+    # pair of windows per state field (u_prev, u) plus m and damp
+    assert (ac.fields, ac.read_fields) == (6, 4)
+    assert (ti.fields, el.fields) == (14, 22)
     # field counts: state + params
     assert (ti.state_fields, ti.param_fields) == (4, 6)
     assert (el.state_fields, el.param_fields) == (9, 4)
@@ -336,7 +338,7 @@ def test_plan_for_physics_acoustic_matches_defaults():
     got, _ = plan_for_physics("acoustic", nz=512, order=4)
     want, _ = autotune_plan(nz=512, radius=2,
                             flops_per_point=ac.flops_per_point(4),
-                            fields=5, read_fields=4, write_fields=2)
+                            fields=6, read_fields=4, write_fields=2)
     assert got == want
 
 
@@ -345,7 +347,9 @@ def test_plan_for_physics_high_order_falls_back():
     spatially-blocked schedule (T = 1), while memory-bound acoustic at
     SO-4 keeps a deep time tile."""
     assert plan_for_physics("tti", nz=512, order=12)[0].T == 1
-    assert plan_for_physics("elastic", nz=512, order=12)[0].T == 1
+    # elastic SO-12's 22 whole-z windows fit only 8-wide tiles (ROADMAP R1)
+    assert plan_for_physics("elastic", nz=512, order=12,
+                            tiles=(8, 16, 32))[0].T == 1
     assert plan_for_physics("acoustic", nz=512, order=4)[0].T > 1
 
 
