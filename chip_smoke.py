@@ -98,10 +98,11 @@ def build_case(n, seed):
     return case, grid, dt, m, damp, src, rec
 
 
-def phase_propagate(args, plan_for_physics):
+def phase_propagate(args):
     import jax.numpy as jnp
 
     from repro.core import sources as S
+    from repro.core.temporal_blocking import plan_for_physics
     from repro.kernels import ops
     from repro.kernels import ref
 
@@ -182,13 +183,13 @@ def phase_survey(args):
                                                                   want))
 
 
-def phase_four_chips(args, plan_for_physics):
+def phase_four_chips(args):
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import sources as S
-    from repro.core.temporal_blocking import plan_hierarchy
+    from repro.core.temporal_blocking import plan_for_physics, plan_hierarchy
     from repro.distributed.halo import (dist_plan_from_hier,
                                         sharded_tb_propagate)
     from repro.kernels import ops
@@ -266,7 +267,6 @@ def main(argv=None):
               file=sys.stderr)
         return 1
 
-    from repro.core.temporal_blocking import plan_for_physics
     from repro.kernels.platform import resolve_interpret
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -274,9 +274,9 @@ def main(argv=None):
     print(f"compile cache: {enable_compile_cache()}")
     print(f"Pallas interpret mode: {resolve_interpret()}")
     if args.four_chips:
-        phase_four_chips(args, plan_for_physics)
+        phase_four_chips(args)
     else:
-        phase_propagate(args, plan_for_physics)
+        phase_propagate(args)
         phase_survey(args)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": count}}))
