@@ -32,6 +32,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 TOL = 1e-4        # max|trace - reference| / max|reference|
 NREC = 32
+SURVEY_NT = 67    # survey shot length, cut from the case's 399 steps
 
 
 def _timed(fn):
@@ -39,11 +40,6 @@ def _timed(fn):
     t0 = time.perf_counter()
     out = jax.block_until_ready(fn())
     return out, time.perf_counter() - t0
-
-
-def _tiles(n):
-    """The autotuner's tile candidates that divide an n-wide grid."""
-    return tuple(t for t in (16, 32, 64, 128, 256) if n % t == 0)
 
 
 def _rel_err(got, want):
@@ -63,8 +59,8 @@ def _check(name, err, scale):
         raise AssertionError(f"{name}: parity {err!r} exceeds {TOL}")
 
 
-def build_case(n, seed):
-    """The paper's acoustic SO-4 case on an n^3 grid: a layered model
+def build_case(seed):
+    """The paper's acoustic SO-4 case on its 512^3 grid: a layered model
     (vp 1500 -> 3500 m/s in 8 layers, made on the device), one off-grid
     10 Hz Ricker source near the surface (z is the last axis) and an
     off-grid line of 32 receivers around it."""
@@ -77,7 +73,8 @@ def build_case(n, seed):
     from repro.core.grid import Grid
 
     case = full_case("acoustic", 4)
-    shape = (n, n, n)
+    shape = case.shape
+    n = shape[0]
     grid = Grid(shape=shape, spacing=case.spacing)
     dt = grid.cfl_dt(case.vmax, case.space_order)
     rng = np.random.default_rng(seed)
@@ -106,16 +103,12 @@ def phase_propagate(args):
     from repro.kernels import ops
     from repro.kernels import ref
 
-    case, grid, dt, m, damp, src, rec = build_case(args.n, args.seed)
+    case, grid, dt, m, damp, src, rec = build_case(args.seed)
     order = case.space_order
-    nt_full = case.nt(dt)
-    nt = args.nt or nt_full
-    plan, _ = plan_for_physics("acoustic", grid.shape[2], order,
-                               tiles=_tiles(args.n))
+    nt = case.nt(dt)
+    plan, _ = plan_for_physics("acoustic", grid.shape[2], order)
     print(f"phase 1 case: {case.name} grid {grid.shape} spacing "
-          f"{grid.spacing} dt {dt!r} nt {nt}"
-          + ("" if nt == nt_full else f" (cut from {nt_full}, the full "
-             f"{case.time_ms} ms)"))
+          f"{grid.spacing} dt {dt!r} nt {nt} (the full {case.time_ms} ms)")
     print(f"phase 1 plan: tile {plan.tile} T {plan.T} "
           f"(remainder tile T {nt % plan.T})")
     if nt < 64 or nt % plan.T == 0:
@@ -135,10 +128,15 @@ def phase_propagate(args):
     print(f"phase 1 acoustic_tb_propagate on device: cold {cold!r} s "
           f"(compile + run), warm {warm!r} s, "
           f"{grid.npoints * nt / warm!r} point-steps/s warm")
-    (rstate, rtraces), rsec = _timed(lambda: ref.acoustic_reference(
-        nt, zero, zero, m, damp, dt, grid.spacing, order, g=g,
-        receivers=gr))
-    print(f"phase 1 reference propagate on device: {rsec!r} s (cold)")
+    def reference():
+        return ref.acoustic_reference(nt, zero, zero, m, damp, dt,
+                                      grid.spacing, order, g=g, receivers=gr)
+
+    (rstate, rtraces), rcold = _timed(reference)
+    _, rwarm = _timed(reference)
+    print(f"phase 1 reference propagate on device: cold {rcold!r} s "
+          f"(compile + run), warm {rwarm!r} s, "
+          f"{grid.npoints * nt / rwarm!r} point-steps/s warm")
     _check("phase 1 receiver traces vs reference", *_rel_err(traces,
                                                              rtraces))
     werr, wscale = _rel_err(state[1], rstate[1])
@@ -153,9 +151,8 @@ def phase_survey(args):
     from repro.launch.stencil_survey import sequential_traces
     from repro.survey import PlanCache, Shot, SurveyEngine
 
-    case, grid, dt, m, damp, src, rec = build_case(args.survey_n,
-                                                   args.seed + 1)
-    nt = args.survey_nt
+    case, grid, dt, m, damp, src, rec = build_case(args.seed + 1)
+    nt = SURVEY_NT
     h = case.spacing[0]
     shots = [Shot(src_coords=src + np.array([[(i - 1.5) * 8.0 * h, 0.0,
                                               0.0]]),
@@ -196,14 +193,13 @@ def phase_four_chips(args):
     from repro.kernels import tb_physics as phys
     from repro.launch.mesh import make_xy_mesh
 
-    case, grid, dt, m, damp, src, rec = build_case(args.n, args.seed)
+    case, grid, dt, m, damp, src, rec = build_case(args.seed)
     order = case.space_order
-    nt = args.nt or case.nt(dt)
+    nt = case.nt(dt)
     mesh = make_xy_mesh()
     px, py = mesh.shape["data"], mesh.shape["model"]
     block = (grid.shape[0] // px, grid.shape[1] // py)
-    hier, _ = plan_hierarchy("acoustic", grid.shape[2], order, block,
-                             tiles=_tiles(min(block)))
+    hier, _ = plan_hierarchy("acoustic", grid.shape[2], order, block)
     plan = dist_plan_from_hier(mesh, grid.shape, phys.ACOUSTIC, order, hier,
                                dt, grid.spacing, inner="pallas")
     print(f"four-chip case: {case.name} grid {grid.shape} nt {nt} mesh "
@@ -230,8 +226,7 @@ def phase_four_chips(args):
     if len(set(devs)) != px * py or shapes != {block + (grid.shape[2],)}:
         raise AssertionError("wavefield shards are not one block per device")
 
-    plan1, _ = plan_for_physics("acoustic", grid.shape[2], order,
-                                tiles=_tiles(args.n))
+    plan1, _ = plan_for_physics("acoustic", grid.shape[2], order)
     one = jnp.zeros(grid.shape, jnp.float32)      # on device 0, as m, damp
     (_, traces1), sec1 = _timed(lambda: ops.acoustic_tb_propagate(
         nt, one, one, m, damp, g, gr, plan1, order, dt, grid.spacing))
@@ -244,12 +239,6 @@ def phase_four_chips(args):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--n", type=int, default=512,
-                    help="grid edge of the propagate phases (paper: 512)")
-    ap.add_argument("--nt", type=int, default=None,
-                    help="timesteps (default: the case's full 512 ms)")
-    ap.add_argument("--survey-n", type=int, default=512, dest="survey_n")
-    ap.add_argument("--survey-nt", type=int, default=67, dest="survey_nt")
     ap.add_argument("--four-chips", action="store_true", dest="four_chips",
                     help="run only the sharded route on a 2x2 mesh")
     args = ap.parse_args(argv)

@@ -232,15 +232,19 @@ class TBPlan:
         centre blocks of the `out_fields` written back, and the live
         temporaries of one x-slab update (`SLAB` + 2*radius planes),
         budgeted at one slab-sized array per field it reads
-        (`slab_fields`) plus three.  That last term bounds what Mosaic
-        allocated for the three physics at 512^3 on a v5e: about 4.4
-        slab-sized arrays for acoustic, 12.5 for TTI, 9.4 for elastic."""
+        (`slab_fields`) plus three, plus one per two points of radius.
+        That last term bounds what Mosaic allocated for the autotuned
+        plans at 512^3 on a v5e, which grows with the radius: about 4.4
+        slab-sized arrays for acoustic SO-4, 9.4 for elastic SO-4, and
+        12.5, 13.3 and 15.4 for TTI at SO-4, 8 and 12 (radius 4, 8,
+        12)."""
         wx, wy, _ = self.window(nz)
         wy = -(-wy // 8) * 8            # the kernel's window is 8-row aligned
         tx, ty = self.tile
         slab = min(SLAB + 2 * self.radius, wx)
+        temps = slab_fields + 3 + self.radius // 2
         planes = (fields * wx * wy + 2 * out_fields * tx * ty
-                  + (slab_fields + 3) * slab * wy)
+                  + temps * slab * wy)
         return planes * nz * dtype_bytes
 
     def hbm_bytes_per_point_step(self, nz: int, read_fields: int = 4,

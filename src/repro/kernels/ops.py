@@ -15,8 +15,9 @@ host-side table binning happens in `_tb_propagate`, and everything after
 it — `tb_propagate_prepared` — is a pure traced function of jnp pytrees
 (state, padded params, `src_dcmp`, the per-tile tables).  That split is
 what makes the survey engine possible: `survey/engine.py` stacks the
-prepared tables of a whole shot bucket and `jax.vmap`s
-`tb_propagate_prepared` over the shot axis, one jit trace per bucket.
+prepared tables of a whole shot bucket and runs `tb_propagate_prepared`
+over the shot axis (a per-shot `jax.lax.map` for the Pallas executor,
+`jax.vmap` for jnp), one jit trace per bucket.
 Each time tile runs through one of two executors sharing the same window
 schedule: `executor="pallas"` (the `stencil_tb` kernel, interpret mode
 off-TPU) or `executor="jnp"` (`_jnp_time_tile`, the same per-window
@@ -91,7 +92,7 @@ def _src_vals_for_tile(src_dcmp: jnp.ndarray, src_tab, t0, T: int):
 
     `src_dcmp` is the (nt, npts) decomposed-wavelet table
     (`GriddedSources.src_dcmp`) — passed as a bare array so the whole
-    call stays a traced pytree function (vmappable over a shot axis)."""
+    call stays a traced pytree function (mappable over a shot axis)."""
     npts = src_dcmp.shape[1]
     vals = jax.lax.dynamic_slice(src_dcmp, (t0, 0), (T, npts))  # (T, npts)
     safe_sid = jnp.maximum(src_tab.sid, 0)                 # (ntiles, cap)
@@ -305,9 +306,11 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
     Every non-static argument is a jnp pytree — state tuple, padded
     params, the (nt, npts) `src_dcmp` wavelet table and the
     `TileSourceTable`/`TileReceiverTable` NamedTuples — so this function
-    jits cleanly and, crucially, `jax.vmap`s over a stacked shot axis:
-    the survey engine (`survey/engine.py`) batches whole shot buckets
-    through one trace of this function.  `spec`/`rspec` (None when
+    jits cleanly and, crucially, maps over a stacked shot axis: the
+    survey engine (`survey/engine.py`) batches whole shot buckets
+    through one trace of this function (`jax.vmap` with the jnp
+    executor; the Pallas kernel takes no batch axis, so with it each
+    shot runs in turn under `jax.lax.map`).  `spec`/`rspec` (None when
     `nt % spec.T == 0`), `nrec`, `interpret` and `executor`
     ("pallas" | "jnp") are static.
 

@@ -336,7 +336,10 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
 
     Each tile's rows of the four tables reach SMEM as flattened blocks:
     the kernel reads them one scalar at a time, and SMEM pads only a
-    block's minor dimension.
+    block's minor dimension.  Natively the call takes no batch axis:
+    Mosaic lowers none on the manually DMA'd (ANY) operands, so a caller
+    with many shots loops over them (`jax.lax.map`, as the survey engine
+    does) instead of `jax.vmap`.
     Returns (new_states tuple, rec_partials) with fields (nx, ny, nz) and
     rec_partials (ntx, nty, T, capr, rec_channels).
     """
@@ -347,45 +350,8 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
     if tables != want:
         raise ValueError(f"tile tables {tables} do not match the spec's "
                          f"{want}")
-    call = functools.partial(_tb_call, spec, physics,
-                             resolve_interpret(interpret))
-    return _batch_as_loop(call)(
-        (tuple(state_pads), tuple(param_pads),
-         () if dom_pad is None else (dom_pad,),
-         (src_coords, src_vals, rec_coords, rec_w)))
-
-
-def _batch_as_loop(fn):
-    """`fn` of one pytree argument, run as a loop over the batch under
-    `jax.vmap` (the survey engine's shot axis): Mosaic lowers no batch
-    dimension on the kernel's manually DMA'd (ANY) operands, and the grid
-    steps of a batched call would run one after another anyway."""
-    @jax.custom_batching.custom_vmap
-    def run(args):
-        return fn(args)
-
-    @run.def_vmap
-    def _(axis_size, in_batched, args):
-        leaves, tree = jax.tree.flatten(args)
-        flags = jax.tree.leaves(in_batched)
-
-        def one(items):
-            items = iter(items)
-            return run(jax.tree.unflatten(
-                tree, [next(items) if f else a for a, f in zip(leaves,
-                                                                flags)]))
-
-        outs = jax.lax.map(one, [a for a, f in zip(leaves, flags) if f])
-        return outs, jax.tree.map(lambda _: True, outs)
-
-    return run
-
-
-def _tb_call(spec: TBKernelSpec, physics: phys.TBPhysics, interpret: bool,
-             args):
-    """The `pallas_call` behind `tb_time_tile` (arguments grouped as
-    (state_pads, param_pads, (dom_pad,) or (), tables))."""
-    state_pads, param_pads, dom_pads, tables = args
+    dom_pads = () if dom_pad is None else (dom_pad,)
+    tables = (src_coords, src_vals, rec_coords, rec_w)
     ns = len(physics.state_fields)
     external_dom = bool(dom_pads)
     nw = physics.num_windows + (1 if external_dom else 0)
@@ -432,7 +398,7 @@ def _tb_call(spec: TBKernelSpec, physics: phys.TBPhysics, interpret: bool,
         ),
         # the budget the autotuner planned this tile against
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*hbm, *rows)
     rec = outs[ns].reshape(ntx, nty, spec.T, nch, spec.rec_cap)
     return tuple(outs[:ns]), jnp.swapaxes(rec, 3, 4)

@@ -13,7 +13,7 @@ executable — across those invocations.  This package is that layer:
                (nsrc, nrec) so the number of distinct compiled shapes is
                bounded regardless of survey size.
   engine       `SurveyEngine`: one jitted executable per (physics,
-               bucket), vmapping the single-device TB propagator
+               bucket), mapping the single-device TB propagator
                (`kernels/ops.tb_propagate_prepared`) over a shot axis,
                with receiver-trace host transfer double-buffered against
                device compute.

@@ -7,9 +7,12 @@ everything shot-invariant:
             (`survey/plan_cache.py`) — never per shot, never per bucket.
   compile   ONE jit trace per (physics, bucket shape): shots are bucketed
             by padded (nsrc, nrec) (`survey/shots.py`) and each bucket's
-            executable `jax.vmap`s the single-device TB propagator
+            executable runs the single-device TB propagator
             (`kernels/ops.tb_propagate_prepared`) over a stacked shot
-            axis.  Batches are padded to a FIXED leading dim
+            axis: `jax.vmap` with the jnp executor, a per-shot
+            `jax.lax.map` with the Pallas one (the kernel takes no batch
+            axis), so Pallas shots of a batch run one after another in
+            one dispatch.  Batches are padded to a FIXED leading dim
             (`bucket_cap`) with silent null shots, so ragged buckets and
             repeat runs never re-trace.
   transfer  receiver traces are double-buffered: batch i+1 is dispatched
@@ -276,6 +279,9 @@ class SurveyEngine:
             # fires once per jit trace: the compile counter the acceptance
             # test pins to 1 per bucket
             self.trace_counts[key] += 1
+            if executor == "pallas":
+                return jax.lax.map(
+                    lambda a: one_shot(param_pads, rparam_pads, a), batch)
             return jax.vmap(one_shot, in_axes=(None, None, 0))(
                 param_pads, rparam_pads, batch)
 
@@ -379,7 +385,7 @@ class SurveyEngine:
         self.metrics.counter("survey.shots").inc(n)
         self.metrics.counter("survey.batches").inc(n_batches)
         stats = {
-            "route": "vmap",
+            "route": "map" if self.executor == "pallas" else "vmap",
             "physics": self.physics_name, "executor": self.executor,
             "shots": n, "seconds": seconds,
             "cold_seconds": cold_seconds, "warm_seconds": warm_seconds,
