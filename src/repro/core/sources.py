@@ -63,6 +63,7 @@ from repro.core import interp as interp_mod
 from repro.core import tables as tables_mod
 from repro.core.grid import Grid
 from repro.core.interp import LINEAR, InterpCoeffs, InterpSpec  # noqa: F401
+from repro.telemetry import spans as _spans
 
 
 # ---------------------------------------------------------------------------
@@ -196,43 +197,46 @@ def precompute(op: SparseOperator, grid: Grid, wavelets: np.ndarray,
     wavelets = np.asarray(wavelets, np.float64)
     if wavelets.ndim != 2 or wavelets.shape[1] != op.num:
         raise ValueError(f"wavelets must be (nt, {op.num}), got {wavelets.shape}")
-    st = interp_stencil(op, grid, interp)
+    with _spans.span("sources.precompute", nsrc=op.num) as sp:
+        st = interp_stencil(op, grid, interp)
 
-    if discover_by_injection:
-        t0 = next((t for t in range(wavelets.shape[0])
-                   if np.all(wavelets[t] != 0.0)), None)
-        if t0 is None:
-            pts = affected_points(st)
+        if discover_by_injection:
+            t0 = next((t for t in range(wavelets.shape[0])
+                       if np.all(wavelets[t] != 0.0)), None)
+            if t0 is None:
+                pts = affected_points(st)
+            else:
+                pts = affected_points_by_injection(st, grid, wavelets[t0])
         else:
-            pts = affected_points_by_injection(st, grid, wavelets[t0])
-    else:
-        pts = affected_points(st)
+            pts = affected_points(st)
 
-    npts = pts.shape[0]
-    sm = np.zeros(grid.shape, np.uint8)
-    sid = np.full(grid.shape, -1, np.int32)
-    sm[tuple(pts.T)] = 1
-    sid[tuple(pts.T)] = np.arange(npts, dtype=np.int32)
+        npts = pts.shape[0]
+        sm = np.zeros(grid.shape, np.uint8)
+        sid = np.full(grid.shape, -1, np.int32)
+        sm[tuple(pts.T)] = 1
+        sid[tuple(pts.T)] = np.arange(npts, dtype=np.int32)
 
-    # Listing 3: decompose wavelets onto affected points.  A point shared by
-    # several sources accumulates all their weighted wavelets (the paper's
-    # "points being affected by more than one source" case).
-    ids = sid[tuple(st.indices.reshape(-1, grid.ndim).T)]      # (num*2^d,)
-    w = st.weights.reshape(-1)                                  # (num*2^d,)
-    src_ids = np.repeat(np.arange(op.num), st.indices.shape[1])
-    nt = wavelets.shape[0]
-    # Accumulate weighted wavelets per affected point; np.add.at handles
-    # repeated ids (several sources hitting the same grid point).
-    src_dcmp = np.zeros((nt, npts), np.float64)
-    contrib = wavelets[:, src_ids] * w[None, :]                # (nt, entries)
-    np.add.at(src_dcmp.T, ids, contrib.T)
-
-    return GriddedSources(
-        sm=jnp.asarray(sm),
-        sid=jnp.asarray(sid),
-        points=jnp.asarray(pts),
-        src_dcmp=jnp.asarray(src_dcmp, dtype=dtype),
-    )
+        # Listing 3: decompose wavelets onto affected points.  A point
+        # shared by several sources accumulates all their weighted wavelets
+        # (the paper's "points being affected by more than one source").
+        ids = sid[tuple(st.indices.reshape(-1, grid.ndim).T)]  # (num*2^d,)
+        w = st.weights.reshape(-1)                              # (num*2^d,)
+        src_ids = np.repeat(np.arange(op.num), st.indices.shape[1])
+        nt = wavelets.shape[0]
+        # Accumulate weighted wavelets per affected point; np.add.at handles
+        # repeated ids (several sources hitting the same grid point).
+        src_dcmp = np.zeros((nt, npts), np.float64)
+        contrib = wavelets[:, src_ids] * w[None, :]            # (nt, entries)
+        np.add.at(src_dcmp.T, ids, contrib.T)
+        # the dense host arrays this builds (the grid-sized SM/SID dominate)
+        sp.set(npts=npts, sm_bytes=sm.nbytes, sid_bytes=sid.nbytes,
+               src_dcmp_bytes=src_dcmp.nbytes)
+        return GriddedSources(
+            sm=jnp.asarray(sm),
+            sid=jnp.asarray(sid),
+            points=jnp.asarray(pts),
+            src_dcmp=jnp.asarray(src_dcmp, dtype=dtype),
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -357,9 +361,12 @@ class GriddedReceivers(NamedTuple):
 def precompute_receivers(op: SparseOperator, grid: Grid,
                          dtype=jnp.float32,
                          interp: InterpSpec = LINEAR) -> GriddedReceivers:
-    st = interp_stencil(op, grid, interp)
-    return GriddedReceivers(jnp.asarray(st.indices),
-                            jnp.asarray(st.weights, dtype=dtype))
+    with _spans.span("sources.precompute_receivers", nrec=op.num) as sp:
+        st = interp_stencil(op, grid, interp)
+        sp.set(npts=st.weights.size, indices_bytes=st.indices.nbytes,
+               weights_bytes=st.weights.nbytes)
+        return GriddedReceivers(jnp.asarray(st.indices),
+                                jnp.asarray(st.weights, dtype=dtype))
 
 
 def interpolate(u: jnp.ndarray, r: GriddedReceivers) -> jnp.ndarray:
@@ -381,13 +388,15 @@ class TileSourceTable(NamedTuple):
     (full z), entries are affected points inside the centre region with
     coordinates local to the tile's *window* origin (centre minus halo).
 
-    nnz:    (n_tiles,) int32 — valid entries per tile (0 -> kernel skips).
+    nnz:    (n_tiles,) int32 — valid entries per tile, a host (numpy)
+            array: the kernel never reads it, and counting the live slots
+            from it syncs nothing.
     coords: (n_tiles, cap, 3) int32 — window-local (x, y, z), padded 0.
     sid:    (n_tiles, cap) int32 — SID per entry, padded -1.
     scale:  (n_tiles, cap) float32 — per-point physical factor, padded 0.
     """
 
-    nnz: jnp.ndarray
+    nnz: np.ndarray
     coords: jnp.ndarray
     sid: jnp.ndarray
     scale: jnp.ndarray
@@ -440,7 +449,7 @@ def tile_source_tables(g: GriddedSources, grid_shape: Tuple[int, int, int],
         coords[tt, k] = (pts[p, 0] - ox, pts[p, 1] - oy, pts[p, 2])
         sid_t[tt, k] = p
         scale_t[tt, k] = scl[p]
-    return TileSourceTable(jnp.asarray(fill), jnp.asarray(coords),
+    return TileSourceTable(fill, jnp.asarray(coords),
                            jnp.asarray(sid_t), jnp.asarray(scale_t))
 
 
@@ -452,7 +461,7 @@ class TileReceiverTable(NamedTuple):
     sample — the host segment-sums partials by receiver id afterwards.
     """
 
-    nnz: jnp.ndarray        # (n_tiles,)
+    nnz: np.ndarray         # (n_tiles,) host-side, as TileSourceTable's
     coords: jnp.ndarray     # (n_tiles, cap, 3) window-local
     rid: jnp.ndarray        # (n_tiles, cap) receiver id, padded -1
     weight: jnp.ndarray     # (n_tiles, cap) float32
@@ -483,7 +492,7 @@ def tile_receiver_tables(r: GriddedReceivers, grid_shape: Tuple[int, int, int],
         coords[tt, k] = (idx[p, 0] - ox, idx[p, 1] - oy, idx[p, 2])
         rid_t[tt, k] = rids[p]
         w_t[tt, k] = w[p]
-    return TileReceiverTable(jnp.asarray(fill), jnp.asarray(coords),
+    return TileReceiverTable(fill, jnp.asarray(coords),
                              jnp.asarray(rid_t), jnp.asarray(w_t))
 
 

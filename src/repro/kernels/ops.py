@@ -87,6 +87,31 @@ def build_tables(spec: ker.TBKernelSpec,
     return src_tab, rec_tab
 
 
+def slot_fill(spec: ker.TBKernelSpec, nt: int, tables, rtables
+              ) -> Dict[str, list]:
+    """Span attributes for the sparse terms' slot fill.  `tables` is the
+    (src_tab, rec_tab) pair of the depth-`spec.T` tiles, serving
+    `(nt // T)·T` steps; `rtables` the remainder tile's, serving
+    `nt % T`.  Per pair: live entries (the tables' host-side `nnz`), the
+    `n_tiles x cap` slots the kernel computes every step, and the steps.
+    A missing table stands for the one-slot dummy the kernel computes
+    over in its place."""
+    ntx, nty = spec.ntiles
+    rem = nt % spec.T
+    out = {k: [] for k in ("src_live", "src_slots", "rec_live",
+                           "rec_slots", "steps")}
+    for pair, steps in ((tables, nt - rem), (rtables, rem)):
+        if steps == 0:
+            continue
+        for kind, tab in zip(("src", "rec"), pair):
+            live = 0 if tab is None else int(np.sum(tab.nnz))
+            cap = 1 if tab is None else tab.coords.shape[1]
+            out[kind + "_live"].append(live)
+            out[kind + "_slots"].append(ntx * nty * cap)
+        out["steps"].append(steps)
+    return out
+
+
 def _src_vals_for_tile(src_dcmp: jnp.ndarray, src_tab, t0, T: int):
     """(ntiles, T, cap) injection values for time tile starting at t0.
 
@@ -204,9 +229,12 @@ def _run_time_tile_impl(spec, physics, state, param_pads, src_dcmp,
     h = spec.halo
     ntx, nty = spec.ntiles
     ntiles = ntx * nty
+    # fixed scope names for the device trace, entered whether or not
+    # telemetry is on (unlike `annotate`'s)
     if src_tab is not None:
         s_coords = src_tab.coords
-        s_vals = _src_vals_for_tile(src_dcmp, src_tab, t0, spec.T)
+        with jax.named_scope("ops.src_vals"):
+            s_vals = _src_vals_for_tile(src_dcmp, src_tab, t0, spec.T)
     else:
         s_coords, s_vals = _dummy_tables(ntiles, spec.T)
     s_vals = s_vals.astype(spec.dtype)
@@ -217,7 +245,8 @@ def _run_time_tile_impl(spec, physics, state, param_pads, src_dcmp,
         r_w = jnp.zeros((ntiles, 1), jnp.float32)
     r_w = r_w.astype(spec.dtype)
 
-    state_pads = tuple(_pad_xy(f, h, "constant") for f in state)
+    with jax.named_scope("ops.state_pad"):
+        state_pads = tuple(_pad_xy(f, h, "constant") for f in state)
     if executor == "pallas":
         new_state, rec_part = ker.tb_time_tile(
             spec, physics, state_pads, param_pads, s_coords, s_vals,
@@ -229,7 +258,8 @@ def _run_time_tile_impl(spec, physics, state, param_pads, src_dcmp,
     else:
         raise ValueError(f"unknown executor {executor!r}")
     if rec_tab is not None:
-        rec = combine_rec_partials(rec_part, rec_tab, nrec)
+        with jax.named_scope("ops.rec_combine"):
+            rec = combine_rec_partials(rec_part, rec_tab, nrec)
     else:
         rec = jnp.zeros((spec.T, 0, physics.rec_channels), spec.dtype)
     return new_state, rec
@@ -386,7 +416,7 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
     # tables depend only on tile/halo/dt (not the caps), so build them once
     # and size the spec's static caps from what came back
     with _spans.span("ops.tables", physics=physics.name, nt=nt,
-                     T=plan.T):
+                     T=plan.T) as sp:
         spec = specced(1, 1)
         src_tab, rec_tab = build_tables(spec, g, receivers, params, physics)
         src_cap = src_tab.cap if src_tab is not None else 1
@@ -413,13 +443,19 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
                 T=rem)
             rparam_pads = tuple(_pad_xy(params[f], rspec.halo, "edge")
                                 for f in physics.param_fields)
+        if _spans.active():
+            sp.set(**slot_fill(spec, nt, (src_tab, rec_tab),
+                               (rsrc_tab, rrec_tab)))
 
     with _spans.span("ops.propagate", physics=physics.name, nt=nt,
                      T=spec.T, executor=executor) as sp:
-        carry, recs = tb_propagate_prepared(
-            physics, nt, spec, rspec, state, param_pads, rparam_pads,
-            src_dcmp, src_tab, rec_tab, rsrc_tab, rrec_tab, nrec,
-            interpret=interpret, executor=executor)
+        # the eager driver traces, lowers and compiles (or loads) its scan
+        # and remainder call on every call: `compiles` counts them
+        with _spans.span("ops.dispatch", count_compiles=True):
+            carry, recs = tb_propagate_prepared(
+                physics, nt, spec, rspec, state, param_pads, rparam_pads,
+                src_dcmp, src_tab, rec_tab, rsrc_tab, rrec_tab, nrec,
+                interpret=interpret, executor=executor)
         sp.sync((carry, recs))
     if receivers is None:
         recs = None

@@ -399,6 +399,8 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
         # the budget the autotuner planned this tile against
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET),
         interpret=resolve_interpret(interpret),
+        # a stable name for the kernel's events on the device trace
+        name="tb_time_tile",
     )(*hbm, *rows)
     rec = outs[ns].reshape(ntx, nty, spec.T, nch, spec.rec_cap)
     return tuple(outs[:ns]), jnp.swapaxes(rec, 3, 4)

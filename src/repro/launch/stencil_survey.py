@@ -142,7 +142,7 @@ def main():
                          "*_tb_propagate call")
     ap.add_argument("--telemetry", nargs="?", const="", default=None,
                     metavar="PATH",
-                    help="enable telemetry spans (sweep/compile/dispatch/"
+                    help="enable telemetry spans (sweep/prep/dispatch/"
                          "readback) and export the Chrome trace to PATH "
                          "(default results/telemetry_survey.json)")
     args = ap.parse_args()

@@ -178,11 +178,6 @@ class SurveyEngine:
                         physics, self.shape[2], self.order, cache=self.cache,
                         key_extra={"grid_shape": list(self.shape),
                                    "use": "survey-single-device"}, **kw)
-        else:
-            # explicit plan: the sweep span still marks the (free) consult
-            # so the required span taxonomy is complete either way
-            with _spans.span("survey.sweep", physics=physics, skipped=True):
-                pass
         self._plan_seconds = time.perf_counter() - t_plan
         self._plan_claimed = False
         self.plan = plan
@@ -237,10 +232,10 @@ class SurveyEngine:
         gr = src_mod.precompute_receivers(
             src_mod.SparseOperator(shot.rec_coords), self.grid,
             interp=self.interp)
-        scale = np.asarray(
-            self.physics.inject_scale(self.params, g, self.dt), np.float32)
-        dcmp = np.zeros((self.nt, npts_cap), np.float32)
-        dcmp[:, :g.npts] = np.asarray(g.src_dcmp)[:self.nt]
+        # the decomposed wavelets back from the device, padded to the cap
+        with _spans.span("survey.dcmp", shot=shot.shot_id):
+            dcmp = np.zeros((self.nt, npts_cap), np.float32)
+            dcmp[:, :g.npts] = np.asarray(g.src_dcmp)[:self.nt]
 
         def tabs(s):
             st = src_mod.tile_source_tables(
@@ -250,10 +245,17 @@ class SurveyEngine:
                                               s.halo, cap=rec_cap)
             return st, rt
 
-        src_tab, rec_tab = tabs(spec)
-        rsrc_tab = rrec_tab = None
-        if rspec is not None:
-            rsrc_tab, rrec_tab = tabs(rspec)
+        with _spans.span("survey.tables", shot=shot.shot_id) as sp:
+            scale = np.asarray(
+                self.physics.inject_scale(self.params, g, self.dt),
+                np.float32)
+            src_tab, rec_tab = tabs(spec)
+            rsrc_tab = rrec_tab = None
+            if rspec is not None:
+                rsrc_tab, rrec_tab = tabs(rspec)
+            if _spans.active():
+                sp.set(**ops_mod.slot_fill(spec, self.nt, (src_tab, rec_tab),
+                                           (rsrc_tab, rrec_tab)))
         return _ShotArrays(jnp.asarray(dcmp), src_tab, rec_tab,
                            rsrc_tab, rrec_tab)
 
@@ -337,40 +339,45 @@ class SurveyEngine:
                 if return_wavefields:
                     fields[i] = tuple(np.asarray(f[row]) for f in st)
 
-        for key, bucket in buckets.items():
-            fn, spec, rspec = self._executable(key)
-            param_pads = self._pads_for(spec.halo)
-            rparam_pads = (self._pads_for(rspec.halo)
-                           if rspec is not None else None)
-            for lo in range(0, len(bucket), self.bucket_cap):
-                chunk = bucket.shots[lo:lo + self.bucket_cap]
-                idxs = bucket.indices[lo:lo + self.bucket_cap]
-                with _spans.span("survey.prep", bucket=key, n=len(chunk)):
-                    preps = [self._prep_shot(s, key, spec, rspec)
-                             for s in chunk]
-                    batch = self._stack_batch(preps, self.bucket_cap)
-                # the dispatch wall includes trace+compile the first time
-                # a bucket runs: attribute it by watching the per-bucket
-                # trace counter around the call (cold/warm satellite)
-                traced_before = self.trace_counts[key]
-                t0 = time.perf_counter()
-                state_b, recs_b = fn(param_pads, rparam_pads, batch)
-                d = time.perf_counter() - t0
-                _spans.add_span("survey.dispatch", t0, d, bucket=key)
-                if self.trace_counts[key] > traced_before:
-                    compile_seconds += d
-                    _spans.add_span("survey.compile", t0, d, bucket=key)
-                    self.metrics.histogram("survey.compile_s").observe(d)
-                else:
-                    self.metrics.histogram("survey.dispatch_s").observe(d)
-                n_batches += 1
-                if pending is not None:
-                    collect(pending)
-                pending = (idxs, recs_b, state_b)
-        if pending is not None:
-            collect(pending)
+        with _spans.span("survey.run", shots=len(shots)):
+            for key, bucket in buckets.items():
+                fn, spec, rspec = self._executable(key)
+                param_pads = self._pads_for(spec.halo)
+                rparam_pads = (self._pads_for(rspec.halo)
+                               if rspec is not None else None)
+                for lo in range(0, len(bucket), self.bucket_cap):
+                    chunk = bucket.shots[lo:lo + self.bucket_cap]
+                    idxs = bucket.indices[lo:lo + self.bucket_cap]
+                    with _spans.span("survey.prep", bucket=key,
+                                     n=len(chunk)):
+                        preps = [self._prep_shot(s, key, spec, rspec)
+                                 for s in chunk]
+                        with _spans.span("survey.stack", n=len(preps)):
+                            batch = self._stack_batch(preps,
+                                                      self.bucket_cap)
+                    # the dispatch wall includes trace+compile the first
+                    # time a bucket runs: attribute it by watching the
+                    # per-bucket trace counter around the call
+                    traced_before = self.trace_counts[key]
+                    t0 = time.perf_counter()
+                    with _spans.span("survey.dispatch", count_compiles=True,
+                                     bucket=key) as sp:
+                        state_b, recs_b = fn(param_pads, rparam_pads, batch)
+                        traced = self.trace_counts[key] > traced_before
+                        sp.set(traced=traced)
+                    d = time.perf_counter() - t0
+                    if traced:
+                        compile_seconds += d
+                        self.metrics.histogram("survey.compile_s").observe(d)
+                    else:
+                        self.metrics.histogram("survey.dispatch_s").observe(d)
+                    n_batches += 1
+                    if pending is not None:
+                        collect(pending)
+                    pending = (idxs, recs_b, state_b)
+            if pending is not None:
+                collect(pending)
         seconds = time.perf_counter() - t_start
-        _spans.add_span("survey.run", t_start, seconds, shots=len(shots))
 
         # cold = planning (first run only) + jit traces; warm = the
         # steady-state execution wall the throughput numbers must use, so

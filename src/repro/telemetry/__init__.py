@@ -2,7 +2,7 @@
 
 Three layers, one import:
 
-  spans    nestable wall-clock spans + Chrome-trace/flat-JSON export
+  spans    nestable wall-clock spans + Chrome-trace export
            (`span`, `annotate`, `enable`, `collector`);
   metrics  named counters/gauges/histograms behind a process registry
            (`registry().counter("plan_cache.hits").inc()`);
@@ -19,15 +19,14 @@ from repro.telemetry import metrics  # noqa: F401  (submodule re-export)
 from repro.telemetry.drift import (DEFAULT_PATH as DRIFT_PATH,  # noqa: F401
                                    DriftLedger, last_drift,
                                    predict_hier_terms, predict_plan_terms)
-from repro.telemetry.metrics import (MetricsRegistry,  # noqa: F401
-                                     merge_snapshots, registry)
+from repro.telemetry.metrics import MetricsRegistry, registry  # noqa: F401
 from repro.telemetry.spans import (SpanCollector, active,  # noqa: F401
-                                   add_span, annotate, collector, disable,
-                                   enable, span)
+                                   annotate, collector, disable, enable,
+                                   span)
 
 __all__ = [
     "DRIFT_PATH", "DriftLedger", "MetricsRegistry", "SpanCollector",
-    "active", "add_span", "annotate", "collector", "disable", "enable",
-    "last_drift", "merge_snapshots", "metrics", "predict_hier_terms",
+    "active", "annotate", "collector", "disable", "enable",
+    "last_drift", "metrics", "predict_hier_terms",
     "predict_plan_terms", "registry", "span",
 ]

@@ -12,9 +12,7 @@ vocabulary:
 Everything is thread-safe and always-on (a counter bump is one lock +
 one add — unlike spans there is no measurable cost to leaving these
 live), so subsystems keep exact counts whether or not `--telemetry`
-asked for an export.  `snapshot()` is the serialization boundary;
-`merge_snapshots` folds snapshots from multiple runs/processes
-(counters sum, gauges last-wins, histograms merge moments).
+asked for an export.  `snapshot()` is the serialization boundary.
 """
 from __future__ import annotations
 
@@ -159,34 +157,6 @@ class MetricsRegistry:
             self._metrics.clear()
 
 
-def merge_snapshots(a: dict, b: dict) -> dict:
-    """Fold two `snapshot()` dicts: counters SUM, gauges LAST-WINS (b over
-    a), histograms merge count/total/min/max exactly (mean recomputed)."""
-    out = {"counters": dict(a.get("counters", {})),
-           "gauges": dict(a.get("gauges", {})),
-           "histograms": {k: dict(v)
-                          for k, v in a.get("histograms", {}).items()}}
-    for name, v in b.get("counters", {}).items():
-        out["counters"][name] = out["counters"].get(name, 0) + v
-    out["gauges"].update(b.get("gauges", {}))
-    for name, h in b.get("histograms", {}).items():
-        cur = out["histograms"].get(name)
-        if cur is None or not cur["count"]:
-            out["histograms"][name] = dict(h)
-            continue
-        if not h["count"]:
-            continue
-        merged = {
-            "count": cur["count"] + h["count"],
-            "total": cur["total"] + h["total"],
-            "min": min(cur["min"], h["min"]),
-            "max": max(cur["max"], h["max"]),
-        }
-        merged["mean"] = merged["total"] / merged["count"]
-        out["histograms"][name] = merged
-    return out
-
-
 _REGISTRY: Optional[MetricsRegistry] = None
 _REGISTRY_LOCK = threading.Lock()
 
@@ -201,5 +171,4 @@ def registry() -> MetricsRegistry:
     return _REGISTRY
 
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "merge_snapshots", "registry"]
+__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "registry"]

@@ -24,12 +24,19 @@ Two measurement regimes, both first-class:
 
 With `enable(jax_profiler=True)` (or ``REPRO_TELEMETRY_JAX=1``) every span
 also enters `jax.profiler.TraceAnnotation`, so the same names land on the
-TensorBoard/Perfetto host timeline when a `jax.profiler.trace` is active.
+TensorBoard/Perfetto host timeline when a `jax.profiler.trace` is active,
+on the device trace's clock.
 
-Exporters: `chrome_trace()` emits the Chrome ``chrome://tracing`` /
+Each record names its `parent`, the span open around it on the same
+thread, so a layer's self time is its span minus its children.  A span
+opened with `count_compiles=True` records as `compiles` the programs JAX
+compiled or loaded from its persistent cache while it was open (one
+`jax.monitoring` listener per process, counting only while a collector
+is installed).
+
+Exporter: `chrome_trace()` emits the Chrome ``chrome://tracing`` /
 Perfetto JSON (phase-"X" complete events, microsecond timestamps);
-`flat()` a plain list of span dicts; `export(path)` / `export_flat(path)`
-write them.
+`export(path)` writes it.
 """
 from __future__ import annotations
 
@@ -42,26 +49,26 @@ from typing import Any, Dict, List, Optional
 
 class SpanRecord:
     """One completed span (durations in seconds, starts relative to the
-    collector's epoch so traces from one process line up)."""
+    collector's epoch so traces from one process line up).  `parent` is
+    the name of the span open around it on the same thread, else None."""
 
-    __slots__ = ("name", "start", "dur", "depth", "tid", "attrs")
+    __slots__ = ("name", "start", "dur", "depth", "tid", "attrs", "parent")
 
     def __init__(self, name: str, start: float, dur: float, depth: int,
-                 tid: int, attrs: Dict[str, Any]):
+                 tid: int, attrs: Dict[str, Any],
+                 parent: Optional[str] = None):
         self.name = name
         self.start = start
         self.dur = dur
         self.depth = depth
         self.tid = tid
         self.attrs = attrs
-
-    def to_dict(self) -> dict:
-        return {"name": self.name, "start_s": self.start, "dur_s": self.dur,
-                "depth": self.depth, "tid": self.tid, "attrs": self.attrs}
+        self.parent = parent
 
     def __repr__(self):
         return (f"SpanRecord({self.name!r}, start={self.start:.6f}, "
-                f"dur={self.dur:.6f}, depth={self.depth})")
+                f"dur={self.dur:.6f}, depth={self.depth}, "
+                f"parent={self.parent!r})")
 
 
 def _jsonable(v):
@@ -87,10 +94,11 @@ class _Span:
     """The live context-manager object `span()` yields while collecting."""
 
     __slots__ = ("_collector", "name", "attrs", "_sync", "_t0",
-                 "_cancelled", "_annos")
+                 "_cancelled", "_annos", "_compiles0")
 
     def __init__(self, collector: "SpanCollector", name: str,
-                 device_sync=None, attrs: Optional[dict] = None):
+                 device_sync=None, attrs: Optional[dict] = None,
+                 count_compiles: bool = False):
         self._collector = collector
         self.name = name
         self.attrs = attrs or {}
@@ -98,6 +106,7 @@ class _Span:
         self._t0 = None
         self._cancelled = False
         self._annos = None
+        self._compiles0 = 0 if count_compiles else None
 
     def sync(self, value):
         """Register a pytree to `jax.block_until_ready` at span exit (so
@@ -105,6 +114,10 @@ class _Span:
         value unchanged for inline use."""
         self._sync.append(value)
         return value
+
+    def set(self, **attrs):
+        """Add attributes known only inside the span (counts, sizes)."""
+        self.attrs.update(attrs)
 
     def cancel(self):
         """Drop this span: nothing is recorded at exit."""
@@ -116,7 +129,9 @@ class _Span:
             import jax
             self._annos = jax.profiler.TraceAnnotation(self.name)
             self._annos.__enter__()
-        c._enter()
+        c._enter(self.name)
+        if self._compiles0 is not None:
+            self._compiles0 = c.compiles
         self._t0 = time.perf_counter()
         return self
 
@@ -126,12 +141,20 @@ class _Span:
             for v in self._sync:
                 jax.block_until_ready(v() if callable(v) else v)
         t1 = time.perf_counter()
-        depth = self._collector._exit()
+        c = self._collector
+        c._exit()
         if self._annos is not None:
             self._annos.__exit__(exc_type, exc, tb)
+        if self._compiles0 is not None:
+            self.attrs["compiles"] = c.compiles - self._compiles0
         if not self._cancelled:
-            self._collector.add_span(self.name, self._t0, t1 - self._t0,
-                                     nest_depth=depth, **self.attrs)
+            stack = c._open()
+            rec = SpanRecord(self.name, self._t0 - c.epoch, t1 - self._t0,
+                             len(stack), threading.get_ident(),
+                             _jsonable(self.attrs),
+                             stack[-1] if stack else None)
+            with c._lock:
+                c._records.append(rec)
         return False
 
 
@@ -142,6 +165,9 @@ class _NullSpan:
 
     def sync(self, value):
         return value
+
+    def set(self, **attrs):
+        pass
 
     def cancel(self):
         pass
@@ -155,55 +181,54 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
+# the event JAX records around every compile-or-load of a program: it wraps
+# the persistent-cache lookup, so it fires on a cache load too (the cache's
+# own retrieval event fires inside it, and is not counted again)
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
 
 class SpanCollector:
     """Thread-safe in-process span store.
 
-    Spans nest per thread (a thread-local depth stack); records carry
-    (name, start, dur, depth, tid, attrs) and export either as a flat
-    JSON list or as a Chrome-trace/Perfetto event stream.
+    Spans nest per thread (a thread-local stack of open span names);
+    records carry (name, start, dur, depth, tid, attrs, parent) and export
+    as a Chrome-trace/Perfetto event stream.  `compiles` counts the
+    programs compiled or loaded in the process since the collector was
+    installed.
     """
 
     def __init__(self, jax_profiler: bool = False):
         self.jax_profiler = bool(jax_profiler)
         self.epoch = time.perf_counter()
+        self.compiles = 0
         self._records: List[SpanRecord] = []
         self._lock = threading.Lock()
         self._local = threading.local()
 
     # --- nesting bookkeeping ------------------------------------------------
 
-    def _depth(self) -> int:
-        return getattr(self._local, "depth", 0)
+    def _open(self) -> List[str]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
-    def _enter(self) -> int:
-        d = self._depth()
-        self._local.depth = d + 1
-        return d
+    def _enter(self, name: str):
+        self._open().append(name)
 
-    def _exit(self) -> int:
-        d = self._depth() - 1
-        self._local.depth = d
-        return d
+    def _exit(self):
+        self._open().pop()
+
+    def _count_compile(self):
+        with self._lock:
+            self.compiles += 1
 
     # --- recording ----------------------------------------------------------
 
-    def span(self, name: str, device_sync=None, **attrs) -> _Span:
-        return _Span(self, name, device_sync=device_sync, attrs=attrs)
-
-    def add_span(self, name: str, start: float, dur: float,
-                 nest_depth: Optional[int] = None, **attrs):
-        """Record an already-measured interval (`start` from
-        `time.perf_counter()`).  The manual twin of `span()` — used where
-        the instrumented code only knows after the fact what the interval
-        was (e.g. a dispatch call that turned out to trace).  `nest_depth`
-        is the nesting level (default: the thread's current depth) — named
-        to stay clear of common span attrs like `depth=`."""
-        rec = SpanRecord(name, start - self.epoch, dur,
-                         self._depth() if nest_depth is None else nest_depth,
-                         threading.get_ident(), _jsonable(attrs))
-        with self._lock:
-            self._records.append(rec)
+    def span(self, name: str, device_sync=None, count_compiles=False,
+             **attrs) -> _Span:
+        return _Span(self, name, device_sync=device_sync, attrs=attrs,
+                     count_compiles=count_compiles)
 
     # --- reading / exporting ------------------------------------------------
 
@@ -217,9 +242,6 @@ class SpanCollector:
     def clear(self):
         with self._lock:
             self._records.clear()
-
-    def flat(self) -> List[dict]:
-        return [r.to_dict() for r in self.records()]
 
     def chrome_trace(self) -> dict:
         """Chrome ``chrome://tracing`` / Perfetto JSON object format:
@@ -243,29 +265,34 @@ class SpanCollector:
             json.dump(self.chrome_trace(), f, indent=1)
         return path
 
-    def export_flat(self, path: str) -> str:
-        d = os.path.dirname(os.path.abspath(path))
-        os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(self.flat(), f, indent=1)
-        return path
-
 
 # ---------------------------------------------------------------------------
 # Module-level switchboard (the API call sites use)
 # ---------------------------------------------------------------------------
 
 _ACTIVE: Optional[SpanCollector] = None
+_LISTENING = False
+
+
+def _on_event_duration(event: str, secs: float, **kw):
+    c = _ACTIVE
+    if c is not None and event == BACKEND_COMPILE:
+        c._count_compile()
 
 
 def enable(jax_profiler: Optional[bool] = None) -> SpanCollector:
     """Install (and return) a fresh process-wide collector.  `jax_profiler`
     defaults from ``REPRO_TELEMETRY_JAX`` (truthy -> every span also enters
-    `jax.profiler.TraceAnnotation`)."""
-    global _ACTIVE
+    `jax.profiler.TraceAnnotation`).  The first call registers the
+    process's one compile listener."""
+    global _ACTIVE, _LISTENING
     if jax_profiler is None:
         jax_profiler = os.environ.get("REPRO_TELEMETRY_JAX", "") not in \
             ("", "0", "false")
+    if not _LISTENING:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_event_duration)
+        _LISTENING = True
     _ACTIVE = SpanCollector(jax_profiler=jax_profiler)
     return _ACTIVE
 
@@ -283,19 +310,14 @@ def active() -> bool:
     return _ACTIVE is not None
 
 
-def span(name: str, device_sync=None, **attrs):
-    """A timing span — no-op (shared null object) when telemetry is off."""
+def span(name: str, device_sync=None, count_compiles=False, **attrs):
+    """A timing span — no-op (shared null object) when telemetry is off.
+    `count_compiles=True` records the compiles seen while it was open."""
     c = _ACTIVE
     if c is None:
         return _NULL
-    return c.span(name, device_sync=device_sync, **attrs)
-
-
-def add_span(name: str, start: float, dur: float, **attrs):
-    """Manually record an interval on the active collector (no-op off)."""
-    c = _ACTIVE
-    if c is not None:
-        c.add_span(name, start, dur, **attrs)
+    return c.span(name, device_sync=device_sync,
+                  count_compiles=count_compiles, **attrs)
 
 
 class _Annotate:
@@ -331,5 +353,5 @@ def annotate(name: str, **attrs):
     return _Annotate(c.span(name, trace_region=True, **attrs))
 
 
-__all__ = ["SpanCollector", "SpanRecord", "enable", "disable", "collector",
-           "active", "span", "add_span", "annotate"]
+__all__ = ["BACKEND_COMPILE", "SpanCollector", "SpanRecord", "enable",
+           "disable", "collector", "active", "span", "annotate"]

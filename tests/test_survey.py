@@ -193,6 +193,68 @@ def test_run_stats_keys_and_cold_warm_split():
     assert s2["cold_seconds"] == 0.0
 
 
+def test_survey_spans_reach_trace_annotation(monkeypatch):
+    """Every span a survey run records enters the profiler's
+    `TraceAnnotation`; `survey.prep` holds the sparse-operator precompute,
+    the wavelets' readback, the table binning (with its slot fill) and the
+    batch stacking; the dispatch that traced carries the compiles it saw,
+    a warm one none."""
+    import jax
+
+    from repro.telemetry import spans as tsp
+
+    annotated = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            annotated.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    grid, dt, params = _case("acoustic", n=8)
+    plan = TBPlan(tile=(8, 8), T=2, radius=2)
+    shots = [_shot(grid, dt, 1, 3, seed=1), _shot(grid, dt, 1, 3, seed=2)]
+    engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
+                          executor="jnp", plan=plan, plan_cache=PlanCache(),
+                          bucket_cap=2)
+    c = tsp.enable(jax_profiler=True)
+    try:
+        engine.run(shots)
+        engine.run(shots)
+    finally:
+        tsp.disable()
+    recs = c.records()
+    names = {r.name for r in recs}
+    assert names == {"survey.run", "survey.prep", "survey.dcmp",
+                     "survey.tables", "survey.stack", "survey.dispatch",
+                     "survey.readback",
+                     "sources.precompute", "sources.precompute_receivers",
+                     "ops.tile_pass"}
+    assert names <= set(annotated)
+    parent = {r.name: r.parent for r in recs}
+    for child in ("survey.dcmp", "survey.tables", "survey.stack",
+                  "sources.precompute", "sources.precompute_receivers"):
+        assert parent[child] == "survey.prep", child
+    assert parent["survey.prep"] == parent["survey.dispatch"] == "survey.run"
+    first, warm = [r.attrs for r in recs if r.name == "survey.dispatch"]
+    assert first["traced"] is True and first["compiles"] >= 1
+    assert warm["traced"] is False and warm["compiles"] == 0
+    _, src_cap, rec_cap = engine._caps((1, pad_count(3)))
+    tables = [r.attrs for r in recs if r.name == "survey.tables"]
+    assert len(tables) == 2 * len(shots)
+    for t in tables:   # one (8, 8) tile; main T 2 and remainder T 1
+        assert t["steps"] == [2, 1]
+        assert t["src_slots"] == [src_cap] * 2
+        assert t["rec_slots"] == [rec_cap] * 2
+        assert all(0 < n <= src_cap for n in t["src_live"])
+        assert all(0 < n <= rec_cap for n in t["rec_live"])
+
+
 def test_sharded_route_matches_vmap_route():
     """`run_sharded` (shot round-robin through `sharded_tb_propagate` on a
     1x1 mesh) must produce the same traces as the vmapped single-device

@@ -2,11 +2,14 @@
 
 Acceptance points under test:
 
-  * spans nest per thread, record in exit order with correct depths, and
-    export a schema-valid Chrome-trace / Perfetto event stream;
+  * spans nest per thread, record in exit order with correct depths and
+    parents, and export a schema-valid Chrome-trace / Perfetto event
+    stream;
   * the whole subsystem is a shared no-op object when disabled;
-  * metric snapshots merge with counter-sum / gauge-last / histogram-
-    moment semantics;
+  * a propagate's spans (sparse-operator precompute, table binning with
+    its slot fill, the dispatch with its compile count) all reach the
+    profiler's timeline, and its tile pass lowers with fixed scope and
+    kernel names whether or not telemetry is on;
   * `predict_plan_terms` reproduces the autotune sweep's own arithmetic
     for an executed plan (same `TBPlan` pricing methods, same hardware
     defaults), and the drift ledger's ratios/geomeans are exact on
@@ -19,6 +22,7 @@ import os
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from repro import telemetry as tele
@@ -46,7 +50,6 @@ def test_disabled_spans_are_shared_noop():
     assert s is tsp.span("y") is tsp.annotate("z")  # one shared object
     with s as live:
         assert live.sync("payload") == "payload"
-    tsp.add_span("manual", 0.0, 1.0)  # silently dropped
 
 
 def test_span_nesting_order_and_depth(coll):
@@ -72,11 +75,14 @@ def test_span_cancel_and_manual_add(coll):
     with tsp.span("dropped") as sp:
         sp.cancel()
     t0 = time.perf_counter()
-    tsp.add_span("manual", t0, 0.25, bucket=(1, 2))
+    with tsp.span("kept", bucket=(1, 2)) as sp:
+        sp.set(n=np.int64(3))
     recs = coll.records()
-    assert [r.name for r in recs] == ["manual"]
-    assert recs[0].dur == 0.25
-    assert recs[0].attrs == {"bucket": [1, 2]}  # tuple made JSON-able
+    assert [r.name for r in recs] == ["kept"]
+    assert 0.0 <= recs[0].dur <= time.perf_counter() - t0
+    # tuples and numpy scalars made JSON-able
+    assert recs[0].attrs == {"bucket": [1, 2], "n": 3}
+    assert type(recs[0].attrs["n"]) is int
 
 
 def test_span_nesting_is_per_thread(coll):
@@ -96,6 +102,26 @@ def test_span_nesting_is_per_thread(coll):
     assert by_name["thread.inner"].depth == 0  # not nested under main's
     assert by_name["main.outer"].depth == 0
     assert by_name["thread.inner"].tid != by_name["main.outer"].tid
+
+
+def test_span_parent_nested_and_per_thread(coll):
+    def worker():
+        with tsp.span("thread.outer"):
+            with tsp.span("thread.inner"):
+                pass
+
+    with tsp.span("main.outer"):
+        with tsp.span("main.inner"):
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=30)
+        with tsp.span("main.after"):
+            pass
+    assert not t.is_alive()
+    assert {r.name: r.parent for r in coll.records()} == {
+        "main.outer": None, "main.inner": "main.outer",
+        "main.after": "main.outer",
+        "thread.outer": None, "thread.inner": "thread.outer"}
 
 
 def test_device_sync_blocks_on_pytree(coll):
@@ -152,9 +178,154 @@ def test_export_roundtrip(tmp_path, coll):
     p = coll.export(str(tmp_path / "trace.json"))
     loaded = json.load(open(p))
     assert [e["name"] for e in loaded["traceEvents"]] == ["e"]
-    q = coll.export_flat(str(tmp_path / "flat.json"))
-    flat = json.load(open(q))
-    assert flat[0]["name"] == "e" and "dur_s" in flat[0]
+
+
+# ---------------------------------------------------------------------------
+# Program spans: a tiny acoustic propagate
+# ---------------------------------------------------------------------------
+
+# two (8, 8) tiles along x; T 2 over nt 3 leaves a remainder tile of T 1.
+# The source's 8 trilinear points (x 2..3) lie in tile 0's centre and
+# outside tile 1's halo-4 window; the receiver's straddle the tile edge
+# (x 7 | 8), 4 points on each side.
+TINY = dict(shape=(16, 8, 12), src=[[25.0, 35.0, 35.0]],
+            rec=[[75.0, 35.0, 35.0]], nt=3, T=2)
+
+
+def _tiny_case():
+    from repro.core import sources as S
+    from repro.core.grid import Grid
+
+    grid = Grid(shape=TINY["shape"], spacing=(10.0,) * 3)
+    wav = np.linspace(1.0, 2.0, TINY["nt"])[:, None]
+    g = S.precompute(S.SparseOperator(TINY["src"]), grid, wav)
+    gr = S.precompute_receivers(S.SparseOperator(TINY["rec"]), grid)
+    return grid, g, gr
+
+
+@pytest.fixture(scope="module")
+def tiny_propagate():
+    """One tiny acoustic propagate under a collector mirrored into the
+    profiler, with `TraceAnnotation` replaced by a recorder of names and a
+    compile listener of the test's own beside the collector's."""
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+
+    from repro.core.temporal_blocking import TBPlan
+    from repro.kernels import ops
+
+    annotated, compiled_at = [], []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            annotated.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def listen(event, secs, **kw):
+        if event == tsp.BACKEND_COMPILE:
+            compiled_at.append(time.perf_counter())
+
+    shape = TINY["shape"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax.profiler, "TraceAnnotation", Recorder)
+        monitoring.register_event_duration_secs_listener(listen)
+        c = tsp.enable(jax_profiler=True)
+        try:
+            grid, g, gr = _tiny_case()
+            zero = jnp.zeros(shape, jnp.float32)
+            _, rec = ops.acoustic_tb_propagate(
+                TINY["nt"], zero, zero, jnp.full(shape, 1e-7),
+                jnp.zeros(shape), g, gr,
+                TBPlan(tile=(8, 8), T=TINY["T"], radius=2), 4, 1e-3,
+                grid.spacing)
+            jax.block_until_ready(rec)
+        finally:
+            tsp.disable()
+            monitoring.unregister_event_duration_listener(listen)
+    return c, annotated, compiled_at
+
+
+def test_propagate_spans_reach_trace_annotation(tiny_propagate):
+    c, annotated, _ = tiny_propagate
+    names = set(c.names())
+    assert names >= {"sources.precompute", "sources.precompute_receivers",
+                     "ops.tables", "ops.propagate", "ops.dispatch",
+                     "ops.tile_pass"}
+    assert names <= set(annotated)
+    parent = {r.name: r.parent for r in c.records()}
+    assert parent["ops.dispatch"] == "ops.propagate"
+    assert parent["ops.tile_pass"] == "ops.dispatch"
+
+
+def test_ops_dispatch_counts_compiles(tiny_propagate):
+    c, _, compiled_at = tiny_propagate
+    (d,) = [r for r in c.records() if r.name == "ops.dispatch"]
+    t0 = c.epoch + d.start
+    seen = [t for t in compiled_at if t0 <= t <= t0 + d.dur]
+    assert d.attrs["compiles"] == len(seen) >= 1
+
+
+def test_ops_tables_slot_fill_hand_count(tiny_propagate):
+    c, _, _ = tiny_propagate
+    (t,) = [r for r in c.records() if r.name == "ops.tables"]
+    # main tables (T 2, halo 4): src 8 live in cap 8 x 2 tiles, rec 8
+    # live in cap 4 x 2 tiles, serving 2 steps; the remainder (T 1)
+    # bins the same points by centre, serving 1
+    assert {k: t.attrs[k] for k in ("src_live", "src_slots", "rec_live",
+                                    "rec_slots", "steps")} == {
+        "src_live": [8, 8], "src_slots": [16, 16], "rec_live": [8, 8],
+        "rec_slots": [8, 8], "steps": [2, 1]}
+
+
+def test_sources_precompute_records_host_bytes(tiny_propagate):
+    c, _, _ = tiny_propagate
+    by = {r.name: r.attrs for r in c.records()}
+    n = int(np.prod(TINY["shape"]))
+    assert by["sources.precompute"] == {
+        "nsrc": 1, "npts": 8, "sm_bytes": n, "sid_bytes": 4 * n,
+        "src_dcmp_bytes": 8 * TINY["nt"] * 8}
+    assert by["sources.precompute_receivers"] == {
+        "nrec": 1, "npts": 8, "indices_bytes": 8 * 3 * 4,
+        "weights_bytes": 8 * 8}
+
+
+def test_tile_pass_lowers_named_scopes_with_telemetry_off():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.temporal_blocking import TBPlan
+    from repro.kernels import ops
+    from repro.kernels import tb_physics as phys
+
+    tsp.disable()
+    shape = TINY["shape"]
+    grid, g, gr = _tiny_case()
+    plan = TBPlan(tile=(8, 8), T=TINY["T"], radius=2)
+    params = {"m": jnp.ones(shape), "damp": jnp.zeros(shape)}
+    st, rt = ops.build_tables(
+        ops.make_spec(shape, plan, 4, 1e-3, grid.spacing, 1, 1), g, gr,
+        params)
+    spec = ops.make_spec(shape, plan, 4, 1e-3, grid.spacing, st.cap,
+                         rt.coords.shape[1])
+    pads = tuple(ops._pad_xy(params[f], spec.halo, "edge")
+                 for f in phys.ACOUSTIC.param_fields)
+
+    def tile_pass(state, pads, dcmp, st, rt):
+        return ops._run_time_tile(spec, phys.ACOUSTIC, state, pads, dcmp,
+                                  st, rt, 0, 1, True)
+
+    zero = jnp.zeros(shape)
+    text = jax.jit(tile_pass).lower((zero, zero), pads, g.src_dcmp, st,
+                                    rt).as_text(debug_info=True)
+    for name in ("ops.state_pad", "ops.src_vals", "ops.rec_combine",
+                 "tb_time_tile"):
+        assert name in text, name
 
 
 # ---------------------------------------------------------------------------
@@ -178,23 +349,6 @@ def test_metrics_registry_and_snapshot():
         r.gauge("hits")  # name already registered as a counter
     r.clear()
     assert r.snapshot() == {"counters": {}, "gauges": {}, "histograms": {}}
-
-
-def test_merge_snapshots():
-    a = tm.MetricsRegistry()
-    b = tm.MetricsRegistry()
-    a.counter("n").inc(2)
-    b.counter("n").inc(3)
-    b.counter("only_b").inc()
-    a.gauge("g").set(1.0)
-    b.gauge("g").set(9.0)
-    a.histogram("h").observe(1.0)
-    b.histogram("h").observe(5.0)
-    m = tm.merge_snapshots(a.snapshot(), b.snapshot())
-    assert m["counters"] == {"n": 5, "only_b": 1}
-    assert m["gauges"]["g"] == 9.0  # last (b) wins
-    assert m["histograms"]["h"] == {
-        "count": 2, "total": 6.0, "min": 1.0, "max": 5.0, "mean": 3.0}
 
 
 # ---------------------------------------------------------------------------
