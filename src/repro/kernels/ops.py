@@ -112,6 +112,24 @@ def slot_fill(spec: ker.TBKernelSpec, nt: int, tables, rtables
     return out
 
 
+def update_counts(spec: ker.TBKernelSpec, nt: int) -> Dict[str, list]:
+    """Span attributes for the kernel's update redundancy, for the depth-T
+    tiles and the `nt % T` remainder tile (when there is one):
+    `update_points`, the points at which the kernel's updates produce a
+    value over all tiles and steps (`stencil_tb.update_points`), and
+    `useful_points`, the grid's points times the steps served."""
+    rem = nt % spec.T
+    out = {"update_points": [], "useful_points": []}
+    for T, steps in ((spec.T, nt - rem), (rem, rem)):
+        if steps == 0:
+            continue
+        calls = steps // T
+        out["update_points"].append(
+            calls * ker.update_points(dataclasses.replace(spec, T=T)))
+        out["useful_points"].append(spec.nx * spec.ny * spec.nz * steps)
+    return out
+
+
 def _src_vals_for_tile(src_dcmp: jnp.ndarray, src_tab, t0, T: int):
     """(ntiles, T, cap) injection values for time tile starting at t0.
 
@@ -445,7 +463,8 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
                                 for f in physics.param_fields)
         if _spans.active():
             sp.set(**slot_fill(spec, nt, (src_tab, rec_tab),
-                               (rsrc_tab, rrec_tab)))
+                               (rsrc_tab, rrec_tab)),
+                   **update_counts(spec, nt))
 
     with _spans.span("ops.propagate", physics=physics.name, nt=nt,
                      T=spec.T, executor=executor) as sp:

@@ -35,11 +35,17 @@ Kernel layout
                                       (T*rec_channels, capr) vector per
                                       tile
 
+Schedule (`step_slabs`): in-VMEM step k advances only the x-planes the
+steps after it read, [r(k+1), wx - r(k+1)) — the trapezoid shrinks by
+r = r_step planes a side per step — as a loop over x-slabs of b = 8
+planes.  A slab applies the physics update to its b planes plus r on
+each side (x is the untiled major dimension, so the slab is a cheap ref
+slice) and keeps the middle b, so Mosaic's code and temporaries scale
+with a slab, not the window.  y stays window-wide.
+
 TPU notes: the z (minor) dimension is kept whole and should be a multiple
 of 128; tiles (tx, ty) should be multiples of 8 (the window's y extent is
-rounded up to 8 rows).  Each in-VMEM step runs as a loop over 8-plane x
-slabs, so Mosaic's unrolled code and temporaries scale with a slab, not
-the window.  Scatter/gather of the sparse points is realized with
+rounded up to 8 rows).  Scatter/gather of the sparse points is realized with
 broadcasted-iota masks over the slab holding the point (predicated vector
 ops — the VPU-friendly analogue of the paper's z-column nnz loop, see
 DESIGN.md §2 table).  Parity runs in interpret mode on CPU
@@ -118,13 +124,38 @@ class TBKernelSpec:
         return wx * wy * wz * jnp.dtype(self.dtype).itemsize * nwindows
 
 
-def _slab_planes(spec: TBKernelSpec, step_radius: int) -> Tuple[int, int]:
-    """(planes per slab, slabs per step): each step updates window planes
-    [r, wx - r) — all that the depth-T trapezoid needs — in slabs of
-    `SLAB` planes, the last one shifted back to end at wx - r."""
-    nout = spec.window[0] - 2 * step_radius
-    b = min(SLAB, nout)
-    return b, -(-nout // b)
+def step_slabs(spec: TBKernelSpec, k):
+    """The x-schedule of in-VMEM step `k` (a Python int, or the kernel's
+    traced step): `(lo, hi, b, nslab)`.
+
+    Step k updates window planes [lo, hi) = [r(k+1), wx - r(k+1)), with
+    r = step radius — the depth-T trapezoid, which is all the steps after
+    it read — in `nslab` slabs of `b` planes (b is the same every step).
+    Slab s keeps planes [x0, x0 + b) with x0 = lo + s*b, except that
+    the last slab is shifted back to end at hi, and a range narrower than
+    b is computed by one slab held inside [r, wx - r), where its reads
+    stay in the window.  The kernel, `kernel_cost` and the
+    `update_points` counter all count from here."""
+    wx = spec.window[0]
+    r = spec.halo // spec.T
+    b = min(SLAB, wx - 2 * r)
+    lo = r * (k + 1)
+    hi = wx - lo
+    return lo, hi, b, (hi - lo + b - 1) // b
+
+
+def update_points(spec: TBKernelSpec) -> int:
+    """Points one call's updates keep: over tiles and steps, slabs x b x
+    wy x nz.  A plane the shifted last slab keeps again counts twice.  (A
+    slab's update also reads r_step planes a side, whose values it drops;
+    on the TPU the kernel's time follows the kept planes, PERF.md §5.)"""
+    ntx, nty = spec.ntiles
+    _, wy, wz = spec.window
+    planes = 0
+    for k in range(spec.T):
+        _, _, b, nslab = step_slabs(spec, k)
+        planes += nslab * b
+    return ntx * nty * planes * wy * wz
 
 
 def _domain_mask(spec: TBKernelSpec, ti, tj, x0, nplanes: int):
@@ -162,14 +193,13 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
                field, between which the step loop ping-pongs — then a DMA
                semaphore array
 
-    Each in-VMEM step runs as a loop over x-slabs: the physics update is
-    applied to `b + 2*r_step` window planes and its middle `b` planes are
-    kept (x is the untiled major dimension, so the slab is a cheap ref
-    slice).  The update is local with radius r_step, so the kept planes are
-    exactly what a whole-window update computes there; the slab loop keeps
-    Mosaic's unrolled code, and its live temporaries, at slab size rather
-    than window size.  Planes within r_step of the window's x faces are
-    not advanced: the depth-T trapezoid never reads them into the centre.
+    Each in-VMEM step runs as a loop over the x-slabs `step_slabs` gives
+    it: the physics update is applied to `b + 2*r_step` window planes and
+    its middle `b` planes are kept.  The update is local with radius
+    r_step, so the kept planes are exactly what a whole-window update
+    computes there.  Step k's slabs cover [r_step*(k+1), wx -
+    r_step*(k+1)), which is all that step k+1 reads; planes outside it
+    keep stale values that nothing the centre depends on reads.
 
     `external_dom` is how the sharded execution layer reuses this kernel
     unchanged (DESIGN.md §4): on a single device the domain mask is an iota
@@ -193,7 +223,6 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
     wx, wy, wz = spec.window
     h = spec.halo
     r = spec.halo // spec.T
-    b, nslab = _slab_planes(spec, r)
     nch = physics.rec_channels
 
     # ---- DMA one window per field HBM -> VMEM ------------------------------
@@ -221,14 +250,12 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
     def step(k, carry):
         cur = [st.at[k % 2] for st in states]
         nxt = [st.at[1 - k % 2] for st in states]
-        # the faces the step does not advance keep their (stale) values
-        for i in range(ns):
-            nxt[i][0:r] = cur[i][0:r]
-            nxt[i][wx - r:wx] = cur[i][wx - r:wx]
+        lo_k, hi_k, b, nslab = step_slabs(spec, k)
 
         def slab(s, carry):
-            lo = r + s * b                       # first plane this slab owns
-            x0 = jnp.minimum(lo, wx - r - b)     # first plane it computes
+            lo = lo_k + s * b                    # first plane this slab owns
+            # first plane it computes (`step_slabs`)
+            x0 = jnp.maximum(jnp.minimum(lo, hi_k - b), r)
             rd = pl.ds(x0 - r, b + 2 * r)
             st_in = {f: cur[i][rd] for i, f in enumerate(physics.state_fields)}
             pr_in = {f: par_refs[i][rd]
@@ -422,16 +449,15 @@ def kernel_cost(spec: TBKernelSpec,
     """Analytic per-call cost of the kernel (feeds §Roofline / benchmarks).
 
     Reads one window per state+param field, writes back the centre of every
-    state field.  Stencil flops count what each step computes: `nslab`
-    slabs of `b + 2*r_step` planes (`_tb_kernel`); sparse-term flops are
-    the masked vector adds of the fused injection/interpolation over the
-    one slab that holds each entry.  `vmem_bytes` is the resident windows,
-    a pair per state field.
+    state field.  Stencil flops count what the steps compute:
+    `update_points` (the `step_slabs` schedule) at the physics' flops per
+    point; sparse-term flops are the masked vector adds of the fused
+    injection/interpolation over the one slab that holds each entry.
+    `vmem_bytes` is the resident windows, a pair per state field.
     """
     ntx, nty = spec.ntiles
     wx, wy, wz = spec.window
-    r = spec.halo // spec.T
-    b, nslab = _slab_planes(spec, r)
+    b = step_slabs(spec, 0)[2]
     if physics.name == "acoustic":
         stencil_flops = st.stencil_flops_per_point(spec.order, 3) + 9
     else:
@@ -439,16 +465,18 @@ def kernel_cost(spec: TBKernelSpec,
         mod = {"elastic": elastic, "tti": tti}[physics.name]
         stencil_flops = mod.model_flops_per_step((1, 1, 1), spec.order)
     window_pts = wx * wy * wz
-    step_pts = nslab * (b + 2 * r) * wy * wz
+    stencil_pts = update_points(spec)
     sparse_flops = (len(physics.inject_fields) * spec.src_cap
                     + 2 * physics.rec_channels * spec.rec_cap) * b * wy * wz
-    flops = ntx * nty * spec.T * (step_pts * stencil_flops + sparse_flops)
+    flops = (stencil_pts * stencil_flops
+             + ntx * nty * spec.T * sparse_flops)
     itemsize = jnp.dtype(spec.dtype).itemsize
     nw = physics.num_windows
     ns = len(physics.state_fields)
     hbm_read = ntx * nty * window_pts * nw * itemsize
     hbm_write = spec.nx * spec.ny * spec.nz * ns * itemsize
     return {"flops": float(flops),
+            "stencil_points": stencil_pts,
             "hbm_bytes": float(hbm_read + hbm_write),
             "useful_flops": float(spec.nx * spec.ny * spec.nz * spec.T
                                   * stencil_flops),

@@ -255,7 +255,8 @@ class SurveyEngine:
                 rsrc_tab, rrec_tab = tabs(rspec)
             if _spans.active():
                 sp.set(**ops_mod.slot_fill(spec, self.nt, (src_tab, rec_tab),
-                                           (rsrc_tab, rrec_tab)))
+                                           (rsrc_tab, rrec_tab)),
+                       **ops_mod.update_counts(spec, self.nt))
         return _ShotArrays(jnp.asarray(dcmp), src_tab, rec_tab,
                            rsrc_tab, rrec_tab)
 

@@ -45,11 +45,15 @@ def test_distributed_equals_reference(physics, T, order, n, nt):
 
 
 @pytest.mark.slow
-def test_distributed_pallas_inner_equals_reference():
+@pytest.mark.parametrize("T,nt", [
+    (2, 4),
+    (3, 7),   # the shrinking trapezoid over the shard's external domain
+])
+def test_distributed_pallas_inner_equals_reference(T, nt):
     """The SAME Pallas TB kernel runs per shard (inner trapezoid) under the
     deep-halo exchange (outer trapezoid) — the unified execution layer."""
     r = _run(["-m", "repro.launch.stencil_dist", "--check", "--inner",
-              "pallas", "--n", "32", "--nt", "4", "--T", "2"])
+              "pallas", "--n", "32", "--nt", str(nt), "--T", str(T)])
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "CHECK PASS" in r.stdout
 

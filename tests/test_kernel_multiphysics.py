@@ -78,6 +78,9 @@ def _plan(physics, order, tile, T):
     (2, (6, 6), 4),   # 2 time tiles (the acceptance minimum)
     (1, (6, 6), 2),   # spatially-blocked baseline path
     (2, (6, 6), 5),   # nt % T != 0 -> remainder tile
+    # shrinking trapezoid: n_k = 22, 14, 6 (overlapping last slabs, then
+    # a range under one slab), remainder tile of depth 1
+    (3, (6, 6), 4),
 ])
 def test_elastic_tb_matches_reference(T, tile, nt):
     order = 4
@@ -100,6 +103,7 @@ def test_elastic_tb_matches_reference(T, tile, nt):
     (2, (6, 6), 4),   # 2 time tiles (the acceptance minimum)
     (2, (12, 6), 4),  # asymmetric tile
     (2, (6, 6), 5),   # nt % T != 0 -> remainder tile
+    (3, (6, 6), 4),   # shrinking trapezoid, as in the elastic case
 ])
 def test_tti_tb_matches_reference(T, tile, nt):
     order = 4

@@ -43,6 +43,11 @@ def _setup(shape=(16, 16, 12), order=4, nt=8, nsrc=2, nrec=3, seed=0,
     (2, (4, 8)),     # asymmetric tiles
     (4, (16, 16)),   # single tile in x/y
     (3, (8, 8)),     # nt % T != 0 -> remainder tile
+    # the shrinking trapezoid (`stencil_tb.step_slabs`): steps whose range
+    # is under one slab (n_k = 12, 8, 4; remainder 8, 4), and last slabs
+    # that overlap the one before (n_k = 20, 16, 12, 4)
+    (3, (4, 8)),
+    (5, (4, 8)),
 ])
 def test_tb_kernel_matches_reference(T, tile):
     nt, order = 8, 4
@@ -144,6 +149,61 @@ def test_kernel_cost_model_sane():
     # temporal blocking must reduce HBM traffic vs 5-field naive traffic
     naive = 64 * 64 * 64 * 4 * 5 * spec.T
     assert c["hbm_bytes"] < naive
+
+
+@pytest.mark.parametrize("physics,tile,T,nt,redundancy", [
+    # the plans `plan_for_physics` picks at 512^3 for SO-4, over the
+    # benchmark's record lengths: acoustic (32, 32) T 8 with a T 7
+    # remainder tile, TTI (16, 32) T 2
+    ("acoustic", (32, 32), 8, 8, 3.0),
+    ("acoustic", (32, 32), 8, 399, (392 * 3.0 + 7 * 40 * 8 * 64 / 7168)
+     / 399),
+    ("tti", (16, 32), 2, 200, 1.875),
+])
+def test_kernel_cost_counts_the_update_schedule(physics, tile, T, nt,
+                                                redundancy):
+    """`kernel_cost`'s stencil points are the `update_points` the span
+    counter reports, both from the one schedule (`step_slabs`); their
+    ratio to the useful points is the trapezoid's (arithmetic only)."""
+    from repro.kernels import stencil_tb as ker
+    from repro.kernels import tb_physics as phys
+    physics = phys.PHYSICS[physics]
+    plan = TBPlan(tile=tile, T=T, radius=physics.step_radius(4))
+    spec = ops.make_spec((512, 512, 512), plan, 4, 1e-3, (10.0,) * 3, 8, 64,
+                         physics=physics)
+    one = ops.update_counts(spec, T)
+    assert ker.kernel_cost(spec, physics)["stencil_points"] == \
+        one["update_points"][0]
+    assert one["useful_points"] == [512 ** 3 * T]
+    got = ops.update_counts(spec, nt)
+    assert sum(got["update_points"]) / sum(got["useful_points"]) == \
+        pytest.approx(redundancy)
+
+
+@pytest.mark.parametrize("physics", ["acoustic", "tti"])
+def test_kernel_call_timer_counts_the_schedule(physics, capsys):
+    """`benchmarks/tb_kernel_call.py` times one kernel call and counts its
+    kept planes from the same schedule as the `update_points` counter."""
+    import importlib.util
+    import os
+    from repro.kernels import stencil_tb as ker
+    from repro.kernels import tb_physics as phys
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "tb_kernel_call.py")
+    spec_ = importlib.util.spec_from_file_location("tb_kernel_call", path)
+    tool = importlib.util.module_from_spec(spec_)
+    spec_.loader.exec_module(tool)
+    tool.main(["--physics", physics, "--n", "32", "--caps", "1,2",
+               "--reps", "1", "--slots", "live"])
+    line = capsys.readouterr().out.strip().splitlines()[-1]
+    tile, T = tool.PLANS[physics]
+    plan = TBPlan(tile=tile, T=T, radius=phys.PHYSICS[physics].step_radius(4))
+    spec = ops.make_spec((32, 32, 32), plan, 4, 1e-3, (10.0,) * 3, 1, 2,
+                         physics=phys.PHYSICS[physics])
+    _, wy, wz = spec.window
+    planes = ker.update_points(spec) // (wy * wz)
+    assert "caps (1,2) live slots: call " in line
+    assert f" {planes} kept planes" in line
 
 
 @settings(max_examples=8, deadline=None)
