@@ -10,8 +10,9 @@ full width of the paper's §IV.B acoustic case (512^3, 10 m, space order 4):
            batches), checked against sequential `acoustic_tb_propagate`.
 
 With --four-chips it runs only the sharded route instead: the same case
-through `sharded_tb_propagate` on a 2x2 mesh (Pallas inner kernel), checked
-against `acoustic_tb_propagate` on one device of the same process.
+through the sharded entry point `sharded_propagate` on a 2x2 mesh (Pallas
+inner kernel), checked against `acoustic_tb_propagate` on one device of the
+same process.
 
 Every timing and parity number printed was measured on the device named on
 the lines above it.  The last line of stdout is one JSON object,
@@ -186,9 +187,8 @@ def phase_four_chips(args):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import sources as S
-    from repro.core.temporal_blocking import plan_for_physics, plan_hierarchy
-    from repro.distributed.halo import (dist_plan_from_hier,
-                                        sharded_tb_propagate)
+    from repro.core.temporal_blocking import plan_for_physics
+    from repro.distributed.halo import sharded_plan, sharded_propagate
     from repro.kernels import ops
     from repro.kernels import tb_physics as phys
     from repro.launch.mesh import make_xy_mesh
@@ -197,11 +197,9 @@ def phase_four_chips(args):
     order = case.space_order
     nt = case.nt(dt)
     mesh = make_xy_mesh()
-    px, py = mesh.shape["data"], mesh.shape["model"]
-    block = (grid.shape[0] // px, grid.shape[1] // py)
-    hier, _ = plan_hierarchy("acoustic", grid.shape[2], order, block)
-    plan = dist_plan_from_hier(mesh, grid.shape, phys.ACOUSTIC, order, hier,
-                               dt, grid.spacing, inner="pallas")
+    plan = sharded_plan(mesh, phys.ACOUSTIC, grid.shape, order, dt,
+                        grid.spacing)
+    block = plan.block
     print(f"four-chip case: {case.name} grid {grid.shape} nt {nt} mesh "
           f"{dict(mesh.shape)} block {block} outer T {plan.T} inner tile "
           f"{plan.inner_tile} inner T {plan.inner_T} overlap {plan.overlap}")
@@ -210,20 +208,24 @@ def phase_four_chips(args):
     gr = S.precompute_receivers(S.SparseOperator(rec), grid)
 
     shard = NamedSharding(mesh, P("data", "model", None))
-    zero = jax.device_put(jnp.zeros(grid.shape, jnp.float32), shard)
     params = {"m": jax.device_put(m, shard),
               "damp": jax.device_put(damp, shard)}
-    fn = jax.jit(lambda s, p: sharded_tb_propagate(plan, nt, s, p, g=g,
-                                                   receivers=gr))
-    (state, traces), cold = _timed(lambda: fn((zero, zero), params))
-    _, warm = _timed(lambda: fn((zero, zero), params))
-    print(f"four-chip sharded_tb_propagate on device: cold {cold!r} s, "
+    zeros = jax.jit(lambda: tuple(jnp.zeros(grid.shape, jnp.float32)
+                                  for _ in range(2)), out_shardings=shard)
+
+    def run():
+        # the entry donates its state: every call gets fresh zeros
+        return sharded_propagate(plan, nt, zeros(), params, g, gr)
+
+    (state, traces), cold = _timed(run)
+    _, warm = _timed(run)
+    print(f"four-chip sharded_propagate on device: cold {cold!r} s, "
           f"warm {warm!r} s, {grid.npoints * nt / warm!r} point-steps/s warm")
     devs = [s.device for s in state[1].addressable_shards]
     shapes = {tuple(s.data.shape) for s in state[1].addressable_shards}
     print(f"four-chip shards: {len(set(devs))} distinct devices "
           f"{sorted(d.id for d in devs)}, shard shapes {sorted(shapes)}")
-    if len(set(devs)) != px * py or shapes != {block + (grid.shape[2],)}:
+    if len(set(devs)) != mesh.size or shapes != {block + (grid.shape[2],)}:
         raise AssertionError("wavefield shards are not one block per device")
 
     plan1, _ = plan_for_physics("acoustic", grid.shape[2], order)

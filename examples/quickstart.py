@@ -28,7 +28,7 @@ src = S.SparseOperator(np.array([[237.3, 214.9, 61.7]]))
 wavelet = S.ricker_wavelet(nt, dt, f0=12.0)
 
 # -- 2. the paper's precompute: align the source to the grid ----------------
-g = S.precompute(src, grid, wavelet)                 # SM, SID, src_dcmp
+g = S.precompute(src, grid, wavelet)           # affected points, src_dcmp
 print(f"source decomposed onto {g.npts} grid points "
       f"(trilinear, paper Fig. 5)")
 

@@ -6,7 +6,9 @@ This module is the faithful reproduction of Section II of the paper:
      (Listing 2) — `affected_points` (we also expose the direct index-based
      computation, which is bit-identical and what production uses);
   2. build the binary source mask ``SM`` and unique-ID volume ``SID``
-     (Fig. 5b/5c) — `GriddedSources.sm`, `GriddedSources.sid`;
+     (Fig. 5b/5c) — `GriddedSources.sm`, `GriddedSources.sid`, dense grids
+     made on request from the affected points: the production paths read
+     the points and never a grid-sized array;
   3. decompose the off-grid wavelets into per-affected-grid-point wavelets
      ``src_dcmp`` (Listing 3, Fig. 5d) — `GriddedSources.src_dcmp`;
   4. the fused, grid-aligned injection that makes temporal blocking legal
@@ -21,9 +23,10 @@ Receivers are handled symmetrically (measurement interpolation, Fig. 3b):
 interpolation weights are precomputed into a gather table so that reading a
 receiver is a local, grid-aligned operation.
 
-Everything here is host-side numpy precomputation producing jnp constants;
-it runs once per model setup, which is the paper's "negligible overhead"
-claim — benchmarked in `benchmarks/overhead_precompute.py`.
+Everything here is host-side numpy precomputation producing jnp constants
+whose size follows the affected points, never the grid; it runs once per
+model setup, which is the paper's "negligible overhead" claim —
+benchmarked in `benchmarks/overhead_precompute.py`.
 
 Interpolation itself lives in `core/interp.py` as a precomputed per-axis
 coefficient operator (`InterpCoeffs`, Devito's
@@ -125,14 +128,16 @@ def affected_points_by_injection(stencil: InterpStencil, grid: Grid,
     read off the non-zero coordinates.  `wavelet0` is src(t0, :) and must be
     non-zero for every source (paper assumption; `precompute` falls back to
     weight-based discovery otherwise, equivalent to injecting for more
-    timesteps)."""
-    u = np.zeros(grid.shape, np.float64)
+    timesteps).  The empty grid is held sparsely (only the points written
+    to), so the work follows the stencils, not the grid."""
+    u = {}
     num, npts, _ = stencil.indices.shape
     for s in range(num):
         for i in range(npts):
-            xs = tuple(stencil.indices[s, i])
-            u[xs] += stencil.weights[s, i] * wavelet0[s]
-    return np.argwhere(u != 0.0).astype(np.int32)
+            xs = tuple(int(v) for v in stencil.indices[s, i])
+            u[xs] = u.get(xs, 0.0) + stencil.weights[s, i] * wavelet0[s]
+    pts = sorted(p for p, v in u.items() if v != 0.0)
+    return np.asarray(pts, np.int32).reshape(-1, grid.ndim)
 
 
 def affected_points(stencil: InterpStencil) -> np.ndarray:
@@ -154,18 +159,16 @@ class GriddedSources(NamedTuple):
     After this structure exists, source injection is a *local* grid-aligned
     operation and temporal blocking is legal (paper §II.A).
 
-    sm:        (grid) uint8 — binary source mask (Fig. 5b).
-    sid:       (grid) int32 — unique ascending ID per affected point, -1
-               elsewhere (Fig. 5c; the paper uses an implicit 0 background —
-               we use -1 so ID 0 is usable).
-    points:    (npts, ndim) int32 — coordinates of affected points, in SID
-               order.
+    points:    (npts, ndim) int32 — coordinates of affected points, in
+               ascending unique-ID (lexicographic) order.
     src_dcmp:  (nt, npts) float32 — per-affected-point wavelets (Listing 3):
                src_dcmp[t, sid] = sum_s w(s->point) * src[t, s].
+
+    The paper's dense SM / SID volumes (Fig. 5b/5c) are `sm(shape)` /
+    `sid(shape)`, host grids made from `points` on request (oracles and
+    tests); nothing grid-sized is built or put on a device otherwise.
     """
 
-    sm: jnp.ndarray
-    sid: jnp.ndarray
     points: jnp.ndarray
     src_dcmp: jnp.ndarray
 
@@ -176,6 +179,34 @@ class GriddedSources(NamedTuple):
     @property
     def nt(self) -> int:
         return self.src_dcmp.shape[0]
+
+    def sm(self, shape: Tuple[int, ...]) -> np.ndarray:
+        """(shape) uint8 binary source mask (Fig. 5b)."""
+        out = np.zeros(shape, np.uint8)
+        out[tuple(np.asarray(self.points).T)] = 1
+        return out
+
+    def sid(self, shape: Tuple[int, ...]) -> np.ndarray:
+        """(shape) int32 unique ascending ID per affected point, -1
+        elsewhere (Fig. 5c; the paper uses an implicit 0 background — we
+        use -1 so ID 0 is usable)."""
+        out = np.full(shape, -1, np.int32)
+        out[tuple(np.asarray(self.points).T)] = np.arange(self.npts,
+                                                          dtype=np.int32)
+        return out
+
+
+def _point_ids(pts: np.ndarray, idx: np.ndarray,
+               shape: Tuple[int, ...]) -> np.ndarray:
+    """SID of each grid index in `idx` (n, ndim): its position in the
+    lexicographically sorted `pts`, -1 where it is no affected point —
+    the SID volume read at `idx`, without building the volume."""
+    keys = np.ravel_multi_index(tuple(pts.T), shape)
+    want = np.ravel_multi_index(tuple(idx.T), shape)
+    pos = np.searchsorted(keys, want)
+    hit = pos < keys.size
+    hit[hit] = keys[pos[hit]] == want[hit]
+    return np.where(hit, pos, -1)
 
 
 def precompute(op: SparseOperator, grid: Grid, wavelets: np.ndarray,
@@ -211,15 +242,10 @@ def precompute(op: SparseOperator, grid: Grid, wavelets: np.ndarray,
             pts = affected_points(st)
 
         npts = pts.shape[0]
-        sm = np.zeros(grid.shape, np.uint8)
-        sid = np.full(grid.shape, -1, np.int32)
-        sm[tuple(pts.T)] = 1
-        sid[tuple(pts.T)] = np.arange(npts, dtype=np.int32)
-
         # Listing 3: decompose wavelets onto affected points.  A point
         # shared by several sources accumulates all their weighted wavelets
         # (the paper's "points being affected by more than one source").
-        ids = sid[tuple(st.indices.reshape(-1, grid.ndim).T)]  # (num*2^d,)
+        ids = _point_ids(pts, st.indices.reshape(-1, grid.ndim), grid.shape)
         w = st.weights.reshape(-1)                              # (num*2^d,)
         src_ids = np.repeat(np.arange(op.num), st.indices.shape[1])
         nt = wavelets.shape[0]
@@ -228,12 +254,10 @@ def precompute(op: SparseOperator, grid: Grid, wavelets: np.ndarray,
         src_dcmp = np.zeros((nt, npts), np.float64)
         contrib = wavelets[:, src_ids] * w[None, :]            # (nt, entries)
         np.add.at(src_dcmp.T, ids, contrib.T)
-        # the dense host arrays this builds (the grid-sized SM/SID dominate)
-        sp.set(npts=npts, sm_bytes=sm.nbytes, sid_bytes=sid.nbytes,
+        # the host arrays this builds: no dense SM/SID grid any more
+        sp.set(npts=npts, sm_bytes=0, sid_bytes=0,
                src_dcmp_bytes=src_dcmp.nbytes)
         return GriddedSources(
-            sm=jnp.asarray(sm),
-            sid=jnp.asarray(sid),
             points=jnp.asarray(pts),
             src_dcmp=jnp.asarray(src_dcmp, dtype=dtype),
         )
@@ -272,8 +296,8 @@ def dense_increment(g: GriddedSources, t: jnp.ndarray,
     ``SM[p] ? src_dcmp[t, SID[p]] : 0``.  Used by oracles and tests; the
     production paths use `inject` (scatter) or the per-tile tables."""
     vals = jax.lax.dynamic_index_in_dim(g.src_dcmp, t, 0, keepdims=False)
-    safe_sid = jnp.maximum(g.sid, 0)
-    inc = vals[safe_sid] * g.sm.astype(dtype)
+    safe_sid = jnp.maximum(jnp.asarray(g.sid(shape)), 0)
+    inc = vals[safe_sid] * jnp.asarray(g.sm(shape)).astype(dtype)
     return inc.reshape(shape).astype(dtype)
 
 
@@ -298,13 +322,13 @@ class ZCompressed(NamedTuple):
         return self.sp_z.shape[-1]
 
 
-def z_compress(g: GriddedSources) -> ZCompressed:
+def z_compress(g: GriddedSources, shape: Tuple[int, int, int]
+               ) -> ZCompressed:
     """Aggregate non-zeros along z, cutting off all-zero z-slices (§II.A.5)."""
-    sm = np.asarray(g.sm)
-    sid = np.asarray(g.sid)
-    if sm.ndim != 3:
+    if len(shape) != 3:
         raise ValueError("z-compression is defined for 3-D grids")
-    nx, ny, nz = sm.shape
+    sm, sid = g.sm(shape), g.sid(shape)
+    nx, ny, nz = shape
     nnz = sm.astype(np.int32).sum(axis=2)
     max_nnz = max(int(nnz.max()), 1)
     sp_z = np.full((nx, ny, max_nnz), -1, np.int32)

@@ -22,10 +22,11 @@ jointly by `core.temporal_blocking.plan_hierarchy`):
                       spatially tiled by `inner_plan.tile`: either the
                       Pallas TB kernel (`stencil_tb.tb_time_tile`,
                       `inner="pallas"`, one kernel grid of block/tile
-                      windows per tile — the shard's `dom_pad` and tile
-                      offsets compose inside the kernel's window DMA) or
-                      its jnp oracle (`inner="jnp"`), which loops the SAME
-                      per-window schedule in pure jnp.
+                      windows per pass, DMA'd in place out of the
+                      shard's frames, its domain mask an iota predicate
+                      over the shard's domain box) or its jnp oracle
+                      (`inner="jnp"`), which loops the SAME per-window
+                      schedule in pure jnp.
 
 The two TIME depths are decoupled (time-nesting, DESIGN.md §4): the inner
 `TBPlan.T` may be any depth up to the outer exchange depth `T`, in which
@@ -64,6 +65,15 @@ by receiver id (`ops.combine_rec_partials`) — so receiver traces are
 per-step at any T, and `nt % T != 0` runs a shallower remainder tile
 exactly like the single-device driver, nested passes included.
 
+Memory (DESIGN.md §4): a shard keeps each exchanged field in ONE buffer,
+its frame (`_Frame`) — the block zero-padded to the exchange depth, the
+received strips written into it in place — and every pass reads its
+windows from the frames at an origin, so no per-tile pad, crop or mask
+array exists; the params are exchanged into their frames once per
+propagate.  With the state donated by the entry point
+(`sharded_propagate`), a 512x512x1024 acoustic block fits one v5e at
+1024^3 on a 2x2 mesh (`tests/test_tpu_compile.py`).
+
 Mesh layout: grid x -> "data" axis, grid y -> "model" axis.  Exchanges are
 `lax.ppermute` shifts; missing neighbors (domain boundary) produce zeros =
 the Dirichlet convention shared by the reference and the Pallas kernel, and
@@ -72,7 +82,6 @@ their physics' `param_fills` there so updates stay finite).
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Dict, NamedTuple, Optional, Tuple
 
@@ -116,38 +125,44 @@ def _shift_from_high(x, h: int, axis_name: str, dim: int):
                                   if i + 1 <= n - 1])
 
 
-def halo_exchange(x, h: int, axis_name: str, dim: int, shift_fns=None):
-    """Pad the local block with depth-h halos from both neighbors.
+def exchange_to_depth(x, depth: int, h, ax_x: str, ax_y: str,
+                      shift_fns=None, frame: Optional[Tuple[int, int]] = None):
+    """The local block with a depth-`depth` halo from its neighbours, in a
+    zero window of depth `h` — the per-field deep exchange (DESIGN.md §4).
+    Cells in the zero band are only ever read into values the trapezoid
+    discards (`TBPhysics.halo_lags` is derived from exactly that
+    dependency cone); `depth == 0` skips the ppermute rounds entirely.
 
+    The window is ONE buffer: the block zero-padded to it, the received
+    strips then written in place (x first, then y from the x-extended
+    rows, which fills the corners).  `h` may be an (x, y) pair, and
+    `frame` (default (bx + 2h, by + 2h)) makes the buffer larger on the
+    high side, so a caller can read windows from it in place (`_Frame`).
     `shift_fns` (default: the ppermute pair above) injects the two
     neighbor-strip providers `(from_low, from_high)` — tests and oracles
-    substitute collective-free simulators so the concat/zero-band algebra
-    is exercised with real neighbor data on one device."""
+    substitute collective-free simulators so the strip algebra is
+    exercised with real neighbor data on one device."""
     from_low, from_high = shift_fns or (_shift_from_low, _shift_from_high)
-    lo = from_low(x, h, axis_name, dim)
-    hi = from_high(x, h, axis_name, dim)
-    return jnp.concatenate([lo, x, hi], axis=dim)
+    hx, hy = (h, h) if isinstance(h, int) else h
+    bx, by = x.shape[0], x.shape[1]
+    fx, fy = frame or (bx + 2 * hx, by + 2 * hy)
+    out = jnp.pad(x, ((hx, fx - hx - bx), (hy, fy - hy - by), (0, 0)))
+    if depth > 0:
+        d = depth
+        put = jax.lax.dynamic_update_slice
+        out = put(out, from_low(x, d, ax_x, 0), (hx - d, hy, 0))
+        out = put(out, from_high(x, d, ax_x, 0), (hx + bx, hy, 0))
+        rows = out[hx - d:hx + bx + d, hy:hy + by]
+        out = put(out, from_low(rows, d, ax_y, 1), (hx - d, hy - d, 0))
+        out = put(out, from_high(rows, d, ax_y, 1), (hx - d, hy + by, 0))
+    return out
 
 
 def halo_exchange_2d(x, h: int, ax_x: str, ax_y: str, shift_fns=None):
-    """x then y (the second exchange carries the x-halo -> corners filled)."""
-    x = halo_exchange(x, h, ax_x, 0, shift_fns=shift_fns)
-    return halo_exchange(x, h, ax_y, 1, shift_fns=shift_fns)
-
-
-def exchange_to_depth(x, depth: int, h: int, ax_x: str, ax_y: str,
-                      shift_fns=None):
-    """Exchange a depth-`depth` halo, then zero-pad out to the uniform
-    window depth `h` — the per-field deep exchange (DESIGN.md §4).  Cells
-    in the zero band are only ever read into values the trapezoid discards
-    (`TBPhysics.halo_lags` is derived from exactly that dependency cone);
-    `depth == 0` skips the ppermute rounds entirely."""
-    if depth > 0:
-        x = halo_exchange_2d(x, depth, ax_x, ax_y, shift_fns=shift_fns)
-    if h > depth:
-        pad = h - depth
-        x = jnp.pad(x, ((pad, pad), (pad, pad), (0, 0)))
-    return x
+    """The full-depth exchange: the block padded with depth-h halos from
+    all eight neighbours (x then y; the second round carries the x-halo,
+    so the corners fill)."""
+    return exchange_to_depth(x, h, h, ax_x, ax_y, shift_fns=shift_fns)
 
 
 class _StepSpec(NamedTuple):
@@ -268,17 +283,102 @@ def dist_plan_from_hier(mesh: Mesh, grid_shape: Tuple[int, int, int],
                       overlap=hier.overlap, **kwargs)
 
 
-def _local_domain_mask(plan: DistTBPlan, h: int, shape_local, dtype):
-    """1.0 inside the global domain for the depth-h halo-padded local block."""
+def _geoms(plan: DistTBPlan, T_depth: int):
+    """The inner passes of one depth-`T_depth` tile: the steps the inner
+    executor runs (all but the split first step with overlap) in chunks of
+    the inner depth, clamped to the tile (the `nt % T` remainder)."""
+    T_rest = T_depth - 1 if plan.overlap else T_depth
+    inner_T = min(plan.inner_T, T_depth, max(T_rest, 1))
+    return nested_pass_geometry(plan.block, plan.inner_tile, T_rest,
+                                inner_T, plan.r_step)
+
+
+def _pass_spec(plan: DistTBPlan, geom: TBPassGeom, nz: int, src_cap: int,
+               rec_cap: int, dtype):
+    return ops_mod.pass_inner_spec(
+        geom, nz, plan.order, float(plan.dt),
+        tuple(float(s) for s in plan.spacing), src_cap, rec_cap, dtype,
+        plan.physics)
+
+
+class _Frame(NamedTuple):
+    """Where a shard keeps an exchanged field (DESIGN.md §4): one (fx, fy,
+    nz) buffer whose index `depth + i` is block-local i, per axis.  A
+    pass reads its kernel windows from it in place, at origin `depth -
+    d_in`; the y depth puts a state frame's first read on the 8-row
+    tiling a window DMA needs, and the high-side margin holds every
+    window (y rounded up to 8 rows)."""
+
+    depth: Tuple[int, int]
+    shape: Tuple[int, int]
+
+
+def _aligned(lo: int, d_in: int) -> int:
+    """The least depth >= lo at which a read of input depth d_in starts
+    on an 8-row boundary."""
+    return lo + (d_in - lo) % 8
+
+
+def _fit(plan: DistTBPlan, depth: Tuple[int, int], reads, nz: int
+         ) -> _Frame:
+    """The frame at `depth` that holds its (bx + 2*depth) window and the
+    kernel windows of the passes in `reads`."""
+    bx, by = plan.block
+    fx, fy = bx + 2 * depth[0], by + 2 * depth[1]
+    for geom in reads:
+        wx, wy, _ = _pass_spec(plan, geom, nz, 1, 1, jnp.float32).window
+        ox, oy = depth[0] - geom.d_in, depth[1] - geom.d_in
+        fx = max(fx, ox + geom.grid[0] - geom.tile[0] + wx)
+        # an unaligned read DMAs from the row below, 8 rows more
+        fy = max(fy, oy - oy % 8 + geom.grid[1] - geom.tile[1] + wy
+                 + (8 if oy % 8 else 0))
+    return _Frame(depth, (fx, fy))
+
+
+def _frames(plan: DistTBPlan, nt: int, nz: int):
+    """(param frame, {tile depth: state frame}).  The params are exchanged
+    once at the main tiles' depth and read by every pass, aligned for the
+    main tiles' first pass; each tile depth exchanges its state into a
+    frame aligned for its own first pass."""
+    h = plan.halo
+    tiles = {d: _geoms(plan, d) for d in _tile_depths(plan, nt)}
+    main = next(iter(tiles.values()))
+    pdepth = (h, _aligned(h, main[0].d_in) if main else h)
+    params = _fit(plan, pdepth, [g for gs in tiles.values() for g in gs],
+                  nz)
+    states = {}
+    for d, geoms in tiles.items():
+        hd = d * plan.r_step
+        sdepth = (hd, _aligned(hd, geoms[0].d_in) if geoms else hd)
+        states[d] = _fit(plan, sdepth, geoms[:1], nz)
+    return params, states
+
+
+def _tile_depths(plan: DistTBPlan, nt: int):
+    """The tile depths a propagate runs: T, then the nt % T remainder."""
+    return sorted({min(nt, plan.T), nt % plan.T} - {0}, reverse=True)
+
+
+def _dom_bounds(plan: DistTBPlan, offset: Tuple[int, int]):
+    """(x_lo, x_hi, y_lo, y_hi): this shard's part of the global domain in
+    local coordinates whose index `offset` is the block's first cell."""
     nx, ny, _ = plan.grid_shape
-    px = jax.lax.axis_index(plan.ax_x)
-    py = jax.lax.axis_index(plan.ax_y)
-    bx = shape_local[0] - 2 * h
-    by = shape_local[1] - 2 * h
-    gx = px * bx - h + jax.lax.broadcasted_iota(jnp.int32, shape_local, 0)
-    gy = py * by - h + jax.lax.broadcasted_iota(jnp.int32, shape_local, 1)
-    ok = (gx >= 0) & (gx < nx) & (gy >= 0) & (gy < ny)
-    return ok.astype(dtype)
+    bx, by = plan.block
+    sx = jax.lax.axis_index(plan.ax_x) * bx
+    sy = jax.lax.axis_index(plan.ax_y) * by
+    return (offset[0] - sx, nx + offset[0] - sx, offset[1] - sy,
+            ny + offset[1] - sy)
+
+
+def _dom_mask(bounds, shape, start, dtype):
+    """1 inside `bounds`, 0 outside, over an array of `shape` whose first
+    cell sits at local coordinates `start` — an iota predicate XLA fuses
+    into its consumer, never a grid-sized buffer."""
+    x_lo, x_hi, y_lo, y_hi = bounds
+    gx = start[0] + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    gy = start[1] + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return ((gx >= x_lo) & (gx < x_hi) & (gy >= y_lo)
+            & (gy < y_hi)).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -291,20 +391,33 @@ def _local_domain_mask(plan: DistTBPlan, h: int, shape_local, dtype):
 _jnp_window_tile = ops_mod._jnp_window_tile
 
 
-def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state_pads, param_pads,
-              dom_pad, h_full: int, s_coords, s_vals, r_coords, r_w,
+def _reach(a, origin: Tuple[int, int], need: Tuple[int, int], fill: float):
+    """`a` padded on the high side (with `fill`) until [origin, origin +
+    need) fits in x and y; frames already hold every window, so this only
+    grows a pass's output that the next nested pass reads."""
+    ex = max(origin[0] + need[0] - a.shape[0], 0)
+    ey = max(origin[1] + need[1] - a.shape[1], 0)
+    if ex or ey:
+        a = jnp.pad(a, ((0, ex), (0, ey), (0, 0)),
+                    constant_values=jnp.asarray(fill, a.dtype))
+    return a
+
+
+def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state,
+              s_depth: Tuple[int, int], param_frames,
+              p_depth: Tuple[int, int], s_coords, s_vals, r_coords, r_w,
               interpret: bool):
     """Advance ONE inner pass of the time-nested schedule (DESIGN.md §4).
 
-    The incoming state is the shard block padded to the remaining halo
-    depth `geom.d_in`; the pass advances `geom.T` steps over the region
-    that stays valid afterwards (`block + 2*geom.d_out`, rounded up to the
-    inner tile with a zero-padded garbage band the crop discards) and
-    returns the state cropped to depth `geom.d_out` — the next pass's
-    input, landing exactly on the block at the last pass.  `param_pads` /
-    `dom_pad` stay at the full exchange depth `h_full` and are sliced to
-    the pass window here (params' round-up band carries `param_fills` so
-    updates stay finite in the garbage region).
+    `state` holds the block at (x, y) depth `s_depth` (a frame, or the
+    previous pass's output) and is valid to depth `geom.d_in`; the pass
+    advances `geom.T` steps over the region that stays valid afterwards
+    (`block + 2*geom.d_out`, rounded up to the inner tile: the round-up
+    band the crop discards reads cells no kept value depends on) and
+    returns it at depth `geom.d_out` — the next pass's input, landing
+    exactly on the block at the last pass.  Every operand is read in place, at origin
+    `depth - geom.d_in`; the domain mask is the shard's box in pass-grid
+    coordinates.
 
     Tables are per pass-local tile: s_coords (ntiles, cap, 3) window-local,
     s_vals (ntiles, geom.T, cap), r_coords/r_w likewise.  Returns
@@ -312,57 +425,61 @@ def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state_pads, param_pads,
     """
     physics = plan.physics
     bx, by = plan.block
-    nz = state_pads[0].shape[2]
+    nz = state[0].shape[2]
     tx, ty = geom.tile
     cx, cy = geom.grid
     hp = geom.halo
     keep = (bx + 2 * geom.d_out, by + 2 * geom.d_out)
-    ex, ey = cx - keep[0], cy - keep[1]
     fills = dict(physics.param_fills)
-
-    def fit(a, crop, fill):
-        if crop:
-            a = a[crop:a.shape[0] - crop, crop:a.shape[1] - crop]
-        if ex or ey:
-            a = jnp.pad(a, ((0, ex), (0, ey), (0, 0)),
-                        constant_values=jnp.asarray(fill, a.dtype))
-        return a
-
-    crop_p = h_full - geom.d_in
-    spads = tuple(fit(a, 0, 0.0) for a in state_pads)
-    ppads = tuple(fit(a, crop_p, fills.get(f, 0.0))
-                  for f, a in zip(physics.param_fields, param_pads))
-    dom = fit(dom_pad, crop_p, 0.0)
+    so = (s_depth[0] - geom.d_in, s_depth[1] - geom.d_in)
+    po = (p_depth[0] - geom.d_in, p_depth[1] - geom.d_in)
+    bounds = _dom_bounds(plan, (geom.d_out, geom.d_out))
     ntx, nty = geom.ntiles
     if plan.inner == "pallas":
-        # One pallas_call whose grid tiles the pass window; the shard's
-        # dom_pad rides along as one more HBM window and is sliced at the
-        # same per-tile window origin as the fields (stencil_tb).
+        # One pallas_call whose grid tiles the pass window, its windows
+        # DMA'd straight out of the frames
         from repro.kernels import stencil_tb as ker
-        spec = ops_mod.pass_inner_spec(
-            geom, nz, plan.order, float(plan.dt),
-            tuple(float(s) for s in plan.spacing), s_coords.shape[1],
-            r_coords.shape[1], spads[0].dtype, physics)
+        spec = _pass_spec(plan, geom, nz, s_coords.shape[1],
+                          r_coords.shape[1], state[0].dtype)
+        need = (cx - tx + spec.window[0], cy - ty + spec.window[1])
+        spads = tuple(_reach(a, so, need, 0.0) for a in state)
+        # an unaligned param read DMAs from the row below, 8 rows more
+        pneed = (need[0], need[1] + (8 if po[1] % 8 else 0))
+        pfrom = (po[0], po[1] - po[1] % 8)
+        ppads = tuple(_reach(a, pfrom, pneed, fills.get(f, 0.0))
+                      for f, a in zip(physics.param_fields, param_frames))
         new, rec = ker.tb_time_tile(
-            spec, physics, spads, ppads, s_coords, s_vals,
-            r_coords, r_w, dom_pad=dom, interpret=interpret)
+            spec, physics, spads, ppads, s_coords, s_vals, r_coords, r_w,
+            origins=(so,) * len(spads) + (po,) * len(ppads),
+            dom_box=jnp.stack(bounds).astype(jnp.int32),
+            interpret=interpret)
     else:
         # jnp oracle: the SAME per-window schedule as the kernel grid,
         # looped in pure jnp (ntx*nty windows, each with its own halo)
         sspec = _StepSpec(float(plan.dt),
                           tuple(float(s) for s in plan.spacing), plan.order)
+        wx, wy = tx + 2 * hp, ty + 2 * hp
+        need = (cx + 2 * hp, cy + 2 * hp)
+        spads = tuple(_reach(a, so, need, 0.0) for a in state)
+        ppads = tuple(_reach(a, po, need, fills.get(f, 0.0))
+                      for f, a in zip(physics.param_fields, param_frames))
         outs = [jnp.zeros((cx, cy, nz), p.dtype) for p in spads]
         rec_rows = []
         for ti in range(ntx):
             row = []
             for tj in range(nty):
                 k = ti * nty + tj
-                slx = slice(ti * tx, ti * tx + tx + 2 * hp)
-                sly = slice(tj * ty, tj * ty + ty + 2 * hp)
-                wpads = tuple(p[slx, sly] for p in spads)
-                wpar = tuple(p[slx, sly] for p in ppads)
+
+                def win(p, o):
+                    return p[o[0] + ti * tx:o[0] + ti * tx + wx,
+                             o[1] + tj * ty:o[1] + tj * ty + wy]
+
+                dom = _dom_mask(bounds, (wx, wy, nz),
+                                (ti * tx - hp, tj * ty - hp), spads[0].dtype)
                 out_w, rec = _jnp_window_tile(
-                    physics, sspec, geom.T, hp, wpads, wpar, dom[slx, sly],
+                    physics, sspec, geom.T, hp,
+                    tuple(win(p, so) for p in spads),
+                    tuple(win(p, po) for p in ppads), dom,
                     s_coords[k], s_vals[k], r_coords[k], r_w[k])
                 for i, centre in enumerate(out_w):
                     outs[i] = outs[i].at[ti * tx:(ti + 1) * tx,
@@ -376,70 +493,102 @@ def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state_pads, param_pads,
 
 
 def _split_first_step(plan: DistTBPlan, sspec: _StepSpec, h: int,
-                      state_blocks, state_pads, param_pads, dom,
+                      state_blocks, frames, s_depth: Tuple[int, int],
+                      param_frames, p_depth: Tuple[int, int],
                       s_coords, s_vals0, r_coords, r_w):
     """The overlapped first step of a deep tile (DESIGN.md §4).
 
     The exchanged halo is only needed within `h + r_step` of the window
     edge at step 1, so the step splits into:
 
-      interior   `physics.update` on the zero-padded LOCAL block — no data
-                 dependency on the ppermute, so XLA can run the exchange
-                 underneath it; valid at >= h + r_step from the window edge.
-      rim strips four band updates of width `h + 2*r_step` sliced from the
-                 exchanged window, each valid (after an r_step crop at cut
+      interior   `physics.update` on the LOCAL block alone (inside the
+                 domain, zero beyond it) — no data dependency on the
+                 ppermute, so XLA can run the exchange underneath it;
+                 valid at >= r_step from the block edge.
+      rim strips four band updates of width `h + 2*r_step` read from the
+                 exchanged frames, each valid (after an r_step crop at cut
                  edges) over the rim the interior cannot cover.
 
-    Stitching writes the strips over the interior result; the assembled
-    state carries the standard trapezoid contract (garbage only within
-    r_step of the window edge).  Injection and receiver partials then run
-    exactly as in `_jnp_window_tile`'s k = 0, on SHARD-level tables.
+    The window is the depth-h part of the frames (state frames at (x, y)
+    depth `s_depth`, params at `p_depth`, both >= h); each evolved
+    field's new frame holds the interior with the strips written over
+    its rim, and a field the update carries unchanged (a previous
+    time level) keeps its source's exchanged frame.  The result carries
+    the standard trapezoid contract (garbage only within r_step of the
+    window edge).  Injection and receiver partials then run exactly as in
+    `_jnp_window_tile`'s k = 0, on SHARD-level tables (window-local).
 
-    Returns (stitched padded state tuple, rec partials (1, capr, chan)).
+    Returns (new frames tuple, rec partials (1, capr, chan)).
     """
     physics = plan.physics
     r = plan.r_step
-    sd = dict(zip(physics.state_fields, state_pads))
-    pd = dict(zip(physics.param_fields, param_pads))
-    wx, wy = state_pads[0].shape[0], state_pads[0].shape[1]
-    bx = wx - 2 * h
+    bx, by = plan.block
+    nz = frames[0].shape[2]
+    dtype = frames[0].dtype
+    ox, oy = s_depth[0] - h, s_depth[1] - h      # the window's origins
+    px, py = p_depth[0] - h, p_depth[1] - h
+    wx, wy = bx + 2 * h, by + 2 * h
+    sd = dict(zip(physics.state_fields, frames))
+    pd = dict(zip(physics.param_fields, param_frames))
+    bounds = _dom_bounds(plan, s_depth)
 
-    def upd(slx, sly):
-        st_ = {f: a[slx, sly] for f, a in sd.items()}
-        pr_ = {f: a[slx, sly] for f, a in pd.items()}
-        dm = dom[slx, sly]
-        return physics.update(st_, pr_, sspec, lambda a: a * dm)
+    def upd(x0, x1, y0, y1):
+        """The update over window rows [x0, x1) x cols [y0, y1), with its
+        domain mask."""
+        ss = (slice(ox + x0, ox + x1), slice(oy + y0, oy + y1))
+        ps = (slice(px + x0, px + x1), slice(py + y0, py + y1))
+        dm = _dom_mask(bounds, (x1 - x0, y1 - y0, nz), (ox + x0, oy + y0),
+                       dtype)
+        new = physics.update({f: a[ss] for f, a in sd.items()},
+                             {f: a[ps] for f, a in pd.items()}, sspec,
+                             lambda a: a * dm)
+        return new, dm
 
-    # interior: independent of the exchange (zero-padded local block)
-    interior = {f: jnp.pad(b, ((h, h), (h, h), (0, 0)))
-                for f, b in zip(physics.state_fields, state_blocks)}
-    out = physics.update(interior, pd, sspec, lambda a: a * dom)
+    # interior: independent of the exchange (the block holds no
+    # out-of-domain cell, so its mask is the identity)
+    blocks = dict(zip(physics.state_fields, state_blocks))
+    inner = physics.update(
+        blocks, {f: a[p_depth[0]:p_depth[0] + bx, p_depth[1]:p_depth[1] + by]
+                 for f, a in pd.items()}, sspec, lambda a: a)
+    carried = {f: src for f, a in inner.items()
+               for src, b in blocks.items() if a is b}
 
     band = h + 2 * r
-    xlo = upd(slice(0, band), slice(None))
-    xhi = upd(slice(wx - band, wx), slice(None))
-    for f in out:
-        out[f] = out[f].at[:h + r].set(xlo[f][:h + r])
-        out[f] = out[f].at[wx - h - r:].set(xhi[f][r:])
+    # (window rows, window cols) of each strip, and the part of it kept
+    strips = [((0, band), (0, wy), (slice(0, h + r), slice(None))),
+              ((wx - band, wx), (0, wy), (slice(r, None), slice(None)))]
     if bx > 2 * r:  # middle x range exists: cover its y rims
-        ylo = upd(slice(h, wx - h), slice(0, band))
-        yhi = upd(slice(h, wx - h), slice(wy - band, wy))
-        for f in out:
-            out[f] = out[f].at[h + r:wx - h - r, :h + r].set(
-                ylo[f][r:bx - r, :h + r])
-            out[f] = out[f].at[h + r:wx - h - r, wy - h - r:].set(
-                yhi[f][r:bx - r, r:])
+        strips += [((h, wx - h), (0, band), (slice(r, bx - r),
+                                              slice(0, h + r))),
+                   ((h, wx - h), (wy - band, wy), (slice(r, bx - r),
+                                                   slice(r, None)))]
+    evolved = [f for f in physics.state_fields if f not in carried]
+    fx, fy = frames[0].shape[:2]
+    out = {f: jnp.pad(inner[f], ((s_depth[0], fx - s_depth[0] - bx),
+                                 (s_depth[1], fy - s_depth[1] - by),
+                                 (0, 0)))
+           for f in evolved}
+    for (x0, x1), (y0, y1), keep in strips:
+        new, dm = upd(x0, x1, y0, y1)
+        at = (ox + x0 + (keep[0].start or 0),
+              oy + y0 + (keep[1].start or 0), 0)
+        for f in evolved:
+            v = new[f][keep]
+            # post-step mask of _jnp_window_tile (the interior is
+            # in-domain: its mask is 1)
+            if f in physics.evolved_fields and \
+                    f not in physics.premasked_fields:
+                v = v * dm[keep]
+            out[f] = jax.lax.dynamic_update_slice(out[f], v, at)
+    for f, src in carried.items():
+        out[f] = sd[src]
 
-    # post-step sequence of _jnp_window_tile, k = 0
-    for f in physics.evolved_fields:
-        if f not in physics.premasked_fields:
-            out[f] = out[f] * dom
-    sx, sy, sz = s_coords[:, 0], s_coords[:, 1], s_coords[:, 2]
+    sx, sy, sz = s_coords[:, 0] + ox, s_coords[:, 1] + oy, s_coords[:, 2]
     for f in physics.inject_fields:
         out[f] = out[f].at[sx, sy, sz].add(s_vals0.astype(out[f].dtype))
-    rx, ry, rz = r_coords[:, 0], r_coords[:, 1], r_coords[:, 2]
-    rec = jnp.stack([(arr[rx, ry, rz] * r_w).astype(arr.dtype)
-                     for arr in physics.record(out)], axis=-1)
+    rx, ry, rz = r_coords[:, 0] + ox, r_coords[:, 1] + oy, r_coords[:, 2]
+    samples = physics.record({f: a[rx, ry, rz] for f, a in out.items()})
+    rec = jnp.stack([(v * r_w).astype(v.dtype) for v in samples], axis=-1)
     return (tuple(out[f] for f in physics.state_fields), rec[None])
 
 
@@ -608,127 +757,76 @@ def _combine_pass(parts, rid, nrec: int):
 # Sharded driver
 # ---------------------------------------------------------------------------
 
-def _depth_setup(plan: DistTBPlan, T_depth: int,
-                 g: Optional[src_mod.GriddedSources],
-                 receivers: Optional[src_mod.GriddedReceivers],
-                 params: Dict[str, jnp.ndarray], interpret: bool,
-                 prepped=None):
-    """Build the shard_map'd tile function, its sharded tables / padded
-    params, and the receiver-partial combiner for one time-tile depth
-    (main T or the nt % T remainder).
-
-    The host-built tables depend only on geometry (g's affected points,
-    block, inner tile, halo) — never on `params` — so this whole setup
-    traces cleanly under jit; the param-dependent injection scale is
-    gathered in-graph by the tile function (table `scale` column = 1/0
-    validity mask).
-
-    `prepped` (optional) is the `(param_pads, dom_pad, h_from)` triple a
-    DEEPER depth setup already exchanged: the remainder tile's halo is
-    strictly shallower than the main tiles' (`rem < T`), so its padded
-    params and domain mask are a collective-free per-shard centre crop of
-    the main ones — the remainder pays ZERO param ppermute rounds
-    (ROADMAP: the remainder's serialized setup exchange).
-
-    Returns (run_tile, combine, (param_pads, dom_pad, h)) with
-      run_tile(state, src_win, scale_vec) -> (new state, partials pytree)
-      combine(partials) -> (T_depth, nrec, rec_channels) per-step samples.
-    """
-    physics = plan.physics
-    ns = len(physics.state_fields)
-    npar = len(physics.param_fields)
-    px, py = plan.pgrid
+def _depth_tables(plan: DistTBPlan, T_depth: int,
+                  g: Optional[src_mod.GriddedSources],
+                  receivers: Optional[src_mod.GriddedReceivers]):
+    """Host-side owner-sharded source/receiver tables for one time-tile
+    depth (main T or the nt % T remainder): per inner pass (the tile
+    origins shift with the remaining depth d_out) `(coords, sid, mask,
+    rcoords, rweight, rid)`, and with overlap one more set for the split
+    first step (window = the whole exchanged block, one "tile" per
+    shard).  They depend only on geometry (g's affected points, block,
+    inner tile, halo), never on `params`: the param-dependent injection
+    scale is gathered in-graph."""
     bx, by = plan.block
-    r = plan.r_step
-    h = T_depth * r
-    overlap = plan.overlap
-    T_rest = T_depth - 1 if overlap else T_depth  # steps the inner exec runs
-    depths = plan.field_depths(T_depth)
-    nrec = receivers.num if receivers is not None else 0
-    nchan = physics.rec_channels
-    spec3 = P(plan.ax_x, plan.ax_y, None)
-
-    # --- the time-nested pass schedule: T_rest steps in inner-depth chunks
-    # over pass-by-pass-shrinking windows (flat = one pass) ------------------
-    geoms = nested_pass_geometry((bx, by), plan.inner_tile, T_rest,
-                                 min(plan.inner_T, max(T_rest, 1)), r)
-
-    # --- host-side owner-sharded source/receiver tables, one binning per
-    # pass (the tile origins shift with the remaining depth d_out) -----------
-    extra = []
-    pass_rids = []
+    out = []
+    geoms = list(_geoms(plan, T_depth))
+    if plan.overlap:
+        geoms.insert(0, TBPassGeom(
+            T=1, t0=0, d_in=T_depth * plan.r_step, d_out=0,
+            halo=T_depth * plan.r_step, grid=(bx, by), tile=(bx, by),
+            ntiles=(1, 1), include_halo=T_depth > 1))
     for geom in geoms:
         sc, sid, smask = _pass_source_tables(plan, g, geom)
         rc, rw, rid = _pass_receiver_tables(plan, receivers, geom)
-        pass_rids.append(rid)
-        extra += [sc, sid, smask, rc, rw]
-    o_rid = None
-    if overlap:
-        # shard-level tables for the split first step (window = the whole
-        # exchanged block, one "tile" per shard)
-        og = TBPassGeom(T=1, t0=0, d_in=h, d_out=0, halo=h, grid=(bx, by),
-                        tile=(bx, by), ntiles=(1, 1),
-                        include_halo=T_depth > 1)
-        o_sc, o_sid, o_smask = _pass_source_tables(plan, g, og)
-        o_rc, o_rw, o_rid = _pass_receiver_tables(plan, receivers, og)
-        extra += [o_sc, o_sid, o_smask, o_rc, o_rw]
-    extra_specs = [P(plan.ax_x, plan.ax_y, *(None,) * (a.ndim - 2))
-                   for a in extra]
+        out.append((sc, sid, smask, rc, rw, jnp.asarray(rid)))
+    return tuple(out)
 
-    # --- time-invariant param halos (exchanged once per depth) --------------
-    fills = dict(physics.param_fills)
 
-    with _spans.span("halo.setup_exchange", depth=h,
-                     reused=prepped is not None):
-        if prepped is not None and prepped[2] >= h:
-            # reuse a deeper setup's exchanged pads: per-shard centre crop
-            # (the depth-h mask/halo band IS the centre of the depth-h_from
-            # one), no ppermute at all
-            d = prepped[2] - h
+def _host_tables(plan: DistTBPlan, nt: int, g, receivers, itemsize: int):
+    """Every depth's tables, under the `halo.tables` span with the
+    layer's counters (`sharded_counts`)."""
+    with _spans.span("halo.tables", physics=plan.physics.name, nt=nt,
+                     T=plan.T) as sp:
+        tables = {d: _depth_tables(plan, d, g, receivers)
+                  for d in _tile_depths(plan, nt)}
+        if _spans.active():
+            sp.set(**sharded_counts(plan, nt, itemsize))
+    return tables
 
-            @functools.partial(jax.shard_map, mesh=plan.mesh,
-                               in_specs=(spec3,) * (npar + 1),
-                               out_specs=(spec3,) * (npar + 1))
-            def reslice(*ps):
-                if d == 0:
-                    return ps
-                return tuple(p[d:-d, d:-d] for p in ps)
 
-            resliced = reslice(*prepped[0], prepped[1])
-            param_pads, dom_pad = resliced[:npar], resliced[npar]
-        else:
-            @functools.partial(jax.shard_map, mesh=plan.mesh,
-                               in_specs=(spec3,) * npar,
-                               out_specs=(spec3,) * (npar + 1))
-            def prepare(*ps):
-                pads = [halo_exchange_2d(p, h, plan.ax_x, plan.ax_y)
-                        for p in ps]
-                dom = _local_domain_mask(plan, h, pads[0].shape,
-                                         pads[0].dtype)
-                out = []
-                for f, pad in zip(physics.param_fields, pads):
-                    fill = fills.get(f, 0.0)
-                    if fill:
-                        pad = jnp.where(dom > 0, pad,
-                                        jnp.asarray(fill, pad.dtype))
-                    out.append(pad)
-                return (*out, dom)
+def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
+          interpret: bool):
+    """The shard_map'd function of one depth-`T_depth` outer-trapezoid
+    tile: ONE deep exchange of the state blocks into their `frame`s, then
+    T_depth local steps (the split first step with overlap, then the
+    inner passes), reading the params in their `pframe`s.
 
-            prepared = prepare(*[params[f] for f in physics.param_fields])
-            param_pads, dom_pad = prepared[:npar], prepared[npar]
-
-    # --- one outer-trapezoid tile: deep exchange + T local steps ------------
+    tile(*state blocks, *param frames, *tables, src_win, scale_vec)
+      -> (*new state blocks, *rec partials, one per pass)."""
+    physics = plan.physics
+    ns = len(physics.state_fields)
+    npar = len(physics.param_fields)
+    bx, by = plan.block
+    r = plan.r_step
+    h = T_depth * r
+    depths = plan.field_depths(T_depth)
+    geoms = _geoms(plan, T_depth)
+    ntab = len(geoms) + plan.overlap
+    spec3 = P(plan.ax_x, plan.ax_y, None)
+    spec6 = P(plan.ax_x, plan.ax_y, None, None, None, None)
+    tab_specs = (P(plan.ax_x, plan.ax_y, None, None, None),
+                 P(plan.ax_x, plan.ax_y, None, None),
+                 P(plan.ax_x, plan.ax_y, None, None),
+                 P(plan.ax_x, plan.ax_y, None, None, None),
+                 P(plan.ax_x, plan.ax_y, None, None)) * ntab
+    in_specs = ((spec3,) * (ns + npar) + tab_specs + (P(None, None),
+                                                      P(None)))
+    out_specs = (spec3,) * ns + (spec6,) * ntab
     sspec = _StepSpec(float(plan.dt), tuple(float(s) for s in plan.spacing),
                       plan.order)
-    in_specs = ((spec3,) * ns + (spec3,) * npar + (spec3,)
-                + tuple(extra_specs) + (P(None, None), P(None)))
-    out_specs = (spec3,) * ns
-    if overlap:
-        out_specs += (P(plan.ax_x, plan.ax_y, None, None, None, None),)
-    out_specs += (P(plan.ax_x, plan.ax_y, None, None, None, None),) \
-        * len(geoms)
 
-    def _gather_vals(win, sid, smask, scale_vec, dtype):
+    def gather_vals(win, sid, smask, scale_vec, dtype):
         """(T, npts) decomposed wavelets -> per-tile (tiles..., T, cap)
         injection values, scale gathered in-graph."""
         safe = jnp.maximum(sid, 0)
@@ -744,69 +842,162 @@ def _depth_setup(plan: DistTBPlan, T_depth: int,
     def tile(*args):
         sblocks = args[:ns]
         ppads = args[ns:ns + npar]
-        dom = args[ns + npar]
-        rest = list(args[ns + npar + 1:])
-        ptabs = []
-        for _ in geoms:
-            ptabs.append([a[0, 0] for a in rest[:5]])
-            rest = rest[5:]
-        if overlap:
-            osc, osid, osmask, orc, orw = [a[0, 0, 0] for a in rest[:5]]
-            rest = rest[5:]
-        src_win, scale_vec = rest
+        rest = args[ns + npar:]
+        tabs = [[a[0, 0] for a in rest[5 * i:5 * i + 5]]
+                for i in range(ntab)]
+        src_win, scale_vec = rest[5 * ntab:]
         dtype = sblocks[0].dtype
         # ONE deep exchange per depth-T tile (the whole point), per-field
-        # depths zero-padded to the uniform window
+        # depths, each into its frame
         with _spans.annotate("halo.exchange", depth=h):
-            spads = tuple(exchange_to_depth(b, d, h, plan.ax_x, plan.ax_y)
+            state = tuple(exchange_to_depth(b, d, frame.depth, plan.ax_x,
+                                            plan.ax_y, frame=frame.shape)
                           for b, d in zip(sblocks, depths))
+        s_depth = frame.depth
         rec_outs = []
         off = 0
-        if overlap:
+        if plan.overlap:
+            osc, osid, osmask, orc, orw = (a[0] for a in tabs.pop(0))
             with _spans.annotate("halo.split_first_step", depth=h):
-                sv0 = (src_win[0][jnp.maximum(osid, 0)]
-                       * (scale_vec[jnp.maximum(osid, 0)]
-                          * osmask)).astype(dtype)
-                state1, rec1 = _split_first_step(
-                    plan, sspec, h, sblocks, spads, ppads, dom, osc, sv0,
-                    orc, orw)
+                safe = jnp.maximum(osid, 0)
+                sv0 = (src_win[0][safe] * (scale_vec[safe] * osmask)
+                       ).astype(dtype)
+                state, rec1 = _split_first_step(
+                    plan, sspec, h, sblocks, state, s_depth, ppads,
+                    pframe.depth, osc, sv0, orc, orw)
             rec_outs.append(rec1[None, None, None])
-            # depth h - r = T_rest * r: exactly the first pass's d_in
-            state = tuple(a[r:-r, r:-r] for a in state1)
             off = 1
-        else:
-            state = spads
-        for ip, (geom, tabs) in enumerate(zip(geoms, ptabs)):
-            isc, isid, ismask, irc, irw = tabs
+        for ip, (geom, (isc, isid, ismask, irc, irw)) in enumerate(
+                zip(geoms, tabs)):
             with _spans.annotate("halo.pass", idx=ip, T=geom.T,
                                  d_out=geom.d_out):
-                sv = _gather_vals(
+                sv = gather_vals(
                     src_win[off + geom.t0:off + geom.t0 + geom.T],
                     isid, ismask, scale_vec, dtype)
-                state, parts = _run_pass(plan, geom, state, ppads, dom, h,
-                                         isc, sv, irc, irw, interpret)
+                state, parts = _run_pass(plan, geom, state, s_depth, ppads,
+                                         pframe.depth, isc, sv, irc, irw,
+                                         interpret)
+            s_depth = (geom.d_out, geom.d_out)
             rec_outs.append(parts[None, None])
+        state = tuple(a[s_depth[0]:s_depth[0] + bx,
+                        s_depth[1]:s_depth[1] + by] for a in state)
         return (*state, *rec_outs)
 
-    def run_tile(state, src_win, scale_vec):
-        outs = tile(*state, *param_pads, dom_pad, *extra, src_win, scale_vec)
-        return tuple(outs[:ns]), tuple(outs[ns:])
+    return tile
 
-    def combine(partials):
-        """Shard partials -> (T_depth, nrec, nchan) per-step samples."""
-        if receivers is None:
-            return jnp.zeros((T_depth, 0, nchan), jnp.float32)
-        recs = []
-        idx = 0
-        if overlap:
-            recs.append(_combine_pass(partials[0], o_rid, nrec))
-            idx = 1
-        for geom, rid in zip(geoms, pass_rids):
-            recs.append(_combine_pass(partials[idx], rid, nrec))
-            idx += 1
-        return recs[0] if len(recs) == 1 else jnp.concatenate(recs, axis=0)
 
-    return run_tile, combine, (param_pads, dom_pad, h)
+def _param_frames(plan: DistTBPlan, frame: _Frame, params):
+    """The time-invariant param fields in their frames: exchanged once per
+    propagate at the main tiles' depth (the remainder tile reads the same
+    frames, deeper in), with each physics' `param_fills` outside the
+    domain and past the exchanged window, where the update must stay
+    finite."""
+    physics = plan.physics
+    spec3 = P(plan.ax_x, plan.ax_y, None)
+    npar = len(physics.param_fields)
+    fills = dict(physics.param_fills)
+    D = plan.halo
+    dx, dy = frame.depth
+    bx, by = plan.block
+
+    @functools.partial(jax.shard_map, mesh=plan.mesh,
+                       in_specs=(spec3,) * npar, out_specs=(spec3,) * npar)
+    def prepare(*ps):
+        out = []
+        for f, p in zip(physics.param_fields, ps):
+            pad = exchange_to_depth(p, D, frame.depth, plan.ax_x,
+                                    plan.ax_y, frame=frame.shape)
+            fill = fills.get(f, 0.0)
+            if fill:
+                # the domain's part of the exchanged window
+                lo, hi, ylo, yhi = _dom_bounds(plan, frame.depth)
+                inside = (jnp.maximum(lo, dx - D),
+                          jnp.minimum(hi, dx + bx + D),
+                          jnp.maximum(ylo, dy - D),
+                          jnp.minimum(yhi, dy + by + D))
+                ok = _dom_mask(inside, pad.shape, (0, 0), jnp.bool_)
+                pad = jnp.where(ok, pad, jnp.asarray(fill, pad.dtype))
+            out.append(pad)
+        return tuple(out)
+
+    with _spans.annotate("halo.setup_exchange", depth=D):
+        return prepare(*[params[f] for f in physics.param_fields])
+
+
+class _RidTab(NamedTuple):
+    """The slice of a receiver table `ops.combine_rec_partials` reads."""
+
+    rid: jnp.ndarray
+
+
+def _combine_pass(parts, rid, nrec: int):
+    """(px, py, ntl, T, capr, chan) shard partials + rid table ->
+    (T, nrec, chan) per-step samples (segment sum over receiver ids)."""
+    px, py, ntl, T, capr, chan = parts.shape
+    flat = parts.reshape(px * py * ntl, 1, T, capr, chan)
+    tab = _RidTab(rid=rid.reshape(px * py * ntl, capr))
+    return ops_mod.combine_rec_partials(flat, tab, nrec)
+
+
+def _propagate(plan: DistTBPlan, nt: int, state, params, g, tables,
+               nrec: int, interpret: Optional[bool]):
+    """The traced sharded propagate, after all host-side binning: a scan
+    over the depth-T tiles plus the `nt % T` remainder tile, all reading
+    the same param frames.  Returns (state tuple, recs (nt, nrec, chan))."""
+    physics = plan.physics
+    ns = len(physics.state_fields)
+    nchan = physics.rec_channels
+    dtype = state[0].dtype
+    pframe, frames = _frames(plan, nt, state[0].shape[2])
+    if g is not None:
+        src_dcmp = g.src_dcmp
+        scale_vec = jnp.asarray(
+            physics.inject_scale(params, g, float(plan.dt)), jnp.float32)
+    else:
+        src_dcmp = jnp.zeros((max(nt, 1), 1), dtype)
+        scale_vec = jnp.zeros((1,), jnp.float32)
+    ppads = _param_frames(plan, pframe, params)
+
+    def run(T_depth, state, t0):
+        tabs = tables[T_depth]
+        flat = [a for t in tabs for a in t[:5]]
+        win = jax.lax.dynamic_slice(src_dcmp, (t0, 0),
+                                    (T_depth, src_dcmp.shape[1]))
+        outs = _tile(plan, frames[T_depth], pframe, T_depth, interpret)(
+            *state, *ppads, *flat, win, scale_vec)
+        if nrec == 0:
+            return tuple(outs[:ns]), jnp.zeros((T_depth, 0, nchan), dtype)
+        recs = [_combine_pass(p, t[5], nrec) for p, t in zip(outs[ns:], tabs)]
+        return tuple(outs[:ns]), (recs[0] if len(recs) == 1
+                                  else jnp.concatenate(recs, axis=0))
+
+    n_main = nt // plan.T
+    rem = nt - n_main * plan.T
+    recs = []
+    if n_main > 0:
+        def body(carry, k):
+            return run(plan.T, carry, k * plan.T)
+
+        state, rec = jax.lax.scan(body, tuple(state), jnp.arange(n_main))
+        recs.append(rec.reshape(n_main * plan.T, -1, nchan))
+    if rem > 0:
+        # the remainder tile nests the same way: passes of the SAME inner
+        # depth (clamped when the remainder is shallower than one pass)
+        state, rec = run(rem, state, n_main * plan.T)
+        recs.append(rec)
+    return state, (recs[0] if len(recs) == 1 else
+                   jnp.concatenate(recs, axis=0))
+
+
+def _check(plan: DistTBPlan, nt: int, state, g):
+    plan.validate()
+    physics = plan.physics
+    if len(state) != len(physics.state_fields):
+        raise ValueError(f"{physics.name} carries "
+                         f"{len(physics.state_fields)} state fields, "
+                         f"got {len(state)}")
+    if g is not None and g.nt < nt:
+        raise ValueError(f"source wavelets cover {g.nt} steps < nt={nt}")
 
 
 def sharded_tb_propagate(plan: DistTBPlan, nt: int,
@@ -832,74 +1023,154 @@ def sharded_tb_propagate(plan: DistTBPlan, nt: int,
     per-step receiver samples at any T (each shard records masked partials,
     segment-summed by receiver id across shards).
 
-    jit-compatible in `state`/`params` (sharded or not — the shard_map
-    specs handle layout): the host-side table build depends only on `g`
-    and the static plan, and the param-dependent injection scale is
-    gathered in-graph.
+    Traceable: under the caller's jit the host-side table build (geometry
+    only) runs at trace time and the param-dependent injection scale is
+    gathered in-graph.  `sharded_propagate` is the entry point that owns
+    the jit and the state's donation.
     """
-    physics = plan.physics
-    plan.validate()
     state = tuple(state)
-    if len(state) != len(physics.state_fields):
-        raise ValueError(f"{physics.name} carries "
-                         f"{len(physics.state_fields)} state fields, "
-                         f"got {len(state)}")
-    nchan = physics.rec_channels
-    dtype = state[0].dtype
-
-    if g is not None:
-        if g.nt < nt:
-            raise ValueError(f"source wavelets cover {g.nt} steps < nt={nt}")
-        src_dcmp = g.src_dcmp
-        scale_vec = jnp.asarray(
-            physics.inject_scale(params, g, float(plan.dt)),
-            jnp.float32)
-    else:
-        src_dcmp = jnp.zeros((max(nt, 1), 1), dtype)
-        scale_vec = jnp.zeros((1,), jnp.float32)
-
-    def src_window(t0, T_depth):
-        return jax.lax.dynamic_slice(src_dcmp, (t0, 0),
-                                     (T_depth, src_dcmp.shape[1]))
-
-    n_main = nt // plan.T
-    rem = nt - n_main * plan.T
-
-    recs_main = None
-    main_pads = None
-    if n_main > 0:
-        with _spans.span("halo.setup", depth=plan.T):
-            run_tile, combine, main_pads = _depth_setup(plan, plan.T, g,
-                                                        receivers, params,
-                                                        interpret)
-
-        def body(carry, tile_idx):
-            new, parts = run_tile(carry, src_window(tile_idx * plan.T,
-                                                    plan.T), scale_vec)
-            return new, combine(parts)
-
-        state, recs_main = jax.lax.scan(body, state, jnp.arange(n_main))
-        recs_main = recs_main.reshape(n_main * plan.T, -1, nchan)
-
-    if rem > 0:
-        # the remainder tile nests the same way: passes of the SAME inner
-        # depth (clamped when the remainder is shallower than one pass);
-        # its shallower param/domain pads are cropped out of the main
-        # tiles' deep-exchanged ones (no second param ppermute round)
-        rplan = plan._replace(
-            T=rem, inner_plan=(dataclasses.replace(
-                plan.inner_plan, T=min(plan.inner_plan.T, rem))
-                if plan.inner_plan is not None else None))
-        with _spans.span("halo.remainder", depth=rem):
-            run_rem, combine_rem, _ = _depth_setup(rplan, rem, g, receivers,
-                                                   params, interpret,
-                                                   prepped=main_pads)
-            state, parts = run_rem(state, src_window(n_main * plan.T, rem),
-                                   scale_vec)
-            rec_rem = combine_rem(parts)
-        recs = (jnp.concatenate([recs_main, rec_rem], axis=0)
-                if recs_main is not None else rec_rem)
-    else:
-        recs = recs_main
-
+    _check(plan, nt, state, g)
+    tables = _host_tables(plan, nt, g, receivers,
+                          jnp.dtype(state[0].dtype).itemsize)
+    nrec = receivers.num if receivers is not None else 0
+    state, recs = _propagate(plan, nt, state, params, g, tables, nrec,
+                             interpret)
     return state, (recs if receivers is not None else None)
+
+
+_propagate_jit = jax.jit(_propagate, static_argnums=(0, 1, 6, 7),
+                         donate_argnums=(2,))
+
+
+def sharded_lower(plan: DistTBPlan, nt: int, state, params,
+                  g: Optional[src_mod.GriddedSources] = None,
+                  receivers: Optional[src_mod.GriddedReceivers] = None,
+                  *, interpret: Optional[bool] = None):
+    """The program `sharded_propagate` runs, lowered for `state` and
+    `params` (arrays or `jax.ShapeDtypeStruct`s with their shardings) and
+    not run: dry runs and compile checks (`.compile().memory_analysis()`
+    is the per-device footprint)."""
+    state = tuple(state)
+    _check(plan, nt, state, g)
+    tables = _host_tables(plan, nt, g, receivers,
+                          jnp.dtype(state[0].dtype).itemsize)
+    nrec = receivers.num if receivers is not None else 0
+    return _propagate_jit.lower(plan, nt, state, dict(params), g, tables,
+                                nrec, interpret)
+
+
+def sharded_propagate(plan: DistTBPlan, nt: int,
+                      state: Tuple[jnp.ndarray, ...],
+                      params: Dict[str, jnp.ndarray],
+                      g: Optional[src_mod.GriddedSources] = None,
+                      receivers: Optional[src_mod.GriddedReceivers] = None,
+                      *, interpret: Optional[bool] = None):
+    """The sharded layer's entry point, as `ops.acoustic_tb_propagate` is
+    the one-chip one: host binning of the source and receiver tables,
+    then one jitted propagate (compiled once per plan, nt and table
+    shapes; the tables are its arguments, not constants) that DONATES
+    `state`, so the final state takes the initial state's memory.  Pass
+    state arrays nothing else holds, one buffer each.
+
+    `state` and `params` are global (nx, ny, nz) arrays, best already
+    sharded `P(plan.ax_x, plan.ax_y, None)` over `plan.mesh`; build the
+    plan with `sharded_plan`.  Returns (final state tuple, rec (nt, nrec,
+    rec_channels) | None), as `sharded_tb_propagate`.
+
+    Spans: `halo.propagate` (synced on the result) holds `halo.dispatch`
+    (`count_compiles`: the binning, and the jit's trace, lowering,
+    compile or load and enqueue), which holds `halo.tables` with the
+    layer's counters (`sharded_counts`)."""
+    state = tuple(state)
+    _check(plan, nt, state, g)
+    nrec = receivers.num if receivers is not None else 0
+    with _spans.span("halo.propagate", physics=plan.physics.name, nt=nt,
+                     T=plan.T) as sp:
+        with _spans.span("halo.dispatch", count_compiles=True):
+            tables = _host_tables(plan, nt, g, receivers,
+                                  jnp.dtype(state[0].dtype).itemsize)
+            out = _propagate_jit(plan, nt, state, dict(params), g, tables,
+                                 nrec, interpret)
+        sp.sync(out)
+    state, recs = out
+    return state, (recs if receivers is not None else None)
+
+
+def sharded_plan(mesh: Mesh, physics: phys.TBPhysics,
+                 grid_shape: Tuple[int, int, int], order: int, dt: float,
+                 spacing: Tuple[float, float, float], inner: str = "pallas",
+                 ax_x: str = "data", ax_y: str = "model",
+                 **planner) -> DistTBPlan:
+    """The plan `sharded_propagate` runs on `mesh`: the joint autotuner's
+    (`plan_hierarchy`) outer depth, inner tile and depth, and overlap for
+    one shard's block; `planner` goes to the autotuner."""
+    from repro.core.temporal_blocking import plan_hierarchy
+
+    block = (grid_shape[0] // mesh.shape[ax_x],
+             grid_shape[1] // mesh.shape[ax_y])
+    hier, _ = plan_hierarchy(physics.name, grid_shape[2], order, block,
+                             **planner)
+    return dist_plan_from_hier(mesh, grid_shape, physics, order, hier, dt,
+                               spacing, inner=inner, ax_x=ax_x, ax_y=ax_y)
+
+
+def sharded_counts(plan: DistTBPlan, nt: int, itemsize: int = 4
+                   ) -> Dict[str, object]:
+    """Span attributes of the sharded layer's work, from the static plan.
+
+    Per tile depth (the depth-T tiles, then the `nt % T` remainder):
+    `update_points`, the points every shard's split first step (interior
+    block and rim strips) and inner passes compute over all tiles and
+    steps — the deep-halo rims plus the kernel's trapezoid
+    (`stencil_tb.update_points`), or the jnp executor's whole windows —
+    and `useful_points`, the global grid's points times the steps served.
+    Per propagate: `exchange_bytes`, the bytes the shards send one another
+    (state strips every tile, params once), and `exchanges`, the
+    ppermute rounds that move them."""
+    from repro.kernels import stencil_tb as ker
+
+    physics = plan.physics
+    nx, ny, nz = plan.grid_shape
+    px, py = plan.pgrid
+    bx, by = plan.block
+    r = plan.r_step
+
+    def sent(d):
+        """(bytes, ppermutes) of one field's depth-d exchange, all shards."""
+        if d == 0:
+            return 0, 0
+        nbytes = (2 * (px - 1) * py * d * by
+                  + 2 * px * (py - 1) * d * (bx + 2 * d)) * nz * itemsize
+        return nbytes, 2 * (px > 1) + 2 * (py > 1)
+
+    out = {"update_points": [], "useful_points": []}
+    nbytes, rounds = 0, 0
+    for d in (plan.halo,) * len(physics.param_fields):
+        b, k = sent(d)
+        nbytes, rounds = nbytes + b, rounds + k
+    rem = nt % plan.T
+    for T_depth, tiles in ((plan.T, nt // plan.T), (rem, 1 if rem else 0)):
+        if tiles == 0:
+            continue
+        h = T_depth * r
+        pts = 0
+        if plan.overlap:
+            band = h + 2 * r
+            pts += (bx * by + 2 * band * (by + 2 * h)
+                    + (2 * bx * band if bx > 2 * r else 0)) * nz
+        for geom in _geoms(plan, T_depth):
+            if plan.inner == "pallas":
+                pts += ker.update_points(_pass_spec(plan, geom, nz, 1, 1,
+                                                    jnp.float32))
+            else:
+                tx, ty = geom.tile
+                pts += (geom.ntiles[0] * geom.ntiles[1] * geom.T
+                        * (tx + 2 * geom.halo) * (ty + 2 * geom.halo) * nz)
+        out["update_points"].append(tiles * px * py * pts)
+        out["useful_points"].append(nx * ny * nz * tiles * T_depth)
+        for d in plan.field_depths(T_depth):
+            b, k = sent(d)
+            nbytes, rounds = nbytes + tiles * b, rounds + tiles * k
+    out["exchange_bytes"] = nbytes
+    out["exchanges"] = rounds
+    return out

@@ -305,11 +305,9 @@ def make_inner_spec(block: Tuple[int, int], nz: int,
     The shard's (bx, by) block plays the role of the kernel's grid and the
     shard's exchanged deep halo plays the role of its zero padding; the
     kernel's own spatial grid is `block / inner_tile` tiles, each DMA'ing
-    an `inner_tile + 2*T*r_step` window out of the exchanged block —
-    `tb_time_tile`'s per-tile window slice composes the shard's `dom_pad`
-    with the inner tile offsets automatically (every HBM operand,
-    including the external domain mask, is sliced at the same
-    `(ti*tx, tj*ty)` window origin)."""
+    an `inner_tile + 2*T*r_step` window out of the exchanged block (at
+    `(ti*tx, tj*ty)` plus the operand's origin in the shard's frame,
+    `tb_time_tile(origins=...)`)."""
     bx, by = block
     tx, ty = inner_tile
     if bx % tx or by % ty:
@@ -330,9 +328,9 @@ def pass_inner_spec(geom: TBPassGeom, nz: int, order: int, dt: float,
     (DESIGN.md §4): the pass's kernel grid is the shard block plus the
     halo depth still valid AFTER the pass (`geom.d_out`, rounded up to the
     inner tile), its halo is the per-pass consumption `geom.T * r_step`,
-    and the window DMA (fields AND the shard's `dom_pad`) slices at the
-    pass-local `(ti*tx, tj*ty)` origin — so the same `tb_time_tile` call
-    advances a window that shrinks pass by pass, with the VMEM window
+    and the window DMA slices at the pass-local `(ti*tx, tj*ty)` origin
+    (plus the operand's origin in its frame) — so the same `tb_time_tile`
+    call advances a window that shrinks pass by pass, with the VMEM window
     sized by the INNER depth regardless of the exchange depth."""
     return make_inner_spec(geom.grid, nz, geom.tile, geom.T, order, dt,
                            spacing, src_cap, rec_cap, dtype, physics)

@@ -158,17 +158,23 @@ def update_points(spec: TBKernelSpec) -> int:
     return ntx * nty * planes * wy * wz
 
 
-def _domain_mask(spec: TBKernelSpec, ti, tj, x0, nplanes: int):
+def _domain_mask(spec: TBKernelSpec, ti, tj, x0, nplanes: int, box=None):
     """1.0 inside the physical domain, 0.0 in the halo padding, over window
     planes [x0, x0 + nplanes) — enforces the Dirichlet boundary at every
-    in-VMEM step (matches the oracle's zero-fill convention)."""
+    in-VMEM step (matches the oracle's zero-fill convention).  The domain
+    is [0, nx) x [0, ny) of the kernel grid, or, with `box`, the
+    [box[0], box[1]) x [box[2], box[3]) read from SMEM (a shard of a
+    decomposed grid, whose domain edges depend on its offset)."""
     _, wy, wz = spec.window
     tx, ty = spec.tile
     h = spec.halo
     shape = (nplanes, wy, wz)
     gx = ti * tx - h + x0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     gy = tj * ty - h + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    ok = ((gx >= 0) & (gx < spec.nx) & (gy >= 0) & (gy < spec.ny))
+    if box is None:
+        ok = (gx >= 0) & (gx < spec.nx) & (gy >= 0) & (gy < spec.ny)
+    else:
+        ok = (gx >= box[0]) & (gx < box[1]) & (gy >= box[2]) & (gy < box[3])
     return ok.astype(spec.dtype)
 
 
@@ -181,17 +187,23 @@ def _point_mask(shape, x, y, z):
 
 
 def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
-               external_dom: bool, *refs):
+               origins: Tuple[Tuple[int, int], ...], has_box: bool, *refs):
     """Generic multi-field TB kernel body.
 
     Ref layout (positional, in pallas_call order):
-      inputs:  n_state + n_param HBM refs (+ a domain-mask HBM ref when
-               `external_dom`), then this tile's src_coords, src_vals,
-               rec_coords, rec_w rows, flattened (1, 1, n) blocks in SMEM
+      inputs:  n_state + n_param HBM refs, then this tile's src_coords,
+               src_vals, rec_coords, rec_w rows, flattened (1, 1, n)
+               blocks in SMEM (+ the (4,) domain box in SMEM when
+               `has_box`)
       outputs: n_state centre refs, then rec partials (1, T*chan, capr)
       scratch: one VMEM window per HBM ref — a pair of them per state
                field, between which the step loop ping-pongs — then a DMA
                semaphore array
+
+    Tile (ti, tj)'s window starts at (ti*tx, tj*ty) of an HBM operand,
+    offset by that operand's static `origins` entry.  A param window whose
+    y origin is off the 8-row tiling is DMA'd from the tiling row below
+    it (8 rows taller) and read at that row offset.
 
     Each in-VMEM step runs as a loop over the x-slabs `step_slabs` gives
     it: the physics update is applied to `b + 2*r_step` window planes and
@@ -201,21 +213,24 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
     r_step*(k+1)), which is all that step k+1 reads; planes outside it
     keep stale values that nothing the centre depends on reads.
 
-    `external_dom` is how the sharded execution layer reuses this kernel
-    unchanged (DESIGN.md §4): on a single device the domain mask is an iota
-    predicate derived from the spec, but on a shard of a decomposed grid it
-    depends on the shard's global offset, so the caller supplies it as one
-    more time-invariant window.
+    `has_box` and `origins` are how the sharded execution layer reuses
+    this kernel unchanged (DESIGN.md §4): on a single device the domain
+    is the spec's grid, but on a shard of a decomposed grid it depends on
+    the shard's global offset, so the caller supplies its edges; and the
+    shard's operands are frames larger than the kernel grid's padding,
+    read in place at an offset.
     """
     ns = len(physics.state_fields)
-    nw = physics.num_windows + (1 if external_dom else 0)
+    nw = physics.num_windows
     hbm = refs[:nw]
     src_coords_ref, src_vals_ref, rec_coords_ref, rec_w_ref = \
         refs[nw:nw + 4]
-    out_refs = refs[4 + nw:4 + nw + ns]
-    rec_out_ref = refs[4 + nw + ns]
-    wins = refs[5 + nw + ns:5 + 2 * nw + ns]
-    sems = refs[5 + 2 * nw + ns]
+    nin = nw + 4 + has_box
+    box = ([refs[nw + 4][i] for i in range(4)] if has_box else None)
+    out_refs = refs[nin:nin + ns]
+    rec_out_ref = refs[nin + ns]
+    wins = refs[nin + ns + 1:nin + ns + 1 + nw]
+    sems = refs[nin + ns + 1 + nw]
 
     ti = pl.program_id(0)
     tj = pl.program_id(1)
@@ -227,18 +242,24 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
 
     # ---- DMA one window per field HBM -> VMEM ------------------------------
     # (state windows land in slot 0 of their (2, wx, wy, wz) pair)
-    def win(ref):
-        return ref.at[pl.ds(ti * tx, wx), pl.ds(tj * ty, wy), :]
+    shifts = [oy % 8 for _, oy in origins]
+
+    def win(i):
+        ox, oy = origins[i]
+        oy -= shifts[i]
+        return hbm[i].at[pl.ds(ti * tx + ox if ox else ti * tx, wx),
+                         pl.ds(tj * ty + oy if oy else tj * ty,
+                               wins[i].shape[-2]), :]
 
     copies = [pltpu.make_async_copy(
-        win(hbm[i]), wins[i].at[0] if i < ns else wins[i], sems.at[i])
+        win(i), wins[i].at[0] if i < ns else wins[i], sems.at[i])
         for i in range(nw)]
     for c in copies:
         c.start()
     for c in copies:
         c.wait()
 
-    states, par_refs = wins[:ns], wins[ns:physics.num_windows]
+    states, par_refs = wins[:ns], wins[ns:]
     rec_out_ref[...] = jnp.zeros(rec_out_ref.shape, rec_out_ref.dtype)
     rows = jax.lax.broadcasted_iota(jnp.int32, (spec.T * nch, spec.rec_cap),
                                     0)
@@ -258,15 +279,14 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
             x0 = jnp.maximum(jnp.minimum(lo, hi_k - b), r)
             rd = pl.ds(x0 - r, b + 2 * r)
             st_in = {f: cur[i][rd] for i, f in enumerate(physics.state_fields)}
-            pr_in = {f: par_refs[i][rd]
+            pr_in = {f: (par_refs[i][rd, pl.ds(shifts[ns + i], wy)]
+                         if shifts[ns + i] else par_refs[i][rd])
                      for i, f in enumerate(physics.param_fields)}
-            dom = (wins[nw - 1][rd] if external_dom
-                   else _domain_mask(spec, ti, tj, x0 - r, b + 2 * r))
+            dom = _domain_mask(spec, ti, tj, x0 - r, b + 2 * r, box)
             new = physics.update(st_in, pr_in, spec, lambda a: a * dom)
             # (computed, not sliced out of `dom`: Mosaic's strided-slice
             # rule mis-handles the iota mask's lane-replicated layout)
-            dom_c = (wins[nw - 1][pl.ds(x0, b)] if external_dom
-                     else _domain_mask(spec, ti, tj, x0, b))
+            dom_c = _domain_mask(spec, ti, tj, x0, b, box)
             for i, f in enumerate(physics.state_fields):
                 v = new[f][r:r + b]
                 if f in physics.evolved_fields and \
@@ -334,7 +354,8 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
 def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
                  state_pads, param_pads,
                  src_coords, src_vals, rec_coords, rec_w,
-                 *, dom_pad=None, interpret: Optional[bool] = None):
+                 *, origins=None, dom_box=None,
+                 interpret: Optional[bool] = None):
     """One depth-T time tile over the whole grid (one pallas_call).
 
     Args:
@@ -344,21 +365,23 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
       src_coords: (ntiles, cap, 3) window-local int32.
       src_vals:   (ntiles, T, cap) f32, scale folded in, 0 on padding.
       rec_coords: (ntiles, capr, 3); rec_w: (ntiles, capr).
-      dom_pad:    optional (nx + 2H, ny + 2H, nz) 0/1 domain mask overriding
-                  the spec-derived one — used when this kernel runs on one
-                  shard of a decomposed grid (distributed/halo.py), where
-                  "inside the physical domain" depends on the shard offset.
-                  It is DMA'd per tile through the same `(ti*tx, tj*ty)`
-                  window slice as the field operands, so it composes with
-                  a multi-tile inner grid (spec.tile < (nx, ny)) exactly
-                  like the state windows.  The sharded layer exploits this
-                  twice (DESIGN.md §4): the flat schedule tiles the whole
-                  exchanged shard block in one `pallas_call`, and the
-                  time-nested schedule issues one call PER PASS with the
-                  spec's grid/halo parameterized by the remaining exchange
-                  depth (`ops.pass_inner_spec`: grid = block + 2*d_out
-                  rounded up to the tile, halo = inner_T * r_step) —
-                  dom_pad then also masks the round-up garbage band.
+      origins:    optional static (ox, oy) per state then param operand:
+                  where that operand's (nx + 2H, ny + 2H) padded grid
+                  starts inside it, so a larger array is read in place
+                  (the sharded execution layer's frames, distributed/
+                  halo.py); None is (0, 0) for every operand.  A state
+                  operand's oy must lie on the 8-row tiling.
+      dom_box:    optional (4,) int32 [x_lo, x_hi, y_lo, y_hi]: the
+                  physical domain in kernel-grid coordinates, overriding
+                  [0, nx) x [0, ny) — used when this kernel runs on one
+                  shard of a decomposed grid, where "inside the physical
+                  domain" depends on the shard offset.  A traced value,
+                  read from SMEM; the mask stays an iota predicate, so no
+                  grid-sized mask exists in HBM or VMEM.  The time-nested
+                  schedule issues one call PER PASS with the spec's
+                  grid/halo parameterized by the remaining exchange depth
+                  (`ops.pass_inner_spec`: grid = block + 2*d_out rounded
+                  up to the tile, halo = inner_T * r_step).
       interpret:  None picks by backend (`platform.resolve_interpret`).
 
     Each tile's rows of the four tables reach SMEM as flattened blocks:
@@ -377,22 +400,30 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
     if tables != want:
         raise ValueError(f"tile tables {tables} do not match the spec's "
                          f"{want}")
-    dom_pads = () if dom_pad is None else (dom_pad,)
     tables = (src_coords, src_vals, rec_coords, rec_w)
     ns = len(physics.state_fields)
-    external_dom = bool(dom_pads)
-    nw = physics.num_windows + (1 if external_dom else 0)
-    ntx, nty = spec.ntiles
+    nw = physics.num_windows
     wx, wy, wz = spec.window
     nch = physics.rec_channels
-    kern = functools.partial(_tb_kernel, spec, physics, external_dom)
+    origins = tuple(origins) if origins is not None else ((0, 0),) * nw
+    if any(oy % 8 for _, oy in origins[:ns]):
+        raise ValueError(f"state origins {origins[:ns]} must start on the "
+                         f"8-row tiling in y")
+    # a param window off the tiling is read from the row below, 8 taller
+    rows_y = [wy + (8 if oy % 8 else 0) for _, oy in origins]
+    has_box = dom_box is not None
+    kern = functools.partial(_tb_kernel, spec, physics, origins, has_box)
     # the last tile row's window reaches past the halo when y is rounded
     # up to the tiling; edge values there stay finite and off the centre
-    hbm = (*state_pads, *param_pads, *dom_pads)
-    extra = (nty - 1) * spec.tile[1] + wy - hbm[0].shape[1]
-    if extra > 0:
-        hbm = [jnp.pad(a, ((0, 0), (0, extra), (0, 0)), mode="edge")
-               for a in hbm]
+    hbm = []
+    for a, (ox, oy), ry in zip((*state_pads, *param_pads), origins, rows_y):
+        if ox + (ntx - 1) * spec.tile[0] + wx > a.shape[0]:
+            raise ValueError(f"operand {a.shape} at origin {(ox, oy)} "
+                             f"does not hold the kernel grid's windows")
+        extra = oy - oy % 8 + (nty - 1) * spec.tile[1] + ry - a.shape[1]
+        if extra > 0:
+            a = jnp.pad(a, ((0, 0), (0, extra), (0, 0)), mode="edge")
+        hbm.append(a)
     tile_idx = lambda i, j: (i * nty + j, 0, 0)  # noqa: E731
     # one flattened row per tile, so the SMEM block pads only its length
     rows = [t.astype(dt).reshape(ntx * nty, 1, -1)
@@ -400,6 +431,7 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
                                       jnp.float32))]
 
     rec_shape = (ntx * nty, spec.T * nch, spec.rec_cap)
+    boxes = [jnp.asarray(dom_box, jnp.int32)] if has_box else []
     outs = pl.pallas_call(
         kern,
         grid=(ntx, nty),
@@ -407,6 +439,7 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
             [pl.BlockSpec(memory_space=pl.ANY)] * nw
             + [pl.BlockSpec((1, 1, t.shape[2]), tile_idx,
                             memory_space=pltpu.SMEM) for t in rows]
+            + [pl.BlockSpec(memory_space=pltpu.SMEM)] * has_box
         ),
         out_specs=(
             [pl.BlockSpec((spec.tile[0], spec.tile[1], spec.nz),
@@ -420,7 +453,7 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
         ),
         scratch_shapes=(
             [pltpu.VMEM((2, wx, wy, wz), spec.dtype)] * ns
-            + [pltpu.VMEM((wx, wy, wz), spec.dtype)] * (nw - ns)
+            + [pltpu.VMEM((wx, ry, wz), spec.dtype) for ry in rows_y[ns:]]
             + [pltpu.SemaphoreType.DMA((nw,))]
         ),
         # the budget the autotuner planned this tile against
@@ -428,7 +461,7 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
         interpret=resolve_interpret(interpret),
         # a stable name for the kernel's events on the device trace
         name="tb_time_tile",
-    )(*hbm, *rows)
+    )(*hbm, *rows, *boxes)
     rec = outs[ns].reshape(ntx, nty, spec.T, nch, spec.rec_cap)
     return tuple(outs[:ns]), jnp.swapaxes(rec, 3, 4)
 

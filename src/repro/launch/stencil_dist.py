@@ -182,7 +182,7 @@ def main():
     from repro.core.grid import Grid
     from repro.core.temporal_blocking import TBPlan
     from repro.distributed.halo import (DistTBPlan, dist_plan_from_hier,
-                                        sharded_tb_propagate)
+                                        sharded_lower, sharded_propagate)
     from repro.kernels import tb_physics as phys
     from repro.launch import mesh as mesh_lib
     from repro.survey.plan_cache import cached_plan_hierarchy
@@ -272,14 +272,10 @@ def main():
         npar = len(plan.physics.param_fields)
         u = jax.ShapeDtypeStruct(shape, jnp.float32)
 
-        def fn(*arrays):
-            state = arrays[:ns]
-            params = dict(zip(plan.physics.param_fields, arrays[ns:]))
-            return sharded_tb_propagate(plan, args.T * 2, state, params,
-                                        None)
-
         with mesh:
-            lowered = jax.jit(fn).lower(*([u] * (ns + npar)))
+            lowered = sharded_lower(
+                plan, args.T * 2, (u,) * ns,
+                dict(zip(plan.physics.param_fields, (u,) * npar)))
             compiled = lowered.compile()
             print("memory:", compiled.memory_analysis())
             ca = compiled.cost_analysis()
@@ -315,10 +311,12 @@ def main():
 
     def run(T):
         plan = build_plan(mesh, shape, grid, physics, order, dt, T)
-        # jit on purpose: the parity checks double as a regression test of
-        # the driver's jit-compatibility contract (state/params traced)
-        fn = jax.jit(functools.partial(sharded_tb_propagate, plan, nt,
-                                       g=g, receivers=gr))
+
+        def fn(state, params):
+            # the entry donates its state: every call gets a copy
+            return sharded_propagate(plan, nt, [jnp.copy(f) for f in state],
+                                     params, g, gr)
+
         with mesh:
             with tele.span("dist.propagate", T=T, nt=nt) as sp:
                 out = fn(state, params)

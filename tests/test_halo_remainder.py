@@ -1,16 +1,16 @@
 """Remainder-tile exchange costs (ISSUE 5 satellite / ROADMAP open item).
 
 The `nt % T` remainder tile is strictly shallower than the main tiles, so
-its padded params and domain mask are a collective-free per-shard centre
-crop of the main tiles' deep-exchanged ones — `_depth_setup(...,
-prepped=...)` must run ZERO param ppermute rounds for it, and the
-overlapped (split-first-step) schedule must cover the remainder exactly
-like full tiles.  Runs in-process on a 1x1 mesh (the ppermute algebra is
-identical; no device forcing needed).
+it reads the params in the frames the main tiles' exchange filled (the
+domain mask is an iota predicate, never an array) — it must run ZERO
+param ppermute rounds, and the overlapped (split-first-step) schedule
+must cover the remainder exactly like full tiles.  Runs in-process on a
+1x1 mesh (the ppermute algebra is identical; no device forcing needed).
 """
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import repro.distributed.halo as H
@@ -46,28 +46,32 @@ def acoustic_case():
 
 
 def test_remainder_setup_runs_no_param_exchange(acoustic_case, monkeypatch):
-    """With the main tiles' pads handed over, the remainder `_depth_setup`
-    must never touch `halo_exchange_2d` — its params come from a local
-    crop, not a second ppermute round."""
+    """The params are exchanged into their frames once per propagate, at
+    the main tiles' depth; the remainder tile reads the same frames
+    deeper in, with no second param ppermute round."""
     plan, nt, state, params, g, gr, _ = acoustic_case
-    with plan.mesh:
-        _, _, main_pads = H._depth_setup(plan, plan.T, g, gr, params, True)
-        assert main_pads[2] == plan.halo
-
-        calls = []
-        orig = H.halo_exchange_2d
-        monkeypatch.setattr(
-            H, "halo_exchange_2d",
-            lambda *a, **k: calls.append(a[1]) or orig(*a, **k))
-
-        rplan = plan._replace(T=1)
-        H._depth_setup(rplan, 1, g, gr, params, True, prepped=main_pads)
-        assert calls == [], ("remainder setup re-exchanged params at "
-                             f"depths {calls}")
-
-        # without the handover it would have paid one round per param
-        H._depth_setup(rplan, 1, g, gr, params, True)
-        assert len(calls) == len(phys.ACOUSTIC.param_fields)
+    assert nt % plan.T, "the case must run a remainder tile"
+    calls = []
+    orig = H._param_frames
+    monkeypatch.setattr(
+        H, "_param_frames",
+        lambda *a, **k: calls.append(1) or orig(*a, **k))
+    depths = []
+    orig_x = H.exchange_to_depth
+    monkeypatch.setattr(
+        H, "exchange_to_depth",
+        lambda x, depth, h, *a, **k: depths.append(depth) or
+        orig_x(x, depth, h, *a, **k))
+    with plan.mesh:     # tracing the propagate is enough to count
+        jax.make_jaxpr(lambda s, p: H.sharded_tb_propagate(
+            plan, nt, s, p, g=g, receivers=gr))(state, params)
+    assert calls == [1], "params were exchanged more than once"
+    # one exchange per param at the main depth, then the state fields'
+    # per-field depths for the main tile and for the remainder tile
+    npar = len(phys.ACOUSTIC.param_fields)
+    assert depths[:npar] == [plan.halo] * npar
+    assert depths[npar:] == (list(plan.field_depths(plan.T))
+                             + list(plan.field_depths(nt % plan.T)))
 
 
 def test_remainder_reuse_parity(acoustic_case):

@@ -67,7 +67,7 @@ class TestAcoustic:
     def test_zcompressed_injection_equivalent_run(self):
         """Full run with Listing-5 (z-compressed) injection == scatter run."""
         params, dt, g, _ = _setup_acoustic()
-        zc = S.z_compress(g)
+        zc = S.z_compress(g, GRID.shape)
         scale = (dt * dt) / S.point_scale(params.m, g)
 
         def inj_zc(u, t):

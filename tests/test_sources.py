@@ -70,7 +70,7 @@ class TestPrecompute:
         op = _rand_sources(4, seed=2)
         wav = S.ricker_wavelet(6, 0.001, 10.0, 4)
         g = S.precompute(op, GRID, wav)
-        sm, sid = np.asarray(g.sm), np.asarray(g.sid)
+        sm, sid = g.sm(GRID.shape), g.sid(GRID.shape)
         assert set(np.unique(sm)) <= {0, 1}
         # SID is -1 exactly where SM is 0, unique ascending elsewhere
         assert np.all((sid >= 0) == (sm == 1))
@@ -79,6 +79,43 @@ class TestPrecompute:
         # points are in SID order
         np.testing.assert_array_equal(
             sid[tuple(np.asarray(g.points).T)], np.arange(g.npts))
+
+    @pytest.mark.parametrize("by_injection", [False, True])
+    def test_no_grid_sized_array_and_dense_sm_sid_on_request(self,
+                                                            by_injection):
+        """`precompute` puts nothing grid-sized on a device — every array
+        it leaves alive follows the affected points — and the SM / SID
+        made on request from the points equal the dense volumes the
+        paper's Listing 2 builds, bit for bit, as does src_dcmp read
+        through the dense SID."""
+        op = _rand_sources(5, seed=11)
+        wav = S.ricker_wavelet(6, 0.001, 10.0, 5) + 0.5
+        before = {id(a) for a in jax.live_arrays()}
+        g = S.precompute(op, GRID, wav, discover_by_injection=by_injection)
+        new = [a for a in jax.live_arrays() if id(a) not in before]
+        assert new and all(a.size < GRID.npoints for a in new)
+        # the dense volumes, built the paper's way: inject into an empty
+        # grid, read off the non-zero points in order
+        st = S.interp_stencil(op, GRID)
+        dense = np.zeros(GRID.shape)
+        for k in range(op.num):
+            for i in range(st.indices.shape[1]):
+                dense[tuple(st.indices[k, i])] += st.weights[k, i] * wav[0, k]
+        pts = np.argwhere(dense != 0.0)
+        sm = np.zeros(GRID.shape, np.uint8)
+        sid = np.full(GRID.shape, -1, np.int32)
+        sm[tuple(pts.T)] = 1
+        sid[tuple(pts.T)] = np.arange(len(pts), dtype=np.int32)
+        got_sm, got_sid = g.sm(GRID.shape), g.sid(GRID.shape)
+        assert got_sm.dtype == sm.dtype and got_sid.dtype == sid.dtype
+        np.testing.assert_array_equal(got_sm, sm)
+        np.testing.assert_array_equal(got_sid, sid)
+        ids = sid[tuple(st.indices.reshape(-1, 3).T)]
+        dcmp = np.zeros((wav.shape[0], len(pts)))
+        np.add.at(dcmp.T, ids, (wav[:, np.repeat(np.arange(op.num), 8)]
+                                * st.weights.reshape(-1)[None]).T)
+        np.testing.assert_array_equal(np.asarray(g.src_dcmp),
+                                      dcmp.astype(np.float32))
 
     def test_decomposition_matches_listing1(self):
         """Scatter of src_dcmp == the original off-the-grid injection."""
@@ -127,16 +164,16 @@ class TestZCompression:
         op = _rand_sources(5, seed=5)
         wav = S.ricker_wavelet(4, 0.001, 10.0, 5)
         g = S.precompute(op, GRID, wav)
-        zc = S.z_compress(g)
+        zc = S.z_compress(g, GRID.shape)
         np.testing.assert_array_equal(np.asarray(zc.nnz_mask),
-                                      np.asarray(g.sm).sum(axis=2))
+                                      g.sm(GRID.shape).sum(axis=2))
 
     def test_injection_equivalence(self):
         """Listing-5 (z-compressed) == Listing-4 (masked) == scatter."""
         op = _rand_sources(5, seed=6)
         wav = np.random.RandomState(3).randn(4, 5)
         g = S.precompute(op, GRID, wav)
-        zc = S.z_compress(g)
+        zc = S.z_compress(g, GRID.shape)
         for t in range(4):
             t_ = jnp.asarray(t)
             u_scatter = S.inject(jnp.zeros(GRID.shape), g, t_)
@@ -221,14 +258,8 @@ class TestTileTableEdgeCases:
     def test_point_on_tile_boundary_owned_by_next_tile(self):
         """A point at exactly x = tx belongs to tile 1's centre, and its
         window-local coordinate equals the halo overhang."""
-        sm = np.zeros(GRID.shape, np.uint8)
-        sid = np.full(GRID.shape, -1, np.int32)
         pts = np.array([[4, 0, 0]], np.int32)  # exactly on the x boundary
-        sm[4, 0, 0] = 1
-        sid[4, 0, 0] = 0
-        g = S.GriddedSources(jnp.asarray(sm), jnp.asarray(sid),
-                             jnp.asarray(pts),
-                             jnp.ones((2, 1), jnp.float32))
+        g = S.GriddedSources(jnp.asarray(pts), jnp.ones((2, 1), jnp.float32))
         tab = S.tile_source_tables(g, GRID.shape, (4, 4), 0)
         nty = -(-GRID.shape[1] // 4)
         owner = np.flatnonzero(np.asarray(tab.nnz))
@@ -240,14 +271,8 @@ class TestTileTableEdgeCases:
         """include_halo=True assigns a point to EVERY tile whose window
         (centre + halo) contains it — the paper's Fig. 4b dependency —
         with consistent window-local coordinates."""
-        sm = np.zeros(GRID.shape, np.uint8)
-        sid = np.full(GRID.shape, -1, np.int32)
         pts = np.array([[4, 4, 1]], np.int32)  # corner of 4 tile centres
-        sm[4, 4, 1] = 1
-        sid[4, 4, 1] = 0
-        g = S.GriddedSources(jnp.asarray(sm), jnp.asarray(sid),
-                             jnp.asarray(pts),
-                             jnp.ones((2, 1), jnp.float32))
+        g = S.GriddedSources(jnp.asarray(pts), jnp.ones((2, 1), jnp.float32))
         tile, halo = (4, 4), 2
         tab = S.tile_source_tables(g, GRID.shape, tile, halo,
                                    include_halo=True)

@@ -286,9 +286,9 @@ def test_ops_tables_slot_fill_hand_count(tiny_propagate):
 def test_sources_precompute_records_host_bytes(tiny_propagate):
     c, _, _ = tiny_propagate
     by = {r.name: r.attrs for r in c.records()}
-    n = int(np.prod(TINY["shape"]))
+    # the host builds no dense SM / SID grid: only the per-point wavelets
     assert by["sources.precompute"] == {
-        "nsrc": 1, "npts": 8, "sm_bytes": n, "sid_bytes": 4 * n,
+        "nsrc": 1, "npts": 8, "sm_bytes": 0, "sid_bytes": 0,
         "src_dcmp_bytes": 8 * TINY["nt"] * 8}
     assert by["sources.precompute_receivers"] == {
         "nrec": 1, "npts": 8, "indices_bytes": 8 * 3 * 4,

@@ -95,3 +95,51 @@ def test_tb_time_tile_compiles_for_v5e(one_chip, monkeypatch, physics,
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().output_size_in_bytes >= \
         (batch or 1) * len(physics.state_fields) * N ** 3 * 4
+
+
+# what XLA:TPU holds back of a v5e chip's HBM beside a program (the
+# "reserved" line of its out-of-memory report)
+XLA_RESERVED = 258 * 2 ** 20
+
+
+def test_sharded_entry_fits_v5e_2x2_at_1024(topo):
+    """The sharded entry's program for acoustic SO-4 at 1024^3 (nt 399,
+    the plan the joint autotuner picks for a 512x512 block) compiles for
+    a described v5e:2x2, one 512x512x1024 block a chip, and fits a chip:
+    arguments + temps + XLA's reserved share at most 15.0 GB a device,
+    the final state taking the donated initial state's memory."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import sources as S
+    from repro.core.grid import Grid
+    from repro.distributed import halo
+
+    n, nt, order = 1024, 399, 4
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    grid = Grid(shape=(n,) * 3, spacing=(10.0,) * 3)
+    dt = grid.cfl_dt(3500.0, order)
+    plan = halo.sharded_plan(mesh, phys.ACOUSTIC, grid.shape, order, dt,
+                             grid.spacing)
+    assert plan.block == (512, 512) and nt % plan.T
+    c = 5.0 * (n - 1)
+    g = S.precompute(S.SparseOperator([[c + 3.3, c + 4.4, 155.0]]), grid,
+                     S.ricker_wavelet(nt, dt, f0=10.0))
+    rec = np.stack([c + 10.0 * np.linspace(-150, 150, 32),
+                    np.full(32, c + 13.0), np.full(32, 105.0)], axis=1)
+    gr = S.precompute_receivers(S.SparseOperator(rec), grid)
+    field = jax.ShapeDtypeStruct(grid.shape, jnp.float32,
+                                 sharding=NamedSharding(mesh, P("data",
+                                                                "model",
+                                                                None)))
+    compiled = halo.sharded_lower(
+        plan, nt, (field, field), {"m": field, "damp": field}, g, gr,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                  + XLA_RESERVED)
+    assert per_device <= 15.0e9, per_device
+    # the output holds the state (aliased to the donated input) and the
+    # traces
+    assert ma.alias_size_in_bytes == 2 * 512 * 512 * n * 4
