@@ -17,7 +17,8 @@ it — `tb_propagate_prepared` — is a pure traced function of jnp pytrees
 what makes the survey engine possible: `survey/engine.py` stacks the
 prepared tables of a whole shot bucket and runs `tb_propagate_prepared`
 over the shot axis (a per-shot `jax.lax.map` for the Pallas executor,
-`jax.vmap` for jnp), one jit trace per bucket.
+`jax.vmap` for jnp), one jit trace per bucket.  The one-chip entry points
+run it under `_tb_propagate_jit`, once per plan and array shapes.
 Each time tile runs through one of two executors sharing the same window
 schedule: `executor="pallas"` (the `stencil_tb` kernel, interpret mode
 off-TPU) or `executor="jnp"` (`_jnp_time_tile`, the same per-window
@@ -27,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -396,28 +398,25 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
     return carry, recs
 
 
-def _tb_propagate(physics: phys.TBPhysics, nt: int,
-                  state: Tuple[jnp.ndarray, ...],
-                  params: Dict[str, jnp.ndarray],
-                  g: Optional[src_mod.GriddedSources],
-                  receivers: Optional[src_mod.GriddedReceivers],
-                  plan: TBPlan, order: int, dt,
-                  spacing: Tuple[float, float, float],
-                  interpret: Optional[bool] = None, executor: str = "pallas"):
-    """Propagate nt timesteps of `physics` with the temporally-blocked kernel.
+def _device_tables(tab):
+    """A table as the jitted propagate takes it: its device arrays, without
+    the host-side `nnz` the kernel never reads (which would otherwise be
+    copied to the device on every call)."""
+    return None if tab is None else tab._replace(nnz=None)
 
-    Semantics identical to the reference propagator in `core/propagators/`
-    (tested): trapezoidal time tiles of depth plan.T, remainder tile of
-    depth nt % T.  `state` is ordered as physics.state_fields; `params`
-    maps physics.param_fields to (nx, ny, nz) arrays.
 
-    Host-side orchestration (table precompute) happens eagerly here; the
-    traced tile loop is `tb_propagate_prepared`.  With the default
-    `executor="pallas"` each time tile is one `pallas_call`;
-    `executor="jnp"` runs the identical window schedule in pure jnp.
-
-    Returns (final state tuple, rec (nt, nrec, rec_channels) | None).
-    """
+def _prepare(physics: phys.TBPhysics, nt: int,
+             state: Tuple[jnp.ndarray, ...],
+             params: Dict[str, jnp.ndarray],
+             g: Optional[src_mod.GriddedSources],
+             receivers: Optional[src_mod.GriddedReceivers],
+             plan: TBPlan, order: int, dt,
+             spacing: Tuple[float, float, float]):
+    """The host half of `_tb_propagate`: bins the per-tile tables (span
+    `ops.tables`, with the slot-fill and update counters) and sizes the
+    kernel specs' caps from them.  Returns the static arguments
+    (physics, nt, spec, rspec, nrec) and the array arguments (state,
+    params, src_dcmp, tables) of `_tb_propagate_jit`."""
     shape = state[0].shape
     dtype = state[0].dtype
     dt = float(dt)
@@ -439,15 +438,12 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
         rec_cap = rec_tab.coords.shape[1] if rec_tab is not None else 1
         spec = specced(src_cap, rec_cap)
 
-        h = spec.halo
-        param_pads = tuple(_pad_xy(params[f], h, "edge")
-                           for f in physics.param_fields)
         nrec = receivers.num if receivers is not None else 0
         src_dcmp = (g.src_dcmp if g is not None
                     else jnp.zeros((max(nt, 1), 1), dtype))
 
         rem = nt % spec.T
-        rspec = rsrc_tab = rrec_tab = rparam_pads = None
+        rspec = rsrc_tab = rrec_tab = None
         if rem > 0:
             # remainder tables must be rebuilt: halo depth changes with T
             # (and with it the entries per tile, so caps as well)
@@ -457,22 +453,78 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
                 rsrc_tab.cap if rsrc_tab is not None else 1,
                 rrec_tab.coords.shape[1] if rrec_tab is not None else 1,
                 T=rem)
-            rparam_pads = tuple(_pad_xy(params[f], rspec.halo, "edge")
-                                for f in physics.param_fields)
         if _spans.active():
             sp.set(**slot_fill(spec, nt, (src_tab, rec_tab),
                                (rsrc_tab, rrec_tab)),
                    **update_counts(spec, nt))
+    tables = tuple(map(_device_tables, (src_tab, rec_tab, rsrc_tab,
+                                        rrec_tab)))
+    return ((physics, nt, spec, rspec, nrec),
+            (tuple(state), tuple(params[f] for f in physics.param_fields),
+             src_dcmp, tables))
 
+
+# `traced` is set by `_tb_propagate_traced`'s Python body, which runs only
+# while the jit traces it, in the calling thread: never on a call its cache
+# serves
+_tracing = threading.local()
+
+
+def _tb_propagate_traced(physics, nt, spec, rspec, nrec, interpret,
+                         executor, state, params, src_dcmp, tables):
+    _tracing.traced = True
+    param_pads = tuple(_pad_xy(p, spec.halo, "edge") for p in params)
+    rparam_pads = (tuple(_pad_xy(p, rspec.halo, "edge") for p in params)
+                   if rspec is not None else None)
+    return tb_propagate_prepared(physics, nt, spec, rspec, state, param_pads,
+                                 rparam_pads, src_dcmp, *tables, nrec,
+                                 interpret=interpret, executor=executor)
+
+
+# the one-chip propagate, compiled once per plan (the specs), nt, nrec,
+# executor and the shapes and dtypes of its arrays; no argument is donated
+# (callers reuse their state arrays)
+_tb_propagate_jit = jax.jit(_tb_propagate_traced,
+                            static_argnums=(0, 1, 2, 3, 4, 5, 6))
+
+
+def _tb_propagate(physics: phys.TBPhysics, nt: int,
+                  state: Tuple[jnp.ndarray, ...],
+                  params: Dict[str, jnp.ndarray],
+                  g: Optional[src_mod.GriddedSources],
+                  receivers: Optional[src_mod.GriddedReceivers],
+                  plan: TBPlan, order: int, dt,
+                  spacing: Tuple[float, float, float],
+                  interpret: Optional[bool] = None, executor: str = "pallas"):
+    """Propagate nt timesteps of `physics` with the temporally-blocked kernel.
+
+    Semantics identical to the reference propagator in `core/propagators/`
+    (tested): trapezoidal time tiles of depth plan.T, remainder tile of
+    depth nt % T.  `state` is ordered as physics.state_fields; `params`
+    maps physics.param_fields to (nx, ny, nz) arrays.
+
+    The host-side table binning (`_prepare`) runs eagerly on every call;
+    the traced half — the param pads and `tb_propagate_prepared` — is one
+    jitted program, traced, lowered and compiled once per plan, nt,
+    executor and array shapes and dtypes, so a warm call goes straight to
+    the compiled program.  With the default `executor="pallas"` each time
+    tile is one `pallas_call`; `executor="jnp"` runs the identical window
+    schedule in pure jnp.
+
+    Returns (final state tuple, rec (nt, nrec, rec_channels) | None).
+    """
+    static, args = _prepare(physics, nt, state, params, g, receivers, plan,
+                            order, dt, spacing)
+    spec = static[2]
     with _spans.span("ops.propagate", physics=physics.name, nt=nt,
                      T=spec.T, executor=executor) as sp:
-        # the eager driver traces, lowers and compiles (or loads) its scan
-        # and remainder call on every call: `compiles` counts them
-        with _spans.span("ops.dispatch", count_compiles=True):
-            carry, recs = tb_propagate_prepared(
-                physics, nt, spec, rspec, state, param_pads, rparam_pads,
-                src_dcmp, src_tab, rec_tab, rsrc_tab, rrec_tab, nrec,
-                interpret=interpret, executor=executor)
+        # `traced`: this call traced (and lowered, compiled or loaded) the
+        # jitted program; a call its cache serves only enqueues it
+        with _spans.span("ops.dispatch", count_compiles=True) as dsp:
+            _tracing.traced = False
+            carry, recs = _tb_propagate_jit(*static, interpret, executor,
+                                            *args)
+            dsp.set(traced=_tracing.traced)
         sp.sync((carry, recs))
     if receivers is None:
         recs = None

@@ -223,3 +223,129 @@ def test_property_kernel_equals_oracle(seed, T, nsrc):
                                rtol=5e-4, atol=1e-6)
     np.testing.assert_allclose(np.asarray(krec), np.asarray(rrec),
                                rtol=5e-4, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The jitted entry: one trace per plan, nt and array shapes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def spans_on():
+    from repro.telemetry import spans as tsp
+    c = tsp.enable(jax_profiler=False)
+    yield c
+    tsp.disable()
+
+
+def _call_spans(c, run):
+    """Run one propagate under the collector `c`; returns its result, the
+    attributes of its `ops.dispatch` and of its `ops.tables`, and the names
+    of every span it recorded."""
+    c.clear()
+    out = run()
+    recs = c.records()
+    (d,) = [r for r in recs if r.name == "ops.dispatch"]
+    (t,) = [r for r in recs if r.name == "ops.tables"]
+    return out, d.attrs, t.attrs, {r.name for r in recs}
+
+
+@pytest.mark.parametrize("executor", ["pallas", "jnp"])
+def test_warm_call_reuses_the_compiled_propagate(spans_on, executor):
+    nt, order = 5, 4
+    grid, m, damp, dt, g, gr, u0, u1 = _setup(order=order, nt=nt)
+    plan = TBPlan(tile=(8, 8), T=2, radius=order // 2)
+    ops._tb_propagate_jit.clear_cache()
+
+    def run():
+        return ops.acoustic_tb_propagate(nt, u0, u1, m, damp, g, gr, plan,
+                                         order, dt, grid.spacing,
+                                         executor=executor)
+
+    cold, d0, _, names0 = _call_spans(spans_on, run)
+    warm, d1, _, names1 = _call_spans(spans_on, run)
+    assert d0["traced"] is True and d0["compiles"] >= 1
+    assert "ops.tile_pass" in names0
+    assert d1 == {"traced": False, "compiles": 0}
+    assert "ops.tile_pass" not in names1
+    for a, b in zip(jax.tree.leaves(cold), jax.tree.leaves(warm)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("executor", ["pallas", "jnp"])
+@pytest.mark.parametrize("change", ["nt", "sources"])
+def test_new_nt_or_caps_trace_again(spans_on, executor, change):
+    order = 4
+    plan = TBPlan(tile=(8, 8), T=2, radius=order // 2)
+    ops._tb_propagate_jit.clear_cache()
+
+    def run(nt, nsrc):
+        grid, m, damp, dt, g, gr, u0, u1 = _setup(order=order, nt=6,
+                                                  nsrc=nsrc, nrec=3)
+        got = ops.acoustic_tb_propagate(nt, u0, u1, m, damp, g, gr, plan,
+                                        order, dt, grid.spacing,
+                                        executor=executor)
+        want = ref.acoustic_reference(nt, u0, u1, m, damp, dt,
+                                      grid.spacing, order, g=g,
+                                      receivers=gr)
+        return got, want
+
+    _, _, t0, _ = _call_spans(spans_on, lambda: run(4, 1))
+    nt, nsrc = (6, 1) if change == "nt" else (4, 3)
+    ((got, want), d, t, names) = _call_spans(spans_on, lambda: run(nt, nsrc))
+    assert d["traced"] is True and "ops.tile_pass" in names
+    if change == "sources":
+        # the caps the tables are sized to grew with the sources
+        assert t["src_slots"][0] > t0["src_slots"][0]
+    (ku0, ku1), krec = got
+    (ru0, ru1), rrec = want
+    assert krec.shape == (nt, 3) and ku1.shape == (16, 16, 12)
+    for k, r in ((ku0, ru0), (ku1, ru1), (krec, rrec)):
+        np.testing.assert_allclose(np.asarray(k), np.asarray(r),
+                                   rtol=2e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("executor", ["pallas", "jnp"])
+@pytest.mark.parametrize("physics,nt,T", [
+    ("acoustic", 7, 3),     # two depth-3 tiles and a depth-1 remainder
+    ("tti", 4, 2),
+])
+def test_jitted_entry_matches_eager_core(physics, nt, T, executor):
+    """`_tb_propagate_jit` on the prepared inputs gives what the traced
+    core, `tb_propagate_prepared`, gives called eagerly on them."""
+    from repro.kernels import tb_physics as phys
+    from test_kernel_multiphysics import _tti_setup
+
+    physics = phys.PHYSICS[physics]
+    order = 4
+    if physics.name == "acoustic":
+        grid, m, damp, dt, g, gr, u0, u1 = _setup(order=order, nt=nt)
+        state, params = (u0, u1), {"m": m, "damp": damp}
+    else:
+        grid, p, st, dt, g, gr = _tti_setup(shape=(12, 12, 8), nt=nt)
+        state = tuple(getattr(st, f) for f in physics.state_fields)
+        params = {f: getattr(p, f) for f in physics.param_fields}
+    plan = TBPlan(tile=(8, 8) if physics.name == "acoustic" else (6, 6),
+                  T=T, radius=physics.step_radius(order))
+    static, args = ops._prepare(physics, nt, state, params, g, gr, plan,
+                                order, dt, grid.spacing)
+    _, _, spec, rspec, nrec = static
+    assert (rspec is not None) == (nt % T > 0)
+    st_, params_, src_dcmp, tables = args
+    pads = tuple(ops._pad_xy(q, spec.halo, "edge") for q in params_)
+    rpads = (tuple(ops._pad_xy(q, rspec.halo, "edge") for q in params_)
+             if rspec is not None else None)
+    eager = ops.tb_propagate_prepared(physics, nt, spec, rspec, st_, pads,
+                                      rpads, src_dcmp, *tables, nrec,
+                                      executor=executor)
+    jitted = ops._tb_propagate_jit(*static, None, executor, *args)
+    assert jitted[1].shape == (nt, nrec, physics.rec_channels)
+    for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager)):
+        a, b = np.asarray(a), np.asarray(b)
+        if executor == "pallas":
+            np.testing.assert_array_equal(a, b)
+        else:
+            # XLA:CPU contracts a*b+c across what the eager call ran as
+            # separate programs: a few ulps of the field's scale
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=4 * np.finfo(np.float32).eps
+                * np.abs(b).max())

@@ -205,9 +205,9 @@ def _tiny_case():
 
 @pytest.fixture(scope="module")
 def tiny_propagate():
-    """One tiny acoustic propagate under a collector mirrored into the
-    profiler, with `TraceAnnotation` replaced by a recorder of names and a
-    compile listener of the test's own beside the collector's."""
+    """One tiny acoustic propagate, cold, under a collector mirrored into
+    the profiler, with `TraceAnnotation` replaced by a recorder of names
+    and a compile listener of the test's own beside the collector's."""
     import jax
     import jax.numpy as jnp
     from jax import monitoring
@@ -232,6 +232,9 @@ def tiny_propagate():
             compiled_at.append(time.perf_counter())
 
     shape = TINY["shape"]
+    # a cold call, even where an earlier test in this process already
+    # compiled the jitted propagate for these shapes
+    ops._tb_propagate_jit.clear_cache()
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax.profiler, "TraceAnnotation", Recorder)
         monitoring.register_event_duration_secs_listener(listen)

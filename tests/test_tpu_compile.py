@@ -7,8 +7,11 @@ refuse (unaligned blocks, scalar stores to VMEM, SMEM or scoped-VMEM
 overflow) fails here, without a chip.  The scoped-VMEM limit is the
 planner's own price of the plan (`TBPlan.kernel_vmem_bytes`), so a pass
 also shows that the price the autotuner planned with bounds what Mosaic
-allocates.  The topology is described inside a fixture, so collection is
-identical in every test worker.
+allocates.  Two further checks compile whole entry programs and hold
+their footprint under a chip's memory: the one-chip jitted propagate at
+512^3 and the sharded one at 1024^3 on a v5e:2x2.  The topology is
+described inside a fixture, so collection is identical in every test
+worker.
 """
 import os
 
@@ -143,3 +146,56 @@ def test_sharded_entry_fits_v5e_2x2_at_1024(topo):
     # the output holds the state (aliased to the donated input) and the
     # traces
     assert ma.alias_size_in_bytes == 2 * 512 * 512 * n * 4
+
+
+@pytest.mark.parametrize("physics,spacing,nt", [
+    ("acoustic", 10.0, 399),    # the T 8 scan and the T 7 remainder tile
+    ("tti", 20.0, 200),
+])
+def test_one_chip_entry_fits_v5e_at_512(one_chip, physics, spacing, nt):
+    """The one-chip entry's jitted program (`ops._tb_propagate_jit`: the
+    param pads, the scan over depth-T tiles and the remainder tile) for SO-4 at 512^3
+    and the benchmark's nt, at the plan `plan_for_physics` picks, compiles
+    for a described v5e and fits a chip: arguments + output + temps +
+    XLA's reserved share at most 15.0 GB (no argument is donated, so the
+    final state takes memory of its own)."""
+    import numpy as np
+
+    from repro.core import sources as S
+    from repro.core.grid import Grid
+
+    physics = phys.PHYSICS[physics]
+    order = 4
+    grid = Grid(shape=(N,) * 3, spacing=(spacing,) * 3)
+    dt = grid.cfl_dt(3500.0, order)
+    plan, _ = plan_for_physics(physics.name, N, order)
+    assert (nt % plan.T > 0) == (physics.name == "acoustic")
+    c = spacing * (N - 1) / 2
+    g = S.precompute(S.SparseOperator([[c + 3.3, c + 4.4, 155.0]]), grid,
+                     S.ricker_wavelet(nt, dt, f0=10.0))
+    rec = np.stack([c + 10.0 * np.linspace(-150, 150, 32),
+                    np.full(32, c + 13.0), np.full(32, 105.0)], axis=1)
+    gr = S.precompute_receivers(S.SparseOperator(rec), grid)
+    field = jax.ShapeDtypeStruct(grid.shape, jnp.float32, sharding=one_chip)
+    # the host binning reads the injection scale at the source's points:
+    # constant models as zero-copy views, not 512^3 arrays
+    params = {f: np.broadcast_to(np.float32(1.0 / 1500.0 ** 2), grid.shape)
+              for f in physics.param_fields}
+    static, args = ops._prepare(
+        physics, nt, (field,) * len(physics.state_fields), params, g, gr,
+        plan, order, dt, grid.spacing)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    compiled = ops._tb_propagate_jit.lower(*static, False, "pallas",
+                                           *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    per_chip = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                + ma.temp_size_in_bytes + XLA_RESERVED)
+    assert per_chip <= 15.0e9, (ma.argument_size_in_bytes,
+                                ma.output_size_in_bytes,
+                                ma.temp_size_in_bytes)
+    state_bytes = len(physics.state_fields) * N ** 3 * 4
+    assert ma.alias_size_in_bytes == 0
+    assert ma.output_size_in_bytes >= state_bytes
