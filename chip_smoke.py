@@ -6,8 +6,8 @@ full width of the paper's §IV.B acoustic case (512^3, 10 m, space order 4):
   phase 1  one `acoustic_tb_propagate` under the autotuned plan, with an
            off-grid Ricker source and a 32-receiver line near the surface,
            checked against the plain reference propagator on the chip;
-  phase 2  a 4-shot `SurveyEngine.run` (executor "pallas", 2-shot
-           batches), checked against sequential `acoustic_tb_propagate`.
+  phase 2  a 4-shot `SurveyEngine.run` (2-shot batches), checked
+           against sequential `acoustic_tb_propagate`.
 
 With --four-chips it runs only the sharded route instead: the same case
 through the sharded entry point `sharded_propagate` on a 2x2 mesh (Pallas
@@ -161,8 +161,8 @@ def phase_survey(args):
                   rec_coords=rec, shot_id=i) for i in range(4)]
     params = {"m": m, "damp": damp}
     engine = SurveyEngine("acoustic", grid, params, nt, dt,
-                          order=case.space_order, executor="pallas",
-                          plan_cache=PlanCache(), bucket_cap=2)
+                          order=case.space_order, plan_cache=PlanCache(),
+                          bucket_cap=2)
     print(f"phase 2 survey: grid {grid.shape} nt {nt} 4 shots x "
           f"{NREC} receivers, bucket_cap 2, plan tile {engine.plan.tile} "
           f"T {engine.plan.T}")

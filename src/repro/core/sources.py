@@ -25,8 +25,8 @@ receiver is a local, grid-aligned operation.
 
 Everything here is host-side numpy precomputation producing jnp constants
 whose size follows the affected points, never the grid; it runs once per
-model setup, which is the paper's "negligible overhead" claim —
-benchmarked in `benchmarks/overhead_precompute.py`.
+model setup, which is the paper's "negligible overhead" claim (span
+`sources.precompute`).
 
 Interpolation itself lives in `core/interp.py` as a precomputed per-axis
 coefficient operator (`InterpCoeffs`, Devito's

@@ -11,7 +11,7 @@ Three layers:
    Pallas kernel and the distributed deep-halo exchange exploit.
 3. Analytical HBM-traffic/overlap models for the trapezoidal VMEM schedule —
    the TPU replacement for the paper's cache-aware roofline reasoning, used
-   by the autotuner (`benchmarks/table1_autotune.py`) and §Roofline — plus
+   by the autotuner and §Roofline — plus
    the interconnect term of the sharded outer trapezoid (exchange bytes and
    latency per depth-T tile, DESIGN.md §4), which makes `plan_for_physics`
    mesh-aware via `mesh_block`/`link_bw`/`link_latency`.  With a mesh

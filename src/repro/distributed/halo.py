@@ -19,14 +19,11 @@ jointly by `core.temporal_blocking.plan_hierarchy`):
                       back to the uniform window — fewer exchange bytes
                       with bit-identical valid centres.
     inner trapezoid   the per-shard schedule over the exchanged block,
-                      spatially tiled by `inner_plan.tile`: either the
-                      Pallas TB kernel (`stencil_tb.tb_time_tile`,
-                      `inner="pallas"`, one kernel grid of block/tile
-                      windows per pass, DMA'd in place out of the
-                      shard's frames, its domain mask an iota predicate
-                      over the shard's domain box) or its jnp oracle
-                      (`inner="jnp"`), which loops the SAME per-window
-                      schedule in pure jnp.
+                      spatially tiled by `inner_plan.tile`: the Pallas TB
+                      kernel (`stencil_tb.tb_time_tile`), one kernel grid
+                      of block/tile windows per pass, DMA'd in place out
+                      of the shard's frames, its domain mask an iota
+                      predicate over the shard's domain box.
 
 The two TIME depths are decoupled (time-nesting, DESIGN.md §4): the inner
 `TBPlan.T` may be any depth up to the outer exchange depth `T`, in which
@@ -44,7 +41,7 @@ the first in-tile step splits into an interior update of the un-exchanged
 local block (data-independent of the ppermute, so XLA's latency-hiding
 scheduler can run the exchange underneath it) plus four rim strips of
 width `H + 2*r_step` recomputed once the halo lands; steps 2..T then run
-through the inner executor on the stitched state at depth `H - r_step`.
+through the kernel on the stitched state at depth `H - r_step`.
 The strips are the overlap's price — `plan_hierarchy` decides when paying
 it beats serializing the exchange.
 
@@ -96,6 +93,7 @@ from repro.core import tables as tables_mod
 from repro.core.temporal_blocking import (HierPlan, TBPassGeom, TBPlan,
                                           nested_pass_geometry)
 from repro.kernels import ops as ops_mod
+from repro.kernels import stencil_tb as ker
 from repro.kernels import tb_physics as phys
 from repro.telemetry import spans as _spans
 
@@ -177,9 +175,9 @@ class DistTBPlan(NamedTuple):
     """Static setup for the sharded temporally-blocked propagator.
 
     `inner_plan` is the inner level of the two-level hierarchy: its tile
-    spatially tiles the shard block inside the per-shard schedule (both
-    executors), and its T is the INNER time depth — any depth up to the
-    outer exchange depth `T`.  When `inner_plan.T < T`, the executor runs
+    spatially tiles the shard block inside the per-shard schedule, and
+    its T is the INNER time depth — any depth up to the outer exchange
+    depth `T`.  When `inner_plan.T < T`, the kernel runs
     `ceil(T / inner_plan.T)` inner passes per deep exchange, each
     consuming `inner_plan.T * r_step` of the remaining halo so the
     advanced window shrinks pass by pass (time-nesting); the VMEM window
@@ -197,10 +195,8 @@ class DistTBPlan(NamedTuple):
     spacing: Tuple[float, float, float] = (10.0, 10.0, 10.0)
     ax_x: str = "data"
     ax_y: str = "model"
-    inner: str = "jnp"          # per-shard executor: "jnp" | "pallas"
     inner_plan: Optional[TBPlan] = None
     overlap: bool = False       # overlapped (split-first-step) exchange
-    per_field_halo: bool = True  # per-field exchange depths (halo_lags)
 
     @property
     def r_step(self) -> int:
@@ -236,9 +232,6 @@ class DistTBPlan(NamedTuple):
 
     def field_depths(self, T_depth: int) -> Tuple[int, ...]:
         """Per-state-field exchange depth for a depth-`T_depth` tile."""
-        if not self.per_field_halo:
-            h = T_depth * self.r_step
-            return (h,) * len(self.physics.state_fields)
         return self.physics.field_halo_depths(T_depth, self.order)
 
     def validate(self):
@@ -253,8 +246,6 @@ class DistTBPlan(NamedTuple):
                 f"halo depth T*r_step={self.halo} exceeds local block "
                 f"({bx}, {by}); single-hop neighbor exchange requires "
                 f"T*r_step <= block — lower T or use a coarser decomposition")
-        if self.inner not in ("jnp", "pallas"):
-            raise ValueError(f"unknown inner schedule {self.inner!r}")
         if self.inner_plan is not None:
             itx, ity = self.inner_plan.tile
             if bx % itx or by % ity:
@@ -273,19 +264,19 @@ def dist_plan_from_hier(mesh: Mesh, grid_shape: Tuple[int, int, int],
                         physics: phys.TBPhysics, order: int,
                         hier: HierPlan, dt: float,
                         spacing: Tuple[float, float, float],
-                        inner: str = "pallas", **kwargs) -> DistTBPlan:
+                        **kwargs) -> DistTBPlan:
     """Turn a jointly-autotuned `core.temporal_blocking.HierPlan` into the
     executable `DistTBPlan` (outer T and exchange overlap from the outer
     level, spatial tile from the inner level)."""
     return DistTBPlan(mesh=mesh, grid_shape=grid_shape, physics=physics,
                       order=order, T=hier.T, dt=dt, spacing=spacing,
-                      inner=inner, inner_plan=hier.inner,
-                      overlap=hier.overlap, **kwargs)
+                      inner_plan=hier.inner, overlap=hier.overlap,
+                      **kwargs)
 
 
 def _geoms(plan: DistTBPlan, T_depth: int):
-    """The inner passes of one depth-`T_depth` tile: the steps the inner
-    executor runs (all but the split first step with overlap) in chunks of
+    """The inner passes of one depth-`T_depth` tile: the steps the kernel
+    runs (all but the split first step with overlap) in chunks of
     the inner depth, clamped to the tile (the `nt % T` remainder)."""
     T_rest = T_depth - 1 if plan.overlap else T_depth
     inner_T = min(plan.inner_T, T_depth, max(T_rest, 1))
@@ -385,12 +376,6 @@ def _dom_mask(bounds, shape, start, dtype):
 # Per-shard inner trapezoids
 # ---------------------------------------------------------------------------
 
-# The jnp oracle of one halo-padded window (shared with the single-device
-# driver — it moved to `kernels/ops` so the survey engine's jnp executor
-# and this sharded layer run literally the same function).
-_jnp_window_tile = ops_mod._jnp_window_tile
-
-
 def _reach(a, origin: Tuple[int, int], need: Tuple[int, int], fill: float):
     """`a` padded on the high side (with `fill`) until [origin, origin +
     need) fits in x and y; frames already hold every window, so this only
@@ -415,9 +400,9 @@ def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state,
     (`block + 2*geom.d_out`, rounded up to the inner tile: the round-up
     band the crop discards reads cells no kept value depends on) and
     returns it at depth `geom.d_out` — the next pass's input, landing
-    exactly on the block at the last pass.  Every operand is read in place, at origin
-    `depth - geom.d_in`; the domain mask is the shard's box in pass-grid
-    coordinates.
+    exactly on the block at the last pass.  Every operand is read in
+    place, at origin `depth - geom.d_in`; the domain mask is the shard's
+    box in pass-grid coordinates.
 
     Tables are per pass-local tile: s_coords (ntiles, cap, 3) window-local,
     s_vals (ntiles, geom.T, cap), r_coords/r_w likewise.  Returns
@@ -428,65 +413,27 @@ def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state,
     nz = state[0].shape[2]
     tx, ty = geom.tile
     cx, cy = geom.grid
-    hp = geom.halo
     keep = (bx + 2 * geom.d_out, by + 2 * geom.d_out)
     fills = dict(physics.param_fills)
     so = (s_depth[0] - geom.d_in, s_depth[1] - geom.d_in)
     po = (p_depth[0] - geom.d_in, p_depth[1] - geom.d_in)
     bounds = _dom_bounds(plan, (geom.d_out, geom.d_out))
     ntx, nty = geom.ntiles
-    if plan.inner == "pallas":
-        # One pallas_call whose grid tiles the pass window, its windows
-        # DMA'd straight out of the frames
-        from repro.kernels import stencil_tb as ker
-        spec = _pass_spec(plan, geom, nz, s_coords.shape[1],
-                          r_coords.shape[1], state[0].dtype)
-        need = (cx - tx + spec.window[0], cy - ty + spec.window[1])
-        spads = tuple(_reach(a, so, need, 0.0) for a in state)
-        # an unaligned param read DMAs from the row below, 8 rows more
-        pneed = (need[0], need[1] + (8 if po[1] % 8 else 0))
-        pfrom = (po[0], po[1] - po[1] % 8)
-        ppads = tuple(_reach(a, pfrom, pneed, fills.get(f, 0.0))
-                      for f, a in zip(physics.param_fields, param_frames))
-        new, rec = ker.tb_time_tile(
-            spec, physics, spads, ppads, s_coords, s_vals, r_coords, r_w,
-            origins=(so,) * len(spads) + (po,) * len(ppads),
-            dom_box=jnp.stack(bounds).astype(jnp.int32),
-            interpret=interpret)
-    else:
-        # jnp oracle: the SAME per-window schedule as the kernel grid,
-        # looped in pure jnp (ntx*nty windows, each with its own halo)
-        sspec = _StepSpec(float(plan.dt),
-                          tuple(float(s) for s in plan.spacing), plan.order)
-        wx, wy = tx + 2 * hp, ty + 2 * hp
-        need = (cx + 2 * hp, cy + 2 * hp)
-        spads = tuple(_reach(a, so, need, 0.0) for a in state)
-        ppads = tuple(_reach(a, po, need, fills.get(f, 0.0))
-                      for f, a in zip(physics.param_fields, param_frames))
-        outs = [jnp.zeros((cx, cy, nz), p.dtype) for p in spads]
-        rec_rows = []
-        for ti in range(ntx):
-            row = []
-            for tj in range(nty):
-                k = ti * nty + tj
-
-                def win(p, o):
-                    return p[o[0] + ti * tx:o[0] + ti * tx + wx,
-                             o[1] + tj * ty:o[1] + tj * ty + wy]
-
-                dom = _dom_mask(bounds, (wx, wy, nz),
-                                (ti * tx - hp, tj * ty - hp), spads[0].dtype)
-                out_w, rec = _jnp_window_tile(
-                    physics, sspec, geom.T, hp,
-                    tuple(win(p, so) for p in spads),
-                    tuple(win(p, po) for p in ppads), dom,
-                    s_coords[k], s_vals[k], r_coords[k], r_w[k])
-                for i, centre in enumerate(out_w):
-                    outs[i] = outs[i].at[ti * tx:(ti + 1) * tx,
-                                         tj * ty:(tj + 1) * ty, :].set(centre)
-                row.append(rec)
-            rec_rows.append(jnp.stack(row, axis=0))
-        new, rec = tuple(outs), jnp.stack(rec_rows, axis=0)
+    # One pallas_call whose grid tiles the pass window, its windows DMA'd
+    # straight out of the frames
+    spec = _pass_spec(plan, geom, nz, s_coords.shape[1], r_coords.shape[1],
+                      state[0].dtype)
+    need = (cx - tx + spec.window[0], cy - ty + spec.window[1])
+    spads = tuple(_reach(a, so, need, 0.0) for a in state)
+    # an unaligned param read DMAs from the row below, 8 rows more
+    pneed = (need[0], need[1] + (8 if po[1] % 8 else 0))
+    pfrom = (po[0], po[1] - po[1] % 8)
+    ppads = tuple(_reach(a, pfrom, pneed, fills.get(f, 0.0))
+                  for f, a in zip(physics.param_fields, param_frames))
+    new, rec = ker.tb_time_tile(
+        spec, physics, spads, ppads, s_coords, s_vals, r_coords, r_w,
+        origins=(so,) * len(spads) + (po,) * len(ppads),
+        dom_box=jnp.stack(bounds).astype(jnp.int32), interpret=interpret)
     new = tuple(a[:keep[0], :keep[1]] for a in new)
     rec = rec.reshape(ntx * nty, geom.T, rec.shape[-2], rec.shape[-1])
     return new, rec
@@ -515,8 +462,8 @@ def _split_first_step(plan: DistTBPlan, sspec: _StepSpec, h: int,
     its rim, and a field the update carries unchanged (a previous
     time level) keeps its source's exchanged frame.  The result carries
     the standard trapezoid contract (garbage only within r_step of the
-    window edge).  Injection and receiver partials then run exactly as in
-    `_jnp_window_tile`'s k = 0, on SHARD-level tables (window-local).
+    window edge).  Injection and receiver partials then run as in the
+    kernel's first in-window step, on SHARD-level tables (window-local).
 
     Returns (new frames tuple, rec partials (1, capr, chan)).
     """
@@ -574,8 +521,8 @@ def _split_first_step(plan: DistTBPlan, sspec: _StepSpec, h: int,
               oy + y0 + (keep[1].start or 0), 0)
         for f in evolved:
             v = new[f][keep]
-            # post-step mask of _jnp_window_tile (the interior is
-            # in-domain: its mask is 1)
+            # the kernel's post-step mask (the interior is in-domain: its
+            # mask is 1)
             if f in physics.evolved_fields and \
                     f not in physics.premasked_fields:
                 v = v * dm[keep]
@@ -738,21 +685,6 @@ def _pass_receiver_tables(plan: DistTBPlan, receivers, geom: TBPassGeom,
     return jnp.asarray(coords), jnp.asarray(weight), rid
 
 
-class _RidTab(NamedTuple):
-    """The slice of a receiver table `ops.combine_rec_partials` reads."""
-
-    rid: jnp.ndarray
-
-
-def _combine_pass(parts, rid, nrec: int):
-    """(px, py, ntl, T, capr, chan) shard partials + host rid table ->
-    (T, nrec, chan) per-step samples (segment sum over receiver ids)."""
-    px, py, ntl, T, capr, chan = parts.shape
-    flat = parts.reshape(px * py * ntl, 1, T, capr, chan)
-    tab = _RidTab(rid=jnp.asarray(rid.reshape(px * py * ntl, capr)))
-    return ops_mod.combine_rec_partials(flat, tab, nrec)
-
-
 # ---------------------------------------------------------------------------
 # Sharded driver
 # ---------------------------------------------------------------------------
@@ -835,8 +767,8 @@ def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
         return jnp.transpose(sv, tuple(range(1, ndim - 1)) + (0, ndim - 1)
                              ).astype(dtype)
 
-    # check_vma=False: the varying-axes checker has no rule for pallas_call
-    # (the inner="pallas" path); every output is explicitly sharded anyway.
+    # check_vma=False: the varying-axes checker has no rule for pallas_call;
+    # every output is explicitly sharded anyway.
     @functools.partial(jax.shard_map, mesh=plan.mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     def tile(*args):
@@ -1015,8 +947,8 @@ def sharded_tb_propagate(plan: DistTBPlan, nt: int,
     `plan.T`; the remainder runs as a shallower tile with its own
     (smaller) exchange depth, mirroring `kernels/ops._tb_propagate`.
     The schedule — inner spatial tiling, inner time depth (time-nested
-    passes when `inner_plan.T < T`), per-field exchange depths,
-    overlapped exchange — comes from the plan and never changes results,
+    passes when `inner_plan.T < T`), overlapped exchange — comes from
+    the plan and never changes results,
     only data movement (tested across all combinations).
 
     Returns (final state tuple, rec (nt, nrec, rec_channels) | None) with
@@ -1098,7 +1030,7 @@ def sharded_propagate(plan: DistTBPlan, nt: int,
 
 def sharded_plan(mesh: Mesh, physics: phys.TBPhysics,
                  grid_shape: Tuple[int, int, int], order: int, dt: float,
-                 spacing: Tuple[float, float, float], inner: str = "pallas",
+                 spacing: Tuple[float, float, float],
                  ax_x: str = "data", ax_y: str = "model",
                  **planner) -> DistTBPlan:
     """The plan `sharded_propagate` runs on `mesh`: the joint autotuner's
@@ -1111,7 +1043,7 @@ def sharded_plan(mesh: Mesh, physics: phys.TBPhysics,
     hier, _ = plan_hierarchy(physics.name, grid_shape[2], order, block,
                              **planner)
     return dist_plan_from_hier(mesh, grid_shape, physics, order, hier, dt,
-                               spacing, inner=inner, ax_x=ax_x, ax_y=ax_y)
+                               spacing, ax_x=ax_x, ax_y=ax_y)
 
 
 def sharded_counts(plan: DistTBPlan, nt: int, itemsize: int = 4
@@ -1122,13 +1054,11 @@ def sharded_counts(plan: DistTBPlan, nt: int, itemsize: int = 4
     `update_points`, the points every shard's split first step (interior
     block and rim strips) and inner passes compute over all tiles and
     steps — the deep-halo rims plus the kernel's trapezoid
-    (`stencil_tb.update_points`), or the jnp executor's whole windows —
-    and `useful_points`, the global grid's points times the steps served.
+    (`stencil_tb.update_points`) — and `useful_points`, the global grid's
+    points times the steps served.
     Per propagate: `exchange_bytes`, the bytes the shards send one another
     (state strips every tile, params once), and `exchanges`, the
     ppermute rounds that move them."""
-    from repro.kernels import stencil_tb as ker
-
     physics = plan.physics
     nx, ny, nz = plan.grid_shape
     px, py = plan.pgrid
@@ -1159,13 +1089,8 @@ def sharded_counts(plan: DistTBPlan, nt: int, itemsize: int = 4
             pts += (bx * by + 2 * band * (by + 2 * h)
                     + (2 * bx * band if bx > 2 * r else 0)) * nz
         for geom in _geoms(plan, T_depth):
-            if plan.inner == "pallas":
-                pts += ker.update_points(_pass_spec(plan, geom, nz, 1, 1,
-                                                    jnp.float32))
-            else:
-                tx, ty = geom.tile
-                pts += (geom.ntiles[0] * geom.ntiles[1] * geom.T
-                        * (tx + 2 * geom.halo) * (ty + 2 * geom.halo) * nz)
+            pts += ker.update_points(_pass_spec(plan, geom, nz, 1, 1,
+                                                jnp.float32))
         out["update_points"].append(tiles * px * py * pts)
         out["useful_points"].append(nx * ny * nz * tiles * T_depth)
         for d in plan.field_depths(T_depth):

@@ -16,18 +16,14 @@ it — `tb_propagate_prepared` — is a pure traced function of jnp pytrees
 (state, padded params, `src_dcmp`, the per-tile tables).  That split is
 what makes the survey engine possible: `survey/engine.py` stacks the
 prepared tables of a whole shot bucket and runs `tb_propagate_prepared`
-over the shot axis (a per-shot `jax.lax.map` for the Pallas executor,
-`jax.vmap` for jnp), one jit trace per bucket.  The one-chip entry points
-run it under `_tb_propagate_jit`, once per plan and array shapes.
-Each time tile runs through one of two executors sharing the same window
-schedule: `executor="pallas"` (the `stencil_tb` kernel, interpret mode
-off-TPU) or `executor="jnp"` (`_jnp_time_tile`, the same per-window
-trapezoid in pure jnp — also the oracle the sharded layer reuses).
+over the shot axis (a per-shot `jax.lax.map`), one jit trace per bucket.
+The one-chip entry points run it under `_tb_propagate_jit`, once per plan
+and array shapes.  Every time tile is one call of the `stencil_tb` kernel
+(interpret mode off-TPU); the plain references live in `kernels/ref.py`.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import threading
 from typing import Dict, Optional, Tuple
 
@@ -161,91 +157,20 @@ def combine_rec_partials(rec_part: jnp.ndarray, rec_tab, nrec: int):
     return jnp.transpose(seg[:nrec], (1, 0, 2))            # (T, nrec, nchan)
 
 
-def _jnp_window_tile(physics: phys.TBPhysics, sspec, T: int, h: int,
-                     state_pads, param_pads, dom, s_coords, s_vals,
-                     r_coords, r_w):
-    """T in-window timesteps on one halo-padded window — the jnp oracle of
-    the Pallas kernel's unrolled loop (`stencil_tb._tb_kernel`), sharing the
-    same `physics.update` / mask / inject / record sequence.  `sspec` is
-    anything exposing `dt`/`spacing`/`order` (a `TBKernelSpec` here, the
-    sharded layer's `_StepSpec` in `distributed/halo.py`).
-
-    Returns (cropped centre tuple, rec partials (T, capr, rec_channels)).
-    """
-    state = dict(zip(physics.state_fields, state_pads))
-    params = dict(zip(physics.param_fields, param_pads))
-    mask_fn = lambda a: a * dom  # noqa: E731
-    sx, sy, sz = s_coords[:, 0], s_coords[:, 1], s_coords[:, 2]
-    rx, ry, rz = r_coords[:, 0], r_coords[:, 1], r_coords[:, 2]
-    recs = []
-    for k in range(T):
-        new = physics.update(state, params, sspec, mask_fn)
-        for f in physics.evolved_fields:
-            if f not in physics.premasked_fields:
-                new[f] = new[f] * dom
-        # fused grid-aligned injection (paper Listing 4); padding slots
-        # carry val = 0 and scatter harmlessly onto window point (0, 0, 0)
-        for f in physics.inject_fields:
-            new[f] = new[f].at[sx, sy, sz].add(s_vals[k].astype(new[f].dtype))
-        # per-step receiver partials (paper Fig. 3b gather, local entries)
-        recs.append(jnp.stack(
-            [(arr[rx, ry, rz] * r_w).astype(arr.dtype)
-             for arr in physics.record(new)], axis=-1))
-        state = new
-    wx, wy = state_pads[0].shape[0], state_pads[0].shape[1]
-    crop = (slice(h, wx - h), slice(h, wy - h), slice(None))
-    return (tuple(state[f][crop] for f in physics.state_fields),
-            jnp.stack(recs, axis=0))
-
-
-def _jnp_time_tile(spec: ker.TBKernelSpec, physics: phys.TBPhysics,
-                   state_pads, param_pads, s_coords, s_vals, r_coords, r_w):
-    """jnp oracle of `stencil_tb.tb_time_tile`: the identical per-window
-    trapezoid (window DMA -> T masked steps -> centre crop) looped in pure
-    jnp, one window per (ti, tj) tile.  Same signature contract; returns
-    (state tuple (nx, ny, nz), rec partials (ntx, nty, T, capr, chan))."""
-    h = spec.halo
-    tx, ty = spec.tile
-    ntx, nty = spec.ntiles
-    dom_pad = jnp.pad(jnp.ones((spec.nx, spec.ny, spec.nz), spec.dtype),
-                      ((h, h), (h, h), (0, 0)))
-    outs = [jnp.zeros((spec.nx, spec.ny, spec.nz), p.dtype)
-            for p in state_pads]
-    rec_rows = []
-    for ti in range(ntx):
-        row = []
-        for tj in range(nty):
-            k = ti * nty + tj
-            slx = slice(ti * tx, ti * tx + tx + 2 * h)
-            sly = slice(tj * ty, tj * ty + ty + 2 * h)
-            wpads = tuple(p[slx, sly] for p in state_pads)
-            wpar = tuple(p[slx, sly] for p in param_pads)
-            out_w, rec = _jnp_window_tile(
-                physics, spec, spec.T, h, wpads, wpar, dom_pad[slx, sly],
-                s_coords[k], s_vals[k], r_coords[k], r_w[k])
-            for i, centre in enumerate(out_w):
-                outs[i] = outs[i].at[ti * tx:(ti + 1) * tx,
-                                     tj * ty:(tj + 1) * ty, :].set(centre)
-            row.append(rec)
-        rec_rows.append(jnp.stack(row, axis=0))
-    return tuple(outs), jnp.stack(rec_rows, axis=0)
-
-
 def _run_time_tile(spec: ker.TBKernelSpec, physics: phys.TBPhysics,
                    state, param_pads, src_dcmp, src_tab, rec_tab, t0,
-                   nrec: int, interpret: bool, executor: str = "pallas"):
+                   nrec: int, interpret: bool):
     # `annotate` is a no-op unless --telemetry enabled a collector; under
     # tracing it names this region in the jaxpr/HLO and the recorded span
     # measures TRACE time (the compile-vs-execute split of DESIGN.md §7)
-    with _spans.annotate("ops.tile_pass", T=spec.T, tile=spec.tile,
-                         executor=executor):
+    with _spans.annotate("ops.tile_pass", T=spec.T, tile=spec.tile):
         return _run_time_tile_impl(spec, physics, state, param_pads,
                                    src_dcmp, src_tab, rec_tab, t0, nrec,
-                                   interpret, executor)
+                                   interpret)
 
 
 def _run_time_tile_impl(spec, physics, state, param_pads, src_dcmp,
-                        src_tab, rec_tab, t0, nrec, interpret, executor):
+                        src_tab, rec_tab, t0, nrec, interpret):
     h = spec.halo
     ntx, nty = spec.ntiles
     ntiles = ntx * nty
@@ -267,16 +192,9 @@ def _run_time_tile_impl(spec, physics, state, param_pads, src_dcmp,
 
     with jax.named_scope("ops.state_pad"):
         state_pads = tuple(_pad_xy(f, h, "constant") for f in state)
-    if executor == "pallas":
-        new_state, rec_part = ker.tb_time_tile(
-            spec, physics, state_pads, param_pads, s_coords, s_vals,
-            r_coords, r_w, interpret=interpret)
-    elif executor == "jnp":
-        new_state, rec_part = _jnp_time_tile(
-            spec, physics, state_pads, param_pads, s_coords, s_vals,
-            r_coords, r_w)
-    else:
-        raise ValueError(f"unknown executor {executor!r}")
+    new_state, rec_part = ker.tb_time_tile(
+        spec, physics, state_pads, param_pads, s_coords, s_vals,
+        r_coords, r_w, interpret=interpret)
     if rec_tab is not None:
         with jax.named_scope("ops.rec_combine"):
             rec = combine_rec_partials(rec_part, rec_tab, nrec)
@@ -345,8 +263,7 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
                           param_pads, rparam_pads,
                           src_dcmp: jnp.ndarray, src_tab, rec_tab,
                           rsrc_tab, rrec_tab, nrec: int,
-                          interpret: Optional[bool] = None,
-                          executor: str = "pallas"):
+                          interpret: Optional[bool] = None):
     """The traced core of `_tb_propagate`: scan over depth-T time tiles
     plus the shallower `nt % T` remainder tile, AFTER all host-side table
     binning.
@@ -356,11 +273,9 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
     `TileSourceTable`/`TileReceiverTable` NamedTuples — so this function
     jits cleanly and, crucially, maps over a stacked shot axis: the
     survey engine (`survey/engine.py`) batches whole shot buckets
-    through one trace of this function (`jax.vmap` with the jnp
-    executor; the Pallas kernel takes no batch axis, so with it each
-    shot runs in turn under `jax.lax.map`).  `spec`/`rspec` (None when
-    `nt % spec.T == 0`), `nrec`, `interpret` and `executor`
-    ("pallas" | "jnp") are static.
+    through one trace of this function (the kernel takes no batch axis,
+    so each shot runs in turn under `jax.lax.map`).  `spec`/`rspec`
+    (None when `nt % spec.T == 0`), `nrec` and `interpret` are static.
 
     Returns (final state tuple, recs (nt, nrec, rec_channels)); recs are
     all-zero shaped (nt, 0, chan) when no receiver tables were bound.
@@ -375,7 +290,7 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
         t0 = tile_idx * spec.T
         new, rec = _run_time_tile(spec, physics, carry, param_pads,
                                   src_dcmp, src_tab, rec_tab, t0, nrec,
-                                  interpret, executor)
+                                  interpret)
         return new, rec
 
     carry = tuple(state)
@@ -389,8 +304,7 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
     if rem > 0:
         carry, rec_rem = _run_time_tile(
             rspec, physics, carry, rparam_pads, src_dcmp, rsrc_tab,
-            rrec_tab, jnp.asarray(n_main * spec.T), nrec, interpret,
-            executor)
+            rrec_tab, jnp.asarray(n_main * spec.T), nrec, interpret)
         recs = (jnp.concatenate([recs_main, rec_rem], axis=0)
                 if recs_main is not None else rec_rem)
     else:
@@ -471,21 +385,21 @@ _tracing = threading.local()
 
 
 def _tb_propagate_traced(physics, nt, spec, rspec, nrec, interpret,
-                         executor, state, params, src_dcmp, tables):
+                         state, params, src_dcmp, tables):
     _tracing.traced = True
     param_pads = tuple(_pad_xy(p, spec.halo, "edge") for p in params)
     rparam_pads = (tuple(_pad_xy(p, rspec.halo, "edge") for p in params)
                    if rspec is not None else None)
     return tb_propagate_prepared(physics, nt, spec, rspec, state, param_pads,
                                  rparam_pads, src_dcmp, *tables, nrec,
-                                 interpret=interpret, executor=executor)
+                                 interpret=interpret)
 
 
-# the one-chip propagate, compiled once per plan (the specs), nt, nrec,
-# executor and the shapes and dtypes of its arrays; no argument is donated
-# (callers reuse their state arrays)
+# the one-chip propagate, compiled once per plan (the specs), nt, nrec and
+# the shapes and dtypes of its arrays; no argument is donated (callers
+# reuse their state arrays)
 _tb_propagate_jit = jax.jit(_tb_propagate_traced,
-                            static_argnums=(0, 1, 2, 3, 4, 5, 6))
+                            static_argnums=(0, 1, 2, 3, 4, 5))
 
 
 def _tb_propagate(physics: phys.TBPhysics, nt: int,
@@ -495,7 +409,7 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
                   receivers: Optional[src_mod.GriddedReceivers],
                   plan: TBPlan, order: int, dt,
                   spacing: Tuple[float, float, float],
-                  interpret: Optional[bool] = None, executor: str = "pallas"):
+                  interpret: Optional[bool] = None):
     """Propagate nt timesteps of `physics` with the temporally-blocked kernel.
 
     Semantics identical to the reference propagator in `core/propagators/`
@@ -505,11 +419,9 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
 
     The host-side table binning (`_prepare`) runs eagerly on every call;
     the traced half — the param pads and `tb_propagate_prepared` — is one
-    jitted program, traced, lowered and compiled once per plan, nt,
-    executor and array shapes and dtypes, so a warm call goes straight to
-    the compiled program.  With the default `executor="pallas"` each time
-    tile is one `pallas_call`; `executor="jnp"` runs the identical window
-    schedule in pure jnp.
+    jitted program, traced, lowered and compiled once per plan, nt and
+    array shapes and dtypes, so a warm call goes straight to the compiled
+    program.  Each time tile is one `pallas_call`.
 
     Returns (final state tuple, rec (nt, nrec, rec_channels) | None).
     """
@@ -517,13 +429,12 @@ def _tb_propagate(physics: phys.TBPhysics, nt: int,
                             order, dt, spacing)
     spec = static[2]
     with _spans.span("ops.propagate", physics=physics.name, nt=nt,
-                     T=spec.T, executor=executor) as sp:
+                     T=spec.T) as sp:
         # `traced`: this call traced (and lowered, compiled or loaded) the
         # jitted program; a call its cache serves only enqueues it
         with _spans.span("ops.dispatch", count_compiles=True) as dsp:
             _tracing.traced = False
-            carry, recs = _tb_propagate_jit(*static, interpret, executor,
-                                            *args)
+            carry, recs = _tb_propagate_jit(*static, interpret, *args)
             dsp.set(traced=_tracing.traced)
         sp.sync((carry, recs))
     if receivers is None:
@@ -540,14 +451,13 @@ def acoustic_tb_propagate(nt: int, u0, u1, m, damp,
                           receivers: Optional[src_mod.GriddedReceivers],
                           plan: TBPlan, order: int, dt,
                           spacing: Tuple[float, float, float],
-                          interpret: Optional[bool] = None,
-                          executor: str = "pallas"):
+                          interpret: Optional[bool] = None):
     """Acoustic TB propagation.  Returns ((u_prev, u), rec (nt, nrec) | None).
 
     Semantics identical to `kernels.ref.acoustic_reference` (tested)."""
     (u0n, u1n), recs = _tb_propagate(
         phys.ACOUSTIC, nt, (u0, u1), {"m": m, "damp": damp}, g, receivers,
-        plan, order, dt, spacing, interpret=interpret, executor=executor)
+        plan, order, dt, spacing, interpret=interpret)
     if recs is not None:
         recs = recs[..., 0]
     return (u0n, u1n), recs
@@ -556,8 +466,7 @@ def acoustic_tb_propagate(nt: int, u0, u1, m, damp,
 def tti_tb_propagate(nt: int, state, params, g, receivers,
                      plan: TBPlan, order: int, dt,
                      spacing: Tuple[float, float, float],
-                     interpret: Optional[bool] = None,
-                     executor: str = "pallas"):
+                     interpret: Optional[bool] = None):
     """TTI TB propagation.
 
     `state` is a `propagators.tti.TTIState`; `params` a `TTIParams`.
@@ -567,8 +476,7 @@ def tti_tb_propagate(nt: int, state, params, g, receivers,
     st_tuple = tuple(getattr(state, f) for f in phys.TTI.state_fields)
     pdict = {f: getattr(params, f) for f in phys.TTI.param_fields}
     final, recs = _tb_propagate(phys.TTI, nt, st_tuple, pdict, g, receivers,
-                                plan, order, dt, spacing, interpret=interpret,
-                                executor=executor)
+                                plan, order, dt, spacing, interpret=interpret)
     if recs is not None:
         recs = recs[..., 0]
     return tt.TTIState(**dict(zip(phys.TTI.state_fields, final))), recs
@@ -577,8 +485,7 @@ def tti_tb_propagate(nt: int, state, params, g, receivers,
 def elastic_tb_propagate(nt: int, state, params, g, receivers,
                          plan: TBPlan, order: int, dt,
                          spacing: Tuple[float, float, float],
-                         interpret: Optional[bool] = None,
-                         executor: str = "pallas"):
+                         interpret: Optional[bool] = None):
     """Elastic TB propagation.
 
     `state` is a `propagators.elastic.ElasticState`; `params` an
@@ -590,7 +497,7 @@ def elastic_tb_propagate(nt: int, state, params, g, receivers,
     pdict = {f: getattr(params, f) for f in phys.ELASTIC.param_fields}
     final, recs = _tb_propagate(phys.ELASTIC, nt, st_tuple, pdict, g,
                                 receivers, plan, order, dt, spacing,
-                                interpret=interpret, executor=executor)
+                                interpret=interpret)
     return el.ElasticState(**dict(zip(phys.ELASTIC.state_fields, final))), \
         recs
 

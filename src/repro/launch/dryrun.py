@@ -80,8 +80,7 @@ def stencil_plan_report(physics: str, nz: int, order: int,
     executor will do plus the per-field exchange-byte saving against the
     uniform-depth baseline and the VMEM saving of the time-nested
     schedule against the flat plan at the same exchange depth.  Consumed
-    by `launch/stencil_dist.py --dryrun` and
-    `benchmarks/fig12_scaling.py --dryrun`.
+    by `launch/stencil_dist.py --dryrun`.
 
     `interp` (a `core.interp.InterpSpec`; default multilinear) annotates
     the report: the ``interp`` field records the kernel, radius, and
@@ -91,7 +90,6 @@ def stencil_plan_report(physics: str, nz: int, order: int,
     from repro.core import interp as interp_mod
     from repro.core.temporal_blocking import PHYSICS_COSTS, TBPlan
     from repro.survey.plan_cache import cached_plan_hierarchy
-    from repro.telemetry import drift as drift_mod
 
     ispec = interp_mod.LINEAR if interp is None else interp
     hier, entry, info = cached_plan_hierarchy(physics, nz, order, block,
@@ -121,10 +119,6 @@ def stencil_plan_report(physics: str, nz: int, order: int,
                   if k in entry},
         "cache": {"key": info.key, "hit": info.hit},
         "interp": {**ispec.to_dict(), "footprint": ispec.footprint(3)},
-        # measured-vs-model calibration state: the last saved drift report
-        # (from `stencil_dist --telemetry` / the telemetry benchmarks),
-        # geomean measured/predicted per term — None until one exists
-        "last_drift": drift_mod.last_drift(),
     }
 
 

@@ -1,7 +1,8 @@
 """Distributed multi-physics stencil launcher + self-check.
 
-Runs the sharded temporally-blocked execution layer (DESIGN.md §4) for any
-registered physics over whatever devices exist (real TPUs or forced host
+Runs the sharded temporally-blocked execution layer (DESIGN.md §4), the
+Pallas TB kernel per shard (interpret mode off-TPU), for any registered
+physics over whatever devices exist (real TPUs or forced host
 devices) and optionally checks agreement — wavefields AND per-step receiver
 traces — with the single-device Listing-1 reference.
 
@@ -18,20 +19,16 @@ traces — with the single-device Listing-1 reference.
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python -m repro.launch.stencil_dist --sweep-T 1,2,4 --n 32 --nt 8
 
-  # run the actual Pallas kernel per shard (inner trapezoid):
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      python -m repro.launch.stencil_dist --check --inner pallas --n 32
-
   # two-level plan: inner tile strictly smaller than the shard block,
   # overlapped (split-first-step) deep exchange:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      python -m repro.launch.stencil_dist --check --inner pallas \
+      python -m repro.launch.stencil_dist --check \
       --inner-tile 4,8 --overlap --n 32
 
   # time-nested: a depth-4 exchange consumed by depth-2 inner passes
   # (--T is the INNER depth once --outer-T decouples the levels):
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      python -m repro.launch.stencil_dist --check --inner pallas \
+      python -m repro.launch.stencil_dist --check \
       --inner-tile 4,8 --T 2 --outer-T 4 --n 32
 
   # let the joint autotuner pick (T, inner tile, overlap) for the block:
@@ -43,7 +40,6 @@ traces — with the single-device Listing-1 reference.
   python -m repro.launch.stencil_dist --dryrun --multipod
 """
 import argparse
-import functools
 import json
 import os
 import sys
@@ -53,8 +49,8 @@ def _build_case(physics_name, shape, order, dt, grid, rng):
     """(physics, state tuple, params dict, ref_fn) for one physics.
 
     The model itself comes from the ONE shared builder
-    (`launch.stencil_survey.build_model` — also the survey CLI's,
-    fig13's and test_survey's model); this adds the random initial state
+    (`launch.stencil_survey.build_model` — also the survey CLI's and
+    test_survey's model); this adds the random initial state
     and the single-device reference closure.
 
     ref_fn(nt, g, gr) -> (state tuple in state_fields order,
@@ -109,9 +105,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--physics", default="acoustic",
                     choices=("acoustic", "tti", "elastic"))
-    ap.add_argument("--inner", default="jnp", choices=("jnp", "pallas"),
-                    help="per-shard executor: jnp oracle or the Pallas TB "
-                         "kernel (interpret mode off-TPU)")
     ap.add_argument("--inner-tile", default=None,
                     help="tx,ty spatial tile of the inner trapezoid "
                          "(must divide the shard block); default: one tile "
@@ -125,9 +118,6 @@ def main():
     ap.add_argument("--overlap", action="store_true",
                     help="overlapped deep exchange: split first step into "
                          "interior (runs under the ppermute) + rim strips")
-    ap.add_argument("--uniform-halo", action="store_true",
-                    help="disable per-field exchange depths (ship every "
-                         "state field at the full T*r_step)")
     ap.add_argument("--auto-plan", action="store_true",
                     help="joint two-level autotune: pick T, inner tile and "
                          "overlap for this block via plan_hierarchy "
@@ -153,10 +143,9 @@ def main():
     ap.add_argument("--order", type=int, default=4)
     ap.add_argument("--telemetry", nargs="?", const="", default=None,
                     metavar="PATH",
-                    help="enable telemetry: span collection (Chrome-trace "
-                         "export to PATH, default results/telemetry_dist"
-                         ".json) plus a predicted-vs-measured cost-model "
-                         "drift report (results/telemetry_drift.json)")
+                    help="enable telemetry: span collection, exported as "
+                         "a Chrome trace to PATH (default "
+                         "results/telemetry_dist.json)")
     args = ap.parse_args()
     if args.auto_plan and (args.inner_tile or args.overlap or args.sweep_T
                            or args.outer_T):
@@ -204,8 +193,6 @@ def main():
         autotuner with --auto-plan)."""
         px, py = mesh.shape["data"], mesh.shape["model"]
         block = (shape[0] // px, shape[1] // py)
-        common = dict(inner=args.inner,
-                      per_field_halo=not args.uniform_halo)
         if args.auto_plan:
             # through the survey plan cache: when --dryrun already swept
             # this configuration for its report (same candidate space),
@@ -220,7 +207,7 @@ def main():
                   f"overlap={hier.overlap} "
                   f"field depths={hier.field_depths}")
             return dist_plan_from_hier(mesh, shape, physics, order, hier,
-                                       dt, grid.spacing, **common)
+                                       dt, grid.spacing)
         # --outer-T decouples the levels: --T is then the inner depth
         T_outer = args.outer_T or T
         inner_plan = None
@@ -232,8 +219,7 @@ def main():
             inner_plan = TBPlan(tile, T, physics.step_radius(order))
         return DistTBPlan(mesh=mesh, grid_shape=shape, physics=physics,
                           order=order, T=T_outer, dt=dt, spacing=grid.spacing,
-                          inner_plan=inner_plan, overlap=args.overlap,
-                          **common)
+                          inner_plan=inner_plan, overlap=args.overlap)
 
     if args.dryrun:
         mesh = mesh_lib.make_production_mesh(multi_pod=args.multipod)
@@ -250,19 +236,6 @@ def main():
             interp=interp_mod.spec_for(args.interp, args.interp_order),
             tiles=AUTO_TILES, depths=AUTO_DEPTHS)
         print("autotuner recommendation:", json.dumps(report))
-        ld = report.get("last_drift")
-        if ld:
-            ratios = {t: s["geomean_ratio"]
-                      for t, s in ld["summary"].items()
-                      if s.get("geomean_ratio") is not None}
-            print("last-run drift (measured/predicted, "
-                  f"{ld['n_records']} rec @ {ld['path']}):",
-                  " ".join(f"{t}={v:.3g}x"
-                           for t, v in sorted(ratios.items()))
-                  or "no finite ratios")
-        else:
-            print("last-run drift: none recorded — run --telemetry on a "
-                  "measured launch to populate it")
         plan = build_plan(mesh, shape, grid, phys.PHYSICS[args.physics],
                           args.order, 1e-3, args.T)
         print(f"compiled plan: outer_T={plan.T} inner_T={plan.inner_T} "
@@ -311,99 +284,21 @@ def main():
 
     def run(T):
         plan = build_plan(mesh, shape, grid, physics, order, dt, T)
-
-        def fn(state, params):
-            # the entry donates its state: every call gets a copy
-            return sharded_propagate(plan, nt, [jnp.copy(f) for f in state],
-                                     params, g, gr)
-
         with mesh:
             with tele.span("dist.propagate", T=T, nt=nt) as sp:
-                out = fn(state, params)
+                # the entry donates its state: every call gets a copy
+                out = sharded_propagate(plan, nt,
+                                        [jnp.copy(f) for f in state],
+                                        params, g, gr)
                 sp.sync(out)
-        return plan, fn, out
-
-    def measure_drift(plan, fn):
-        """Predicted vs measured seconds/point-step for the executed plan
-        (DESIGN.md §7): total from the warm jitted propagate, exchange
-        from an exchange-only microbench of the same per-field deep
-        `ppermute` schedule, kernel phase as their difference.  Hardware
-        counters aren't available, so the kernel phase stands in for BOTH
-        roofline terms and is honestly compared against max(comp, mem)."""
-        import time
-
-        from jax.sharding import PartitionSpec as P
-
-        from repro.distributed.halo import exchange_to_depth
-
-        bx, by = plan.block
-        nz = shape[2]
-        with mesh:
-            times = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(state, params))
-                times.append(time.perf_counter() - t0)
-        total_pps = min(times) / (bx * by * nz * nt)
-
-        depths = plan.field_depths(plan.T)
-        h, ns = plan.halo, len(plan.physics.state_fields)
-        spec3 = P(plan.ax_x, plan.ax_y, None)
-
-        @jax.jit
-        @functools.partial(jax.shard_map, mesh=plan.mesh,
-                           in_specs=(spec3,) * ns, out_specs=(spec3,) * ns)
-        def exchange_only(*fields):
-            pads = tuple(exchange_to_depth(f, d, h, plan.ax_x, plan.ax_y)
-                         for f, d in zip(fields, depths))
-            return tuple(p[h:-h, h:-h] for p in pads)
-
-        with mesh:
-            jax.block_until_ready(exchange_only(*state))  # compile
-            times = []
-            for _ in range(5):
-                t0 = time.perf_counter()
-                with tele.span("dist.exchange_bench") as sp:
-                    sp.sync(exchange_only(*state))
-                times.append(time.perf_counter() - t0)
-        # one deep exchange buys T steps of the whole shard block
-        ex_pps = min(times) / (bx * by * nz * plan.T)
-        kernel_pps = max(total_pps - ex_pps, 0.0)
-
-        inner = TBPlan(plan.inner_tile, plan.inner_T, plan.r_step)
-        predicted = tele.predict_plan_terms(
-            args.physics, nz, order, inner, outer_T=plan.T,
-            block=plan.block, overlap=plan.overlap)
-        measured = {"total_s": total_pps, "exchange_s": ex_pps,
-                    "kernel_s": kernel_pps, "compute_s": kernel_pps,
-                    "memory_s": kernel_pps}
-        ledger = tele.DriftLedger()
-        rec = ledger.record(
-            {"physics": args.physics, "grid": list(shape), "nt": nt,
-             "mesh": dict(mesh.shape), "block": [bx, by],
-             "outer_T": plan.T, "inner_T": plan.inner_T,
-             "inner_tile": list(plan.inner_tile),
-             "overlap": plan.overlap, "inner": args.inner},
-            predicted, measured)
-        path = ledger.save()
-        print("drift predicted s/pt-step:",
-              json.dumps({k: rec["predicted"][k]
-                          for k in ("compute_s", "memory_s", "exchange_s",
-                                    "total_s")}))
-        print("drift measured  s/pt-step:",
-              json.dumps({k: measured[k]
-                          for k in ("compute_s", "memory_s", "exchange_s",
-                                    "total_s")}))
-        print("drift ratio measured/predicted:",
-              json.dumps(rec["ratio"]))
-        print("drift report written to", path)
+        return out
 
     def tol_ok(err, scale):
         return err <= 5e-4 * scale + 1e-6
 
     if args.sweep_T:
         depths = [int(t) for t in args.sweep_T.split(",")]
-        traces = {T: np.asarray(run(T)[2][1]) for T in depths}
+        traces = {T: np.asarray(run(T)[1]) for T in depths}
         base = traces[depths[0]]
         scale = float(np.max(np.abs(base))) + 1e-30
         ok = True
@@ -418,20 +313,15 @@ def main():
         print("SWEEP", "PASS" if ok else "FAIL")
         return 0 if ok else 1
 
-    plan, fn, (dstate, drec) = run(args.T)
+    dstate, drec = run(args.T)
     print(f"sharded {args.physics} propagate done on mesh "
-          f"{dict(mesh.shape)} (inner={args.inner}, "
-          f"inner_tile={args.inner_tile or 'block'}, "
-          f"overlap={args.overlap}, "
-          f"per_field_halo={not args.uniform_halo}, nt={nt}, "
+          f"{dict(mesh.shape)} (inner_tile={args.inner_tile or 'block'}, "
+          f"overlap={args.overlap}, nt={nt}, "
           f"outer_T={args.outer_T or args.T}"
           + (f", inner_T={args.T}" if args.outer_T else "") + ")")
 
-    if args.telemetry is not None:
-        measure_drift(plan, fn)
-        if telemetry_path:
-            print("telemetry trace:",
-                  tele.collector().export(telemetry_path))
+    if telemetry_path:
+        print("telemetry trace:", tele.collector().export(telemetry_path))
 
     if args.check:
         rstate, rrec = ref_fn(nt, g, gr)

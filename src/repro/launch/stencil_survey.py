@@ -2,17 +2,18 @@
 
 Builds a synthetic survey (shot geometries drawn with varying source /
 receiver counts so multiple buckets exercise the shape-bounding) over a
-random-velocity model, runs it through `survey.SurveyEngine`, and reports
-throughput plus the plan-cache / per-bucket-compile statistics.  With
+random-velocity model, runs it through `survey.SurveyEngine` (the Pallas
+TB kernel per shot, interpret mode off-TPU), and reports throughput plus
+the plan-cache / per-bucket-compile statistics.  With
 ``--check`` every batched trace is compared against a sequential
 `kernels.ops.*_tb_propagate` call for the same shot.
 
-  # 6-shot acoustic survey, pure-jnp executor, 2-shot compiled batches:
+  # 6-shot acoustic survey, 2-shot compiled batches:
   python -m repro.launch.stencil_survey --physics acoustic --shots 6 \
-      --bucket-cap 2 --inner jnp
+      --bucket-cap 2
 
-  # the Pallas kernel per shot (interpret mode off-TPU), with parity:
-  python -m repro.launch.stencil_survey --shots 2 --inner pallas --check
+  # with parity against sequential propagates:
+  python -m repro.launch.stencil_survey --shots 2 --check
 
 Exit codes: 0 ok / parity pass, 1 parity fail.
 """
@@ -123,9 +124,6 @@ def main():
     ap.add_argument("--bucket-cap", type=int, default=2, dest="bucket_cap",
                     help="compiled batch size (shots per dispatch; partial "
                          "batches pad with silent null shots)")
-    ap.add_argument("--inner", default="jnp", choices=("jnp", "pallas"),
-                    help="per-shot executor: pure-jnp window schedule or "
-                         "the Pallas TB kernel (interpret mode off-TPU)")
     ap.add_argument("--interp", default="linear",
                     choices=("linear", "sinc"),
                     help="source/receiver interpolation kernel: trilinear "
@@ -173,13 +171,13 @@ def main():
     spec = interp_mod.spec_for(args.interp, args.interp_order)
     cache = PlanCache()
     engine = SurveyEngine(args.physics, grid, params, nt, dt, order=order,
-                          executor=args.inner, plan_cache=cache,
+                          plan_cache=cache,
                           bucket_cap=args.bucket_cap, interp=spec)
     result = engine.run(shots)
     print("survey stats:", json.dumps(result.stats))
     print(f"survey {args.physics} x{args.shots} shots "
           f"({result.stats['buckets']} buckets, "
-          f"{result.stats['batches']} batches, inner={args.inner}): "
+          f"{result.stats['batches']} batches): "
           f"{result.stats['shots_per_s']:.3f} shots/s, "
           f"{result.stats['mpoints_per_s']:.3f} Mpt/s "
           f"(warm {result.stats['warm_seconds']:.3f}s / "

@@ -9,10 +9,9 @@ everything shot-invariant:
             by padded (nsrc, nrec) (`survey/shots.py`) and each bucket's
             executable runs the single-device TB propagator
             (`kernels/ops.tb_propagate_prepared`) over a stacked shot
-            axis: `jax.vmap` with the jnp executor, a per-shot
-            `jax.lax.map` with the Pallas one (the kernel takes no batch
-            axis), so Pallas shots of a batch run one after another in
-            one dispatch.  Batches are padded to a FIXED leading dim
+            axis with a per-shot `jax.lax.map` (the kernel takes no batch
+            axis), so the shots of a batch run one after another in one
+            dispatch.  Batches are padded to a FIXED leading dim
             (`bucket_cap`) with silent null shots, so ragged buckets and
             repeat runs never re-trace.
   transfer  receiver traces are double-buffered: batch i+1 is dispatched
@@ -80,12 +79,11 @@ class SurveyResult(NamedTuple):
     wavefields: Optional[list] = None
 
 
-# The public contract of `SurveyEngine.run(...).stats` — every key the
-# downstream consumers (benchmarks/fig13_survey.py, check_regression's
-# SURVEY_KEY, the CI survey gate) may read.  tests/test_survey.py pins
-# this set, so growing it is deliberate and shrinking it is loud.
+# The public contract of `SurveyEngine.run(...).stats`: every key a caller
+# may read.  tests/test_survey.py pins this set, so growing it is
+# deliberate and shrinking it is loud.
 RUN_STATS_KEYS = frozenset({
-    "route", "physics", "executor", "shots",
+    "route", "physics", "shots",
     "seconds", "cold_seconds", "warm_seconds",
     "plan_seconds", "compile_seconds",
     "shots_per_s", "mpoints_per_s",
@@ -114,8 +112,9 @@ class SurveyEngine:
       nt:         timesteps per shot (uniform across the survey).
       dt:         timestep.
       order:      space order.
-      executor:   "pallas" (the TB kernel; interpreted on CPU) or "jnp"
-                  (the same window schedule in pure jnp).
+      executor:   "pallas", the only value: every shot runs the TB kernel
+                  (interpreted on CPU).  The keyword goes once the
+                  benchmark's survey mix stops naming it.
       plan:       a TBPlan to skip planning; default consults the plan
                   cache (ONE sweep per configuration).
       plan_cache: PlanCache instance (default: the process-wide cache).
@@ -143,7 +142,7 @@ class SurveyEngine:
                  plan_kwargs: Optional[dict] = None,
                  interp: Union[str, interp_mod.InterpSpec] = "linear",
                  interp_order: Optional[int] = None):
-        if executor not in ("pallas", "jnp"):
+        if executor != "pallas":
             raise ValueError(f"unknown executor {executor!r}")
         self.interp = (interp if isinstance(interp, interp_mod.InterpSpec)
                        else interp_mod.spec_for(interp, interp_order))
@@ -156,7 +155,6 @@ class SurveyEngine:
         self.nt = int(nt)
         self.dt = float(dt)
         self.order = int(order)
-        self.executor = executor
         self.bucket_cap = int(bucket_cap)
         if self.bucket_cap < 1:
             raise ValueError("bucket_cap must be >= 1")
@@ -268,7 +266,7 @@ class SurveyEngine:
         spec, rspec = self._specs(key)
         physics, nt = self.physics, self.nt
         nrec_pad = key[1]
-        interpret, executor = self.interpret, self.executor
+        interpret = self.interpret
         self.trace_counts.setdefault(key, 0)
 
         def one_shot(param_pads, rparam_pads, arrs: _ShotArrays):
@@ -276,17 +274,14 @@ class SurveyEngine:
                 physics, nt, spec, rspec, self._zero_state, param_pads,
                 rparam_pads, arrs.src_dcmp, arrs.src_tab, arrs.rec_tab,
                 arrs.rsrc_tab, arrs.rrec_tab, nrec_pad,
-                interpret=interpret, executor=executor)
+                interpret=interpret)
 
         def batched(param_pads, rparam_pads, batch: _ShotArrays):
             # fires once per jit trace: the compile counter the acceptance
             # test pins to 1 per bucket
             self.trace_counts[key] += 1
-            if executor == "pallas":
-                return jax.lax.map(
-                    lambda a: one_shot(param_pads, rparam_pads, a), batch)
-            return jax.vmap(one_shot, in_axes=(None, None, 0))(
-                param_pads, rparam_pads, batch)
+            return jax.lax.map(
+                lambda a: one_shot(param_pads, rparam_pads, a), batch)
 
         fn = jax.jit(batched)
         self._execs[key] = (fn, spec, rspec)
@@ -382,7 +377,7 @@ class SurveyEngine:
 
         # cold = planning (first run only) + jit traces; warm = the
         # steady-state execution wall the throughput numbers must use, so
-        # BENCH_survey stops depending on cache state
+        # they do not depend on cache state
         plan_seconds = 0.0 if self._plan_claimed else self._plan_seconds
         self._plan_claimed = True
         cold_seconds = plan_seconds + compile_seconds
@@ -393,9 +388,8 @@ class SurveyEngine:
         self.metrics.counter("survey.shots").inc(n)
         self.metrics.counter("survey.batches").inc(n_batches)
         stats = {
-            "route": "map" if self.executor == "pallas" else "vmap",
-            "physics": self.physics_name, "executor": self.executor,
-            "shots": n, "seconds": seconds,
+            "route": "map", "physics": self.physics_name, "shots": n,
+            "seconds": seconds,
             "cold_seconds": cold_seconds, "warm_seconds": warm_seconds,
             "plan_seconds": plan_seconds,
             "compile_seconds": compile_seconds,
@@ -470,7 +464,7 @@ class SurveyEngine:
             "shots_per_s": n / seconds if seconds else float("inf"),
             "mpoints_per_s": pts / seconds / 1e6 if seconds else 0.0,
             "mesh": dict(dist_plan.mesh.shape),
-            "outer_T": dist_plan.T, "inner": dist_plan.inner,
+            "outer_T": dist_plan.T,
             "cache": {"sweeps": self.cache.sweeps},
         }
         return SurveyResult(traces=traces, stats=stats)

@@ -14,9 +14,8 @@ The cached value is JSON (via `TBPlan.to_dict` / `HierPlan.to_dict` plus
 the winning sweep-log entry), so the disk cache is a directory of small
 self-describing files — safe to delete at any time, shared across
 processes.  Consumers: `survey.engine.SurveyEngine`,
-`launch/stencil_dist.py --auto-plan`, `launch/dryrun.stencil_plan_report`
-(hence `benchmarks/fig12_scaling.py --dryrun`), and
-`benchmarks/fig13_survey.py`.
+`launch/stencil_dist.py --auto-plan` and
+`launch/dryrun.stencil_plan_report`.
 
 Set ``REPRO_PLAN_CACHE_DIR`` to point the default cache's disk tier
 somewhere persistent (default: in-memory only, so tests and one-shot

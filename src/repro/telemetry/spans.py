@@ -4,7 +4,7 @@
 The whole subsystem is OFF by default: `span(...)` returns a shared no-op
 context manager until `enable()` installs a collector, so instrumented hot
 paths (the survey dispatch loop, the sharded driver) pay a dict lookup and
-nothing else — the <2% fig13 overhead budget of ISSUE 10.
+nothing else.
 
 Two measurement regimes, both first-class:
 

@@ -5,7 +5,6 @@ use).  Each check prints one JSON line.
 
     python tests/_sharded_case.py parity|counts
 """
-import dataclasses
 import json
 import sys
 
@@ -48,13 +47,13 @@ def case(seed=0, nt=7):
     return grid, dt, m, damp, g, gr
 
 
-def the_1024_class(mesh, dt, physics=phys.ACOUSTIC, inner="pallas"):
+def the_1024_class(mesh, dt, physics=phys.ACOUSTIC):
     """The plan class `plan_hierarchy` picks at 1024^3: inner and outer T
     4, overlap, per-field depths (6, 8), an inner tile narrower in x than
     in y."""
     return H.DistTBPlan(mesh=mesh, grid_shape=SHAPE, physics=physics,
                         order=ORDER, T=4, dt=dt, spacing=(10.0,) * 3,
-                        inner=inner, inner_plan=TBPlan((4, 8), 4, 2),
+                        inner_plan=TBPlan((4, 8), 4, 2),
                         overlap=True)
 
 
@@ -146,42 +145,29 @@ def counts():
     mesh = mesh_lib.make_xy_mesh()
     px, py = mesh.shape["data"], mesh.shape["model"]
     grid, dt, m, damp, g, gr = case()
-    out = []
-    seen = []
-
-    def counting(state, params, spec, mask_fn):
-        new = phys.ACOUSTIC.update(state, params, spec, mask_fn)
-        seen.append(int(np.prod(new["u"].shape)))
-        return new
-
-    counted = dataclasses.replace(phys.ACOUSTIC, update=counting)
-    # jnp executor: every update call counted as it is traced; nested
-    # passes (inner T 2 of outer 4, then 1) after the split first step
-    plans = [the_1024_class(mesh, dt),
-             the_1024_class(mesh, dt, counted, inner="jnp")._replace(
-                 inner_plan=TBPlan((4, 8), 2, 2))]
-    for plan in plans:
+    out = {}
+    # the flat class, and nested passes (inner T 2 of outer 4, then 1)
+    # after the split first step
+    plans = {"flat": the_1024_class(mesh, dt),
+             "nested": the_1024_class(mesh, dt)._replace(
+                 inner_plan=TBPlan((4, 8), 2, 2))}
+    for name, plan in plans.items():
         nt = plan.T + 3          # one main tile (the scan body traces
         #                          once) and a remainder tile
         state = (jnp.zeros(SHAPE, jnp.float32),) * 2
         params = {"m": m, "damp": damp}
-        seen.clear()
         with mesh:
             jaxpr = jax.make_jaxpr(
                 lambda s, p: H.sharded_tb_propagate(plan, nt, s, p, g, gr)
             )(state, params)
         nperm, nbytes = _ppermutes(jaxpr.jaxpr, mesh, [0, 0])
-        c = H.sharded_counts(plan, nt)
-        if plan.inner == "jnp":
-            brute = sum(seen) * px * py
-        else:
-            brute = sum((_split_points(plan, d * plan.r_step)
-                         + _kernel_points(plan, d)) * px * py
-                        for d in (plan.T, nt % plan.T))
-        out.append({"inner": plan.inner, "ppermutes": nperm,
-                    "bytes": nbytes, "update_points": brute,
-                    "useful_points": int(np.prod(SHAPE)) * nt,
-                    "counters": c})
+        brute = sum((_split_points(plan, d * plan.r_step)
+                     + _kernel_points(plan, d)) * px * py
+                    for d in (plan.T, nt % plan.T))
+        out[name] = {"ppermutes": nperm, "bytes": nbytes,
+                     "update_points": brute,
+                     "useful_points": int(np.prod(SHAPE)) * nt,
+                     "counters": H.sharded_counts(plan, nt)}
     print(json.dumps(out))
 
 

@@ -161,18 +161,17 @@ def test_property_field_depths_bounded_and_monotone(name, T, order):
 @given(name=hst.sampled_from(["acoustic", "tti", "elastic"]),
        T=hst.integers(1, 4), order=hst.sampled_from([2, 4]))
 def test_property_dist_plan_field_depths(name, T, order):
-    """`DistTBPlan.field_depths` == the physics' cone depths (per-field
-    on), == the uniform depth everywhere (per-field off), at ANY tile
-    depth including the remainder depths below plan.T."""
+    """`DistTBPlan.field_depths` == the physics' cone depths, none above
+    the uniform depth and one at it, at ANY tile depth including the
+    remainder depths below plan.T."""
     tp = phys.PHYSICS[name]
     plan = _one_device_plan(tp, T, order)
     for T_depth in range(1, T + 1):
         h = T_depth * plan.r_step
-        assert plan.field_depths(T_depth) == tp.field_halo_depths(T_depth,
-                                                                  order)
-        uni = plan._replace(per_field_halo=False).field_depths(T_depth)
-        assert uni == (h,) * len(tp.state_fields)
-        assert all(d <= h for d in plan.field_depths(T_depth))
+        depths = plan.field_depths(T_depth)
+        assert depths == tp.field_halo_depths(T_depth, order)
+        assert len(depths) == len(tp.state_fields)
+        assert all(d <= h for d in depths) and max(depths) == h
 
 
 # ---------------------------------------------------------------------------

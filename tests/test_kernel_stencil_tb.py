@@ -249,8 +249,7 @@ def _call_spans(c, run):
     return out, d.attrs, t.attrs, {r.name for r in recs}
 
 
-@pytest.mark.parametrize("executor", ["pallas", "jnp"])
-def test_warm_call_reuses_the_compiled_propagate(spans_on, executor):
+def test_warm_call_reuses_the_compiled_propagate(spans_on):
     nt, order = 5, 4
     grid, m, damp, dt, g, gr, u0, u1 = _setup(order=order, nt=nt)
     plan = TBPlan(tile=(8, 8), T=2, radius=order // 2)
@@ -258,8 +257,7 @@ def test_warm_call_reuses_the_compiled_propagate(spans_on, executor):
 
     def run():
         return ops.acoustic_tb_propagate(nt, u0, u1, m, damp, g, gr, plan,
-                                         order, dt, grid.spacing,
-                                         executor=executor)
+                                         order, dt, grid.spacing)
 
     cold, d0, _, names0 = _call_spans(spans_on, run)
     warm, d1, _, names1 = _call_spans(spans_on, run)
@@ -271,9 +269,8 @@ def test_warm_call_reuses_the_compiled_propagate(spans_on, executor):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("executor", ["pallas", "jnp"])
 @pytest.mark.parametrize("change", ["nt", "sources"])
-def test_new_nt_or_caps_trace_again(spans_on, executor, change):
+def test_new_nt_or_caps_trace_again(spans_on, change):
     order = 4
     plan = TBPlan(tile=(8, 8), T=2, radius=order // 2)
     ops._tb_propagate_jit.clear_cache()
@@ -282,8 +279,7 @@ def test_new_nt_or_caps_trace_again(spans_on, executor, change):
         grid, m, damp, dt, g, gr, u0, u1 = _setup(order=order, nt=6,
                                                   nsrc=nsrc, nrec=3)
         got = ops.acoustic_tb_propagate(nt, u0, u1, m, damp, g, gr, plan,
-                                        order, dt, grid.spacing,
-                                        executor=executor)
+                                        order, dt, grid.spacing)
         want = ref.acoustic_reference(nt, u0, u1, m, damp, dt,
                                       grid.spacing, order, g=g,
                                       receivers=gr)
@@ -304,12 +300,11 @@ def test_new_nt_or_caps_trace_again(spans_on, executor, change):
                                    rtol=2e-4, atol=1e-6)
 
 
-@pytest.mark.parametrize("executor", ["pallas", "jnp"])
 @pytest.mark.parametrize("physics,nt,T", [
     ("acoustic", 7, 3),     # two depth-3 tiles and a depth-1 remainder
     ("tti", 4, 2),
 ])
-def test_jitted_entry_matches_eager_core(physics, nt, T, executor):
+def test_jitted_entry_matches_eager_core(physics, nt, T):
     """`_tb_propagate_jit` on the prepared inputs gives what the traced
     core, `tb_propagate_prepared`, gives called eagerly on them."""
     from repro.kernels import tb_physics as phys
@@ -335,17 +330,8 @@ def test_jitted_entry_matches_eager_core(physics, nt, T, executor):
     rpads = (tuple(ops._pad_xy(q, rspec.halo, "edge") for q in params_)
              if rspec is not None else None)
     eager = ops.tb_propagate_prepared(physics, nt, spec, rspec, st_, pads,
-                                      rpads, src_dcmp, *tables, nrec,
-                                      executor=executor)
-    jitted = ops._tb_propagate_jit(*static, None, executor, *args)
+                                      rpads, src_dcmp, *tables, nrec)
+    jitted = ops._tb_propagate_jit(*static, None, *args)
     assert jitted[1].shape == (nt, nrec, physics.rec_channels)
     for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager)):
-        a, b = np.asarray(a), np.asarray(b)
-        if executor == "pallas":
-            np.testing.assert_array_equal(a, b)
-        else:
-            # XLA:CPU contracts a*b+c across what the eager call ran as
-            # separate programs: a few ulps of the field's scale
-            np.testing.assert_allclose(
-                a, b, rtol=0, atol=4 * np.finfo(np.float32).eps
-                * np.abs(b).max())
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -45,15 +45,14 @@ def test_sharded_entry_equals_reference():
     assert out["shards"] == [[16, 16, 16]]
 
 
-@pytest.mark.parametrize("inner", ["pallas", "jnp"])
-def test_sharded_counters_equal_brute_force(inner):
+@pytest.mark.parametrize("plan", ["flat", "nested"])
+def test_sharded_counters_equal_brute_force(plan):
     """`sharded_counts` against counts taken another way, over one main
-    tile and one remainder tile: the ppermutes and the bytes they send,
-    from the traced program itself; the update points, for the jnp
-    executor from every update the trace evaluates (nested passes after
-    the split first step), for the kernel from its slab loops and the
-    split step's index boxes."""
-    got = {c["inner"]: c for c in _case("counts")}[inner]
+    tile and one remainder tile, for the flat 1024^3 class and for nested
+    passes after the split first step: the ppermutes and the bytes they
+    send, from the traced program itself; the update points from the
+    kernel's slab loops and the split step's index boxes."""
+    got = _case("counts")[plan]
     c = got["counters"]
     assert c["exchanges"] == got["ppermutes"] > 0
     assert c["exchange_bytes"] == got["bytes"] > 0

@@ -4,8 +4,8 @@ The acceptance points of the survey subsystem (ISSUE 5):
 
   * shots bucket by padded (nsrc, nrec) with zero-amplitude padding that
     cannot change results (ragged buckets included);
-  * a vmapped bucket of K shots matches K sequential `*_tb_propagate`
-    calls for every physics and both executors;
+  * a batched bucket of K shots matches K sequential `*_tb_propagate`
+    calls for every physics;
   * a multi-bucket survey runs EXACTLY one autotune sweep and one jit
     trace per bucket — rerunning adds neither.
 """
@@ -16,8 +16,7 @@ from repro.core import sources as S
 from repro.core.grid import Grid
 from repro.core.temporal_blocking import TBPlan
 from repro.kernels import tb_physics as phys
-# the CLI's model builder and sequential oracle ARE the test fixtures —
-# one copy, shared with benchmarks/fig13_survey.py
+# the CLI's model builder and sequential oracle ARE the test fixtures
 from repro.launch.stencil_survey import build_model, sequential_traces
 from repro.survey import PlanCache, Shot, SurveyEngine, bucket_shots
 from repro.survey.shots import pad_count
@@ -92,29 +91,19 @@ def test_shot_padding_is_silent():
 
 
 # ---------------------------------------------------------------------------
-# Batched parity: vmapped bucket == K sequential *_tb_propagate calls
+# Batched parity: a mapped bucket == K sequential *_tb_propagate calls
 # ---------------------------------------------------------------------------
 
-# One fast representative stays in tier-1; the rest of the physics x
-# executor matrix is multi-minute jit work and runs in the CI slow job.
-@pytest.mark.parametrize("physics_name,executor", [
-    ("acoustic", "pallas"),
-    pytest.param("acoustic", "jnp", marks=pytest.mark.slow),
-    pytest.param("tti", "jnp", marks=pytest.mark.slow),
-    pytest.param("tti", "pallas", marks=pytest.mark.slow),
-    pytest.param("elastic", "jnp", marks=pytest.mark.slow),
-    pytest.param("elastic", "pallas", marks=pytest.mark.slow),
-])
-def test_batched_parity(physics_name, executor):
+@pytest.mark.parametrize("physics_name", ["acoustic", "tti", "elastic"])
+def test_batched_parity(physics_name):
     grid, dt, params = _case(physics_name, n=8)
     plan = TBPlan(tile=(8, 8), T=2,
                   radius=phys.PHYSICS[physics_name].step_radius(ORDER))
     # a ragged bucket: nsrc 3 pads to 4 (zero-amplitude source) next to an
-    # exact-shape nsrc-4 shot — one vmapped batch of both
+    # exact-shape nsrc-4 shot — one batch of both
     shots = [_shot(grid, dt, 3, 3, seed=1), _shot(grid, dt, 4, 3, seed=2)]
     engine = SurveyEngine(physics_name, grid, params, NT, dt, order=ORDER,
-                          executor=executor, plan=plan,
-                          plan_cache=PlanCache(), bucket_cap=2)
+                          plan=plan, plan_cache=PlanCache(), bucket_cap=2)
     result = engine.run(shots)
     refs = _sequential(physics_name, shots, grid, params, plan, dt)
     for i, (got, ref) in enumerate(zip(result.traces, refs)):
@@ -139,7 +128,7 @@ def test_engine_one_sweep_one_trace_per_bucket():
              _shot(grid, dt, 1, 3, seed=5)]
     cache = PlanCache()
     engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                          executor="jnp", plan_cache=cache, bucket_cap=2)
+                          plan_cache=cache, bucket_cap=2)
     result = engine.run(shots)
     assert result.stats["buckets"] >= 2
     assert cache.sweeps == 1
@@ -147,7 +136,7 @@ def test_engine_one_sweep_one_trace_per_bucket():
 
     # a second engine over the same configuration: the sweep is cached
     engine2 = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                           executor="jnp", plan_cache=cache, bucket_cap=2)
+                           plan_cache=cache, bucket_cap=2)
     assert cache.sweeps == 1 and engine2.cache_info.hit
 
     # rerunning the first engine re-traces nothing
@@ -172,10 +161,10 @@ def test_run_stats_keys_and_cold_warm_split():
     grid, dt, params = _case("acoustic")
     shots = [_shot(grid, dt, 1, 3, seed=1), _shot(grid, dt, 2, 3, seed=2)]
     engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                          executor="jnp", plan_cache=PlanCache(),
-                          bucket_cap=2)
+                          plan_cache=PlanCache(), bucket_cap=2)
     s = engine.run(shots).stats
     assert set(s) == set(RUN_STATS_KEYS)
+    assert s["route"] == "map"
     assert s["plan_seconds"] > 0.0          # this engine ran the sweep
     assert s["compile_seconds"] > 0.0       # first run traced its buckets
     assert s["cold_seconds"] == pytest.approx(
@@ -220,8 +209,7 @@ def test_survey_spans_reach_trace_annotation(monkeypatch):
     plan = TBPlan(tile=(8, 8), T=2, radius=2)
     shots = [_shot(grid, dt, 1, 3, seed=1), _shot(grid, dt, 1, 3, seed=2)]
     engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                          executor="jnp", plan=plan, plan_cache=PlanCache(),
-                          bucket_cap=2)
+                          plan=plan, plan_cache=PlanCache(), bucket_cap=2)
     c = tsp.enable(jax_profiler=True)
     try:
         engine.run(shots)
@@ -255,9 +243,9 @@ def test_survey_spans_reach_trace_annotation(monkeypatch):
         assert all(0 < n <= rec_cap for n in t["rec_live"])
 
 
-def test_sharded_route_matches_vmap_route():
+def test_sharded_route_matches_map_route():
     """`run_sharded` (shot round-robin through `sharded_tb_propagate` on a
-    1x1 mesh) must produce the same traces as the vmapped single-device
+    1x1 mesh) must produce the same traces as the single-device `map`
     route."""
     from repro.distributed.halo import DistTBPlan
     from repro.launch import mesh as mesh_lib
@@ -265,25 +253,32 @@ def test_sharded_route_matches_vmap_route():
     grid, dt, params = _case("acoustic", n=16)
     shots = [_shot(grid, dt, 2, 3, seed=1), _shot(grid, dt, 1, 4, seed=2)]
     engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                          executor="jnp", plan_cache=PlanCache(),
-                          bucket_cap=2)
-    vres = engine.run(shots)
+                          plan_cache=PlanCache(), bucket_cap=2)
+    mres = engine.run(shots)
+    assert mres.stats["route"] == "map"
     dplan = DistTBPlan(mesh=mesh_lib.make_xy_mesh(),
                        grid_shape=tuple(grid.shape),
                        physics=phys.ACOUSTIC, order=ORDER, T=2, dt=dt,
                        spacing=grid.spacing)
     sres = engine.run_sharded(shots, dplan)
     assert sres.stats["route"] == "sharded"
-    for got, ref in zip(sres.traces, vres.traces):
+    for got, ref in zip(sres.traces, mres.traces):
         assert got.shape == ref.shape
         scale = float(np.max(np.abs(ref))) + 1e-30
         assert float(np.max(np.abs(got - ref))) <= 5e-4 * scale + 1e-6
 
 
+def test_engine_rejects_unknown_executor():
+    grid, dt, params = _case("acoustic")
+    with pytest.raises(ValueError, match="executor"):
+        SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
+                     executor="jnp", plan_cache=PlanCache())
+
+
 def test_engine_rejects_mismatched_nt():
     grid, dt, params = _case("acoustic")
     engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                          executor="jnp", plan_cache=PlanCache())
+                          plan_cache=PlanCache())
     bad = _shot(grid, dt, 1, 2, seed=1)
     bad = Shot(src_coords=bad.src_coords,
                wavelet=np.zeros((NT + 2, 1)), rec_coords=bad.rec_coords)
@@ -301,23 +296,18 @@ def test_caps_scale_with_interp_footprint():
 
     grid, dt, params = _case("acoustic")
     lin = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                       executor="jnp", plan_cache=PlanCache())
+                       plan_cache=PlanCache())
     assert lin._caps((4, 8)) == (8 * 4, 8 * 4, 8 * 8)
     snc = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                       executor="jnp", plan_cache=PlanCache(),
-                       interp="sinc", interp_order=2)
+                       plan_cache=PlanCache(), interp="sinc",
+                       interp_order=2)
     assert snc.interp == interp_mod.InterpSpec(kernel="sinc", radius=2)
     assert snc._caps((4, 8)) == (64 * 4, 64 * 4, 64 * 8)
 
 
-@pytest.mark.slow
 def test_batched_parity_sinc():
-    """The sinc survey path end-to-end: engine (batched, jnp executor)
-    vs the sequential oracle with the SAME spec — traces must agree.
-
-    Slow: the footprint-64 scatter/gather makes this the most expensive
-    jit in the suite; tier-1 sinc coverage lives in test_interp.py /
-    test_sources.py (inject + receiver parity without a TB propagate)."""
+    """The sinc survey path end-to-end: engine (batched) vs the sequential
+    oracle with the SAME spec — traces must agree."""
     from repro.core import interp as interp_mod
 
     spec = interp_mod.InterpSpec(kernel="sinc", radius=2)
@@ -335,8 +325,8 @@ def test_batched_parity_sinc():
                                                          num=2),
                           rec_coords=rec, shot_id=i))
     engine = SurveyEngine("acoustic", grid, params, NT, dt, order=ORDER,
-                          executor="jnp", plan_cache=PlanCache(),
-                          bucket_cap=2, interp=spec)
+                          plan_cache=PlanCache(), bucket_cap=2,
+                          interp=spec)
     result = engine.run(shots)
     assert result.stats["interp"] == spec.to_dict()
     assert result.stats["footprint"] == 64

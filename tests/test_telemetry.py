@@ -1,4 +1,4 @@
-"""Telemetry layer: spans, metrics, and cost-model drift (ISSUE 10).
+"""Telemetry layer: spans and metrics.
 
 Acceptance points under test:
 
@@ -10,10 +10,6 @@ Acceptance points under test:
     its slot fill, the dispatch with its compile count) all reach the
     profiler's timeline, and its tile pass lowers with fixed scope and
     kernel names whether or not telemetry is on;
-  * `predict_plan_terms` reproduces the autotune sweep's own arithmetic
-    for an executed plan (same `TBPlan` pricing methods, same hardware
-    defaults), and the drift ledger's ratios/geomeans are exact on
-    synthetic numbers;
   * a corrupt/truncated disk plan-cache entry is a counted miss (warning,
     not a crash) and the next sweep's `store` overwrites it.
 """
@@ -25,11 +21,9 @@ import time
 import numpy as np
 import pytest
 
-from repro import telemetry as tele
-from repro.core.temporal_blocking import PHYSICS_COSTS, TBPlan
+from repro.core.temporal_blocking import TBPlan
 from repro.telemetry import metrics as tm
 from repro.telemetry import spans as tsp
-from repro.telemetry.drift import _SWEEP_DEFAULTS
 
 
 @pytest.fixture
@@ -352,103 +346,6 @@ def test_metrics_registry_and_snapshot():
         r.gauge("hits")  # name already registered as a counter
     r.clear()
     assert r.snapshot() == {"counters": {}, "gauges": {}, "histograms": {}}
-
-
-# ---------------------------------------------------------------------------
-# Drift: prediction must equal the sweep's own arithmetic
-# ---------------------------------------------------------------------------
-
-HW = dict(peak_flops=1e12, hbm_bw=1e11, link_bw=1e10, link_latency=2e-6,
-          dtype_bytes=4)
-
-
-def test_predict_single_device_matches_tbplan_methods():
-    pc = PHYSICS_COSTS["acoustic"]
-    plan = TBPlan((16, 16), 2, pc.step_radius(4))
-    got = tele.predict_plan_terms("acoustic", 64, 4, plan, **HW)
-    comp = plan.overlap_factor() * pc.flops_per_point(4) / HW["peak_flops"]
-    mem = plan.hbm_bytes_per_point_step(
-        64, read_fields=pc.read_fields, write_fields=pc.write_fields,
-        dtype_bytes=4) / HW["hbm_bw"]
-    assert got["compute_s"] == pytest.approx(comp)
-    assert got["memory_s"] == pytest.approx(mem)
-    assert got["exchange_s"] == 0.0 and got["split_s"] == 0.0
-    assert got["roofline_s"] == pytest.approx(max(comp, mem))
-    assert got["total_s"] == pytest.approx(max(comp, mem))
-    assert not got["nested"]
-
-
-def test_predict_sharded_terms_and_serialization():
-    pc = PHYSICS_COSTS["elastic"]
-    plan = TBPlan((16, 16), 2, pc.step_radius(4))
-    block = (32, 32)
-    got = tele.predict_plan_terms("elastic", 64, 4, plan, block=block, **HW)
-    depths = tuple(max(plan.halo - lag, 0) for lag in pc.exchange_lags(4))
-    exch = plan.exchange_seconds_per_point_step(
-        block, 64, pc.state_fields, HW["link_bw"], HW["link_latency"],
-        dtype_bytes=4, depths=depths)
-    assert got["exchange_s"] == pytest.approx(exch) and exch > 0.0
-    assert got["total_s"] == pytest.approx(got["roofline_s"] + exch)
-    # overlapped schedule: max(roofline, exch) + split instead
-    ov = tele.predict_plan_terms("elastic", 64, 4, plan, block=block,
-                                 overlap=True, **HW)
-    assert ov["split_s"] > 0.0
-    assert ov["total_s"] == pytest.approx(
-        max(ov["roofline_s"], ov["exchange_s"]) + ov["split_s"])
-
-
-def test_predict_defaults_track_autotune_signature():
-    # the guarantee that prediction and sweep can never disagree on the
-    # hardware model: defaults are read off autotune_plan's own signature
-    import inspect
-
-    from repro.core.temporal_blocking import autotune_plan
-
-    sig = inspect.signature(autotune_plan).parameters
-    for k, v in _SWEEP_DEFAULTS.items():
-        assert sig[k].default == v
-    got = tele.predict_plan_terms(
-        "acoustic", 64, 4, TBPlan((16, 16), 2, 1))
-    assert got["hardware"] == _SWEEP_DEFAULTS
-
-
-def test_drift_ledger_ratios_and_geomean(tmp_path):
-    led = tele.DriftLedger()
-    pred = {"compute_s": 1.0, "memory_s": 2.0, "exchange_s": 4.0,
-            "split_s": 0.0, "roofline_s": 2.0, "total_s": 6.0,
-            "hardware": {"hw": 1}}
-    rec = led.record({"cell": "a"}, pred,
-                     {"compute_s": 2.0, "memory_s": 6.0, "exchange_s": 4.0,
-                      "total_s": 12.0, "kernel_s": 4.0})
-    assert rec["ratio"] == {"compute_s": 2.0, "memory_s": 3.0,
-                            "exchange_s": 1.0, "total_s": 2.0,
-                            "kernel_vs_roofline": 2.0}
-    assert "hardware" not in rec["predicted"]  # hoisted to its own key
-    led.record({"cell": "b"}, pred,
-               {"compute_s": 8.0, "total_s": 24.0})  # partial measurement
-    rep = led.report()
-    s = rep["summary"]
-    assert s["compute_s"] == {"geomean_ratio": pytest.approx(4.0), "n": 2}
-    assert s["total_s"] == {"geomean_ratio": pytest.approx(
-        (2.0 * 4.0) ** 0.5), "n": 2}
-    assert s["memory_s"]["n"] == 1  # missing terms don't poison the geomean
-    assert s["exchange_s"]["geomean_ratio"] == pytest.approx(1.0)
-
-    p = led.save(str(tmp_path / "drift.json"))
-    last = tele.last_drift(p)
-    assert last["n_records"] == 2
-    assert last["summary"]["compute_s"]["geomean_ratio"] == \
-        pytest.approx(4.0)
-
-
-def test_last_drift_missing_or_corrupt(tmp_path):
-    assert tele.last_drift(str(tmp_path / "nope.json")) is None
-    bad = tmp_path / "bad.json"
-    bad.write_text('{"records": [')
-    assert tele.last_drift(str(bad)) is None
-    notdrift = tmp_path / "nd.json"
-    notdrift.write_text('[1, 2, 3]')
-    assert tele.last_drift(str(notdrift)) is None
 
 
 # ---------------------------------------------------------------------------

@@ -90,7 +90,7 @@ def test_tb_time_tile_compiles_for_v5e(one_chip, monkeypatch, physics,
                                     rc, rw, interpret=False)
         if batch is None:
             return one(state, sc, sv, rc, rw)
-        # the survey engine's Pallas executor maps over the shots of a
+        # the survey engine maps the kernel over the shots of a
         # batch, with the model shared
         return jax.lax.map(lambda a: one(*a), (state, sc, sv, rc, rw))
 
@@ -187,8 +187,7 @@ def test_one_chip_entry_fits_v5e_at_512(one_chip, physics, spacing, nt):
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)
-    compiled = ops._tb_propagate_jit.lower(*static, False, "pallas",
-                                           *args).compile()
+    compiled = ops._tb_propagate_jit.lower(*static, False, *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     ma = compiled.memory_analysis()
     per_chip = (ma.argument_size_in_bytes + ma.output_size_in_bytes
