@@ -5,20 +5,27 @@ Runs the plan the planner picks at the size asked for (acoustic
 `(32, 32)` T 8, TTI `(16, 32)` T 2 at 512^3), and prints one line per
 case: the best of `--reps` calls after a warm one, and the x-planes the
 call's steps keep (summed over tiles and steps).  Sparse slots are
-either empty, at window point 0 as the tile tables pad them (the slot
-loops run, their bodies do not), or live, all at window point (h, h, h),
-so that the slab holding plane h runs every slot's body each step.
+either empty, at window point 0 as the tile tables pad them, with no live
+entry in any tile, or live, all at window point (h, h, h) and every slot
+counted live, so that the slab holding plane h runs every slot's body
+each step.  A kernel that bounds its slot loops by the tile's live count
+runs no iteration over empty slots; one that loops over the caps (a
+checkout before the counts) runs them all, bodies skipped.
 
   python3 benchmarks/tb_kernel_call.py [--src DIR] [--physics acoustic tti]
-      [--n 512] [--caps 8,56 1,1] [--slots empty]
+      [--n 512] [--caps 8,56 8,256 1,1] [--slots empty]
 
-`--src` is the `src/` directory of the checkout whose kernel is timed
-(default: this one's), so two commits can be timed by one script.
+Caps `8,56` are the acoustic propagate's at 512^3 (sized to its fullest
+tile), `8,256` the survey's (worst cases from its bucket key, 1 source
+and 32 receivers), and `1,1` the least a table holds.  `--src` is the
+`src/` directory of the checkout whose kernel is timed (default: this
+one's), so two commits can be timed by one script.
 """
 from __future__ import annotations
 
 import argparse
 import functools
+import inspect
 import os
 import sys
 import time
@@ -61,6 +68,7 @@ def main(argv=None):
 
     n = args.n
     interpret = jax.devices()[0].platform != "tpu"
+    takes_counts = "counts" in inspect.signature(ker.tb_time_tile).parameters
     cases = [(name, caps) for name in args.physics for caps in args.caps]
     for name, caps in cases:
         physics = phys.PHYSICS[name]
@@ -83,9 +91,12 @@ def main(argv=None):
         src_vals = jnp.zeros((ntiles, T, src_cap))
         rec_coords = jnp.full((ntiles, rec_cap, 3), at, jnp.int32)
         rec_w = jnp.zeros((ntiles, rec_cap))
+        live = (0, 0) if args.slots == "empty" else (src_cap, rec_cap)
+        counts = jnp.tile(jnp.asarray([live], jnp.int32), (ntiles, 1))
         call = jax.jit(functools.partial(ker.tb_time_tile, spec, physics,
                                          interpret=interpret))
-        operands = (states, params, src_coords, src_vals, rec_coords, rec_w)
+        operands = (states, params, src_coords, src_vals, rec_coords, rec_w,
+                    *((counts,) if takes_counts else ()))
         jax.block_until_ready(call(*operands))
         times = []
         for _ in range(args.reps):

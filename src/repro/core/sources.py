@@ -412,9 +412,12 @@ class TileSourceTable(NamedTuple):
     (full z), entries are affected points inside the centre region with
     coordinates local to the tile's *window* origin (centre minus halo).
 
-    nnz:    (n_tiles,) int32 — valid entries per tile, a host (numpy)
-            array: the kernel never reads it, and counting the live slots
-            from it syncs nothing.
+    nnz:    (n_tiles,) int32 — valid entries per tile, which fill slots
+            0..nnz-1: the kernel's inject loop runs that many iterations
+            a slab (`stencil_tb.tb_time_tile`'s `counts`).  A host
+            (numpy) array, so counting the live slots from it syncs
+            nothing; the propagate passes it to the device with the rest
+            of the table.
     coords: (n_tiles, cap, 3) int32 — window-local (x, y, z), padded 0.
     sid:    (n_tiles, cap) int32 — SID per entry, padded -1.
     scale:  (n_tiles, cap) float32 — per-point physical factor, padded 0.
@@ -485,7 +488,8 @@ class TileReceiverTable(NamedTuple):
     sample — the host segment-sums partials by receiver id afterwards.
     """
 
-    nnz: np.ndarray         # (n_tiles,) host-side, as TileSourceTable's
+    nnz: np.ndarray         # (n_tiles,) as TileSourceTable's: bounds the
+    #                         kernel's record loop
     coords: jnp.ndarray     # (n_tiles, cap, 3) window-local
     rid: jnp.ndarray        # (n_tiles, cap) receiver id, padded -1
     weight: jnp.ndarray     # (n_tiles, cap) float32

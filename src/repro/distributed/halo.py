@@ -391,7 +391,7 @@ def _reach(a, origin: Tuple[int, int], need: Tuple[int, int], fill: float):
 def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state,
               s_depth: Tuple[int, int], param_frames,
               p_depth: Tuple[int, int], s_coords, s_vals, r_coords, r_w,
-              interpret: bool):
+              counts, interpret: bool):
     """Advance ONE inner pass of the time-nested schedule (DESIGN.md §4).
 
     `state` holds the block at (x, y) depth `s_depth` (a frame, or the
@@ -405,7 +405,8 @@ def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state,
     box in pass-grid coordinates.
 
     Tables are per pass-local tile: s_coords (ntiles, cap, 3) window-local,
-    s_vals (ntiles, geom.T, cap), r_coords/r_w likewise.  Returns
+    s_vals (ntiles, geom.T, cap), r_coords/r_w likewise, and counts
+    (ntiles, 2) the live (source, receiver) entries of each tile.  Returns
     (state tuple at depth d_out, rec partials (ntiles, geom.T, capr, chan)).
     """
     physics = plan.physics
@@ -432,7 +433,7 @@ def _run_pass(plan: DistTBPlan, geom: TBPassGeom, state,
                   for f, a in zip(physics.param_fields, param_frames))
     new, rec = ker.tb_time_tile(
         spec, physics, spads, ppads, s_coords, s_vals, r_coords, r_w,
-        origins=(so,) * len(spads) + (po,) * len(ppads),
+        counts, origins=(so,) * len(spads) + (po,) * len(ppads),
         dom_box=jnp.stack(bounds).astype(jnp.int32), interpret=interpret)
     new = tuple(a[:keep[0], :keep[1]] for a in new)
     rec = rec.reshape(ntx * nty, geom.T, rec.shape[-2], rec.shape[-1])
@@ -585,7 +586,8 @@ def _pass_source_tables(plan: DistTBPlan, g, geom: TBPassGeom,
     Returns (coords (px, py, ntl, cap, 3) window-local int32,
              sid    (px, py, ntl, cap) int32, -1 padding,
              mask   (px, py, ntl, cap) float32 1/0 validity — the physical
-             injection scale is gathered in-graph from sid).
+             injection scale is gathered in-graph from sid,
+             nnz    (px, py, ntl) host int32 live entries, slots 0..n-1).
     """
     px, py = plan.pgrid
     ntx, nty = geom.ntiles
@@ -593,7 +595,8 @@ def _pass_source_tables(plan: DistTBPlan, g, geom: TBPassGeom,
     if g is None:
         return (jnp.zeros((px, py, ntl, 1, 3), jnp.int32),
                 jnp.full((px, py, ntl, 1), -1, jnp.int32),
-                jnp.zeros((px, py, ntl, 1), jnp.float32))
+                jnp.zeros((px, py, ntl, 1), jnp.float32),
+                np.zeros((px, py, ntl), np.int32))
     bx, by = plan.block
     tx, ty = geom.tile
     hp = geom.halo
@@ -611,8 +614,8 @@ def _pass_source_tables(plan: DistTBPlan, g, geom: TBPassGeom,
         s, t = divmod(flat, ntl)
         return f"(shard {divmod(s, py)}, tile {t})"
 
-    _, slot, cap = tables_mod.pack_slots(pairs, px * py * ntl, cap,
-                                         "pass source table", tile_name)
+    fill, slot, cap = tables_mod.pack_slots(pairs, px * py * ntl, cap,
+                                            "pass source table", tile_name)
     coords = np.zeros((px, py, ntl, cap, 3), np.int32)
     sid = np.full((px, py, ntl, cap), -1, np.int32)
     mask = np.zeros((px, py, ntl, cap), np.float32)
@@ -624,7 +627,8 @@ def _pass_source_tables(plan: DistTBPlan, g, geom: TBPassGeom,
         coords[sx, sy, t, k] = (pts[p, 0] - ox, pts[p, 1] - oy, pts[p, 2])
         sid[sx, sy, t, k] = p
         mask[sx, sy, t, k] = 1.0
-    return jnp.asarray(coords), jnp.asarray(sid), jnp.asarray(mask)
+    return (jnp.asarray(coords), jnp.asarray(sid), jnp.asarray(mask),
+            fill.reshape(px, py, ntl))
 
 
 def _pass_receiver_tables(plan: DistTBPlan, receivers, geom: TBPassGeom,
@@ -638,7 +642,8 @@ def _pass_receiver_tables(plan: DistTBPlan, receivers, geom: TBPassGeom,
     (`tables.pack_slots`): None auto-sizes, a too-small cap raises naming
     the (shard, tile) and the required cap.  Returns (coords, weight) as
     sharded jnp arrays plus the host-side rid table `_combine_pass`
-    segment-sums partials with.
+    segment-sums partials with, and the (px, py, ntl) host int32 live
+    entries of each (shard, tile).
     """
     px, py = plan.pgrid
     ntx, nty = geom.ntiles
@@ -646,7 +651,8 @@ def _pass_receiver_tables(plan: DistTBPlan, receivers, geom: TBPassGeom,
     if receivers is None:
         return (jnp.zeros((px, py, ntl, 1, 3), jnp.int32),
                 jnp.zeros((px, py, ntl, 1), jnp.float32),
-                np.full((px, py, ntl, 1), -1, np.int32))
+                np.full((px, py, ntl, 1), -1, np.int32),
+                np.zeros((px, py, ntl), np.int32))
     idx = np.asarray(receivers.indices).reshape(-1, 3)
     w = np.asarray(receivers.weights, np.float64).reshape(-1)
     rids = np.repeat(np.arange(receivers.num, dtype=np.int32),
@@ -669,7 +675,7 @@ def _pass_receiver_tables(plan: DistTBPlan, receivers, geom: TBPassGeom,
         s, tt = divmod(fl, ntl)
         return f"(shard {divmod(s, py)}, tile {tt})"
 
-    _, slot, cap = tables_mod.pack_slots(
+    fill, slot, cap = tables_mod.pack_slots(
         list(zip(flat.tolist(), range(idx.shape[0]))), px * py * ntl, cap,
         "pass receiver table", tile_name)
     coords = np.zeros((px, py, ntl, cap, 3), np.int32)
@@ -682,7 +688,8 @@ def _pass_receiver_tables(plan: DistTBPlan, receivers, geom: TBPassGeom,
                                          idx[p, 2])
         weight[sx[p], sy[p], t[p], k] = w[p]
         rid[sx[p], sy[p], t[p], k] = rids[p]
-    return jnp.asarray(coords), jnp.asarray(weight), rid
+    return (jnp.asarray(coords), jnp.asarray(weight), rid,
+            fill.reshape(px, py, ntl))
 
 
 # ---------------------------------------------------------------------------
@@ -695,11 +702,12 @@ def _depth_tables(plan: DistTBPlan, T_depth: int,
     """Host-side owner-sharded source/receiver tables for one time-tile
     depth (main T or the nt % T remainder): per inner pass (the tile
     origins shift with the remaining depth d_out) `(coords, sid, mask,
-    rcoords, rweight, rid)`, and with overlap one more set for the split
-    first step (window = the whole exchanged block, one "tile" per
-    shard).  They depend only on geometry (g's affected points, block,
-    inner tile, halo), never on `params`: the param-dependent injection
-    scale is gathered in-graph."""
+    rcoords, rweight, counts, rid)`, with `counts` (px, py, ntl, 2) the
+    live (source, receiver) entries that bound the kernel's slot loops,
+    and with overlap one more set for the split first step (window = the
+    whole exchanged block, one "tile" per shard).  They depend only on
+    geometry (g's affected points, block, inner tile, halo), never on
+    `params`: the param-dependent injection scale is gathered in-graph."""
     bx, by = plan.block
     out = []
     geoms = list(_geoms(plan, T_depth))
@@ -709,10 +717,31 @@ def _depth_tables(plan: DistTBPlan, T_depth: int,
             halo=T_depth * plan.r_step, grid=(bx, by), tile=(bx, by),
             ntiles=(1, 1), include_halo=T_depth > 1))
     for geom in geoms:
-        sc, sid, smask = _pass_source_tables(plan, g, geom)
-        rc, rw, rid = _pass_receiver_tables(plan, receivers, geom)
-        out.append((sc, sid, smask, rc, rw, jnp.asarray(rid)))
+        sc, sid, smask, snnz = _pass_source_tables(plan, g, geom)
+        rc, rw, rid, rnnz = _pass_receiver_tables(plan, receivers, geom)
+        out.append((sc, sid, smask, rc, rw, np.stack([snnz, rnnz], -1),
+                    jnp.asarray(rid)))
     return tuple(out)
+
+
+def pass_slot_iters(plan: DistTBPlan, nt: int, tables) -> Dict[str, int]:
+    """Span attributes for the kernel's sparse slot loops over a sharded
+    propagate, from `_host_tables`' tables: `slot_iters`, the iterations
+    its inject and record loops run in every pass — each (shard, tile)'s
+    live entries, in each slab of each step (`stencil_tb.slab_steps`) —
+    and `slot_iters_at_cap`, the same over every slot of the caps.  The
+    split first step runs outside the kernel and counts none."""
+    nz = plan.grid_shape[2]
+    out = {"slot_iters": 0, "slot_iters_at_cap": 0}
+    for T_depth, tabs in tables.items():
+        tiles = nt // plan.T if T_depth == plan.T else 1
+        for geom, tab in zip(_geoms(plan, T_depth), tabs[plan.overlap:]):
+            slabs = tiles * ker.slab_steps(
+                _pass_spec(plan, geom, nz, 1, 1, jnp.float32))
+            caps = tab[0].shape[3] + tab[3].shape[3]
+            out["slot_iters"] += slabs * int(np.sum(tab[5]))
+            out["slot_iters_at_cap"] += slabs * tab[5].size // 2 * caps
+    return out
 
 
 def _host_tables(plan: DistTBPlan, nt: int, g, receivers, itemsize: int):
@@ -723,7 +752,8 @@ def _host_tables(plan: DistTBPlan, nt: int, g, receivers, itemsize: int):
         tables = {d: _depth_tables(plan, d, g, receivers)
                   for d in _tile_depths(plan, nt)}
         if _spans.active():
-            sp.set(**sharded_counts(plan, nt, itemsize))
+            sp.set(**sharded_counts(plan, nt, itemsize),
+                   **pass_slot_iters(plan, nt, tables))
     return tables
 
 
@@ -751,6 +781,7 @@ def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
                  P(plan.ax_x, plan.ax_y, None, None),
                  P(plan.ax_x, plan.ax_y, None, None),
                  P(plan.ax_x, plan.ax_y, None, None, None),
+                 P(plan.ax_x, plan.ax_y, None, None),
                  P(plan.ax_x, plan.ax_y, None, None)) * ntab
     in_specs = ((spec3,) * (ns + npar) + tab_specs + (P(None, None),
                                                       P(None)))
@@ -775,9 +806,9 @@ def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
         sblocks = args[:ns]
         ppads = args[ns:ns + npar]
         rest = args[ns + npar:]
-        tabs = [[a[0, 0] for a in rest[5 * i:5 * i + 5]]
+        tabs = [[a[0, 0] for a in rest[6 * i:6 * i + 6]]
                 for i in range(ntab)]
-        src_win, scale_vec = rest[5 * ntab:]
+        src_win, scale_vec = rest[6 * ntab:]
         dtype = sblocks[0].dtype
         # ONE deep exchange per depth-T tile (the whole point), per-field
         # depths, each into its frame
@@ -789,7 +820,7 @@ def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
         rec_outs = []
         off = 0
         if plan.overlap:
-            osc, osid, osmask, orc, orw = (a[0] for a in tabs.pop(0))
+            osc, osid, osmask, orc, orw, _ = (a[0] for a in tabs.pop(0))
             with _spans.annotate("halo.split_first_step", depth=h):
                 safe = jnp.maximum(osid, 0)
                 sv0 = (src_win[0][safe] * (scale_vec[safe] * osmask)
@@ -799,7 +830,7 @@ def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
                     pframe.depth, osc, sv0, orc, orw)
             rec_outs.append(rec1[None, None, None])
             off = 1
-        for ip, (geom, (isc, isid, ismask, irc, irw)) in enumerate(
+        for ip, (geom, (isc, isid, ismask, irc, irw, icnt)) in enumerate(
                 zip(geoms, tabs)):
             with _spans.annotate("halo.pass", idx=ip, T=geom.T,
                                  d_out=geom.d_out):
@@ -808,7 +839,7 @@ def _tile(plan: DistTBPlan, frame: _Frame, pframe: _Frame, T_depth: int,
                     isid, ismask, scale_vec, dtype)
                 state, parts = _run_pass(plan, geom, state, s_depth, ppads,
                                          pframe.depth, isc, sv, irc, irw,
-                                         interpret)
+                                         icnt, interpret)
             s_depth = (geom.d_out, geom.d_out)
             rec_outs.append(parts[None, None])
         state = tuple(a[s_depth[0]:s_depth[0] + bx,
@@ -892,14 +923,14 @@ def _propagate(plan: DistTBPlan, nt: int, state, params, g, tables,
 
     def run(T_depth, state, t0):
         tabs = tables[T_depth]
-        flat = [a for t in tabs for a in t[:5]]
+        flat = [a for t in tabs for a in t[:6]]
         win = jax.lax.dynamic_slice(src_dcmp, (t0, 0),
                                     (T_depth, src_dcmp.shape[1]))
         outs = _tile(plan, frames[T_depth], pframe, T_depth, interpret)(
             *state, *ppads, *flat, win, scale_vec)
         if nrec == 0:
             return tuple(outs[:ns]), jnp.zeros((T_depth, 0, nchan), dtype)
-        recs = [_combine_pass(p, t[5], nrec) for p, t in zip(outs[ns:], tabs)]
+        recs = [_combine_pass(p, t[6], nrec) for p, t in zip(outs[ns:], tabs)]
         return tuple(outs[:ns]), (recs[0] if len(recs) == 1
                                   else jnp.concatenate(recs, axis=0))
 

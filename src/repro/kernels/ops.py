@@ -48,6 +48,14 @@ def _dummy_tables(ntiles: int, T: int):
     return coords, vals
 
 
+def _live_counts(tab, ntiles: int) -> jnp.ndarray:
+    """(ntiles,) live entries a tile of `tab` holds: its `nnz`, zero for
+    a missing table."""
+    if tab is None:
+        return jnp.zeros((ntiles,), jnp.int32)
+    return jnp.asarray(tab.nnz, jnp.int32)
+
+
 def build_tables(spec: ker.TBKernelSpec,
                  g: Optional[src_mod.GriddedSources],
                  receivers: Optional[src_mod.GriddedReceivers],
@@ -86,26 +94,35 @@ def build_tables(spec: ker.TBKernelSpec,
 
 
 def slot_fill(spec: ker.TBKernelSpec, nt: int, tables, rtables
-              ) -> Dict[str, list]:
+              ) -> Dict[str, object]:
     """Span attributes for the sparse terms' slot fill.  `tables` is the
     (src_tab, rec_tab) pair of the depth-`spec.T` tiles, serving
     `(nt // T)·T` steps; `rtables` the remainder tile's, serving
     `nt % T`.  Per pair: live entries (the tables' host-side `nnz`), the
-    `n_tiles x cap` slots the kernel computes every step, and the steps.
-    A missing table stands for the one-slot dummy the kernel computes
-    over in its place."""
+    `n_tiles x cap` slots of the tables, and the steps.  A missing table
+    stands for a one-slot dummy with no live entry.
+
+    Per propagate: `slot_iters`, the iterations the kernel's inject and
+    record loops run — every tile's live entries, in each slab of each
+    step (`stencil_tb.slab_steps`) — and `slot_iters_at_cap`, the same
+    over every slot of the caps, which is what loops bounded by the caps
+    would run."""
     ntx, nty = spec.ntiles
     rem = nt % spec.T
     out = {k: [] for k in ("src_live", "src_slots", "rec_live",
                            "rec_slots", "steps")}
-    for pair, steps in ((tables, nt - rem), (rtables, rem)):
+    out.update(slot_iters=0, slot_iters_at_cap=0)
+    for pair, T, steps in ((tables, spec.T, nt - rem), (rtables, rem, rem)):
         if steps == 0:
             continue
+        slabs = steps // T * ker.slab_steps(dataclasses.replace(spec, T=T))
         for kind, tab in zip(("src", "rec"), pair):
             live = 0 if tab is None else int(np.sum(tab.nnz))
             cap = 1 if tab is None else tab.coords.shape[1]
             out[kind + "_live"].append(live)
             out[kind + "_slots"].append(ntx * nty * cap)
+            out["slot_iters"] += slabs * live
+            out["slot_iters_at_cap"] += slabs * ntx * nty * cap
         out["steps"].append(steps)
     return out
 
@@ -189,12 +206,14 @@ def _run_time_tile_impl(spec, physics, state, param_pads, src_dcmp,
         r_coords = jnp.zeros((ntiles, 1, 3), jnp.int32)
         r_w = jnp.zeros((ntiles, 1), jnp.float32)
     r_w = r_w.astype(spec.dtype)
+    counts = jnp.stack([_live_counts(src_tab, ntiles),
+                        _live_counts(rec_tab, ntiles)], axis=1)
 
     with jax.named_scope("ops.state_pad"):
         state_pads = tuple(_pad_xy(f, h, "constant") for f in state)
     new_state, rec_part = ker.tb_time_tile(
         spec, physics, state_pads, param_pads, s_coords, s_vals,
-        r_coords, r_w, interpret=interpret)
+        r_coords, r_w, counts, interpret=interpret)
     if rec_tab is not None:
         with jax.named_scope("ops.rec_combine"):
             rec = combine_rec_partials(rec_part, rec_tab, nrec)
@@ -312,13 +331,6 @@ def tb_propagate_prepared(physics: phys.TBPhysics, nt: int,
     return carry, recs
 
 
-def _device_tables(tab):
-    """A table as the jitted propagate takes it: its device arrays, without
-    the host-side `nnz` the kernel never reads (which would otherwise be
-    copied to the device on every call)."""
-    return None if tab is None else tab._replace(nnz=None)
-
-
 def _prepare(physics: phys.TBPhysics, nt: int,
              state: Tuple[jnp.ndarray, ...],
              params: Dict[str, jnp.ndarray],
@@ -371,8 +383,7 @@ def _prepare(physics: phys.TBPhysics, nt: int,
             sp.set(**slot_fill(spec, nt, (src_tab, rec_tab),
                                (rsrc_tab, rrec_tab)),
                    **update_counts(spec, nt))
-    tables = tuple(map(_device_tables, (src_tab, rec_tab, rsrc_tab,
-                                        rrec_tab)))
+    tables = (src_tab, rec_tab, rsrc_tab, rrec_tab)
     return ((physics, nt, spec, rspec, nrec),
             (tuple(state), tuple(params[f] for f in physics.param_fields),
              src_dcmp, tables))

@@ -29,7 +29,10 @@ Kernel layout
 
   inputs (ANY/HBM, manually DMA'd):   state fields then param fields,
                                       each padded by H = T*r_step
-  inputs (SMEM, one block per tile):  flattened source/receiver tables
+  inputs (SMEM, one block per tile):  flattened source/receiver tables,
+                                      then the tile's live (source,
+                                      receiver) counts, which bound its
+                                      slot loops
   outputs (blocked):                  per-state-field centre regions;
                                       receiver partials, one
                                       (T*rec_channels, capr) vector per
@@ -144,6 +147,12 @@ def step_slabs(spec: TBKernelSpec, k):
     return lo, hi, b, (hi - lo + b - 1) // b
 
 
+def slab_steps(spec: TBKernelSpec) -> int:
+    """Slabs one tile's call runs, over its T steps: the times the slot
+    loops run, each for the tile's live entries."""
+    return sum(step_slabs(spec, k)[3] for k in range(spec.T))
+
+
 def update_points(spec: TBKernelSpec) -> int:
     """Points one call's updates keep: over tiles and steps, slabs x b x
     wy x nz.  A plane the shifted last slab keeps again counts twice.  (A
@@ -151,11 +160,8 @@ def update_points(spec: TBKernelSpec) -> int:
     on the TPU the kernel's time follows the kept planes, PERF.md §5.)"""
     ntx, nty = spec.ntiles
     _, wy, wz = spec.window
-    planes = 0
-    for k in range(spec.T):
-        _, _, b, nslab = step_slabs(spec, k)
-        planes += nslab * b
-    return ntx * nty * planes * wy * wz
+    b = step_slabs(spec, 0)[2]
+    return ntx * nty * slab_steps(spec) * b * wy * wz
 
 
 def _domain_mask(spec: TBKernelSpec, ti, tj, x0, nplanes: int, box=None):
@@ -192,9 +198,9 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
 
     Ref layout (positional, in pallas_call order):
       inputs:  n_state + n_param HBM refs, then this tile's src_coords,
-               src_vals, rec_coords, rec_w rows, flattened (1, 1, n)
-               blocks in SMEM (+ the (4,) domain box in SMEM when
-               `has_box`)
+               src_vals, rec_coords, rec_w and counts rows, flattened
+               (1, 1, n) blocks in SMEM (+ the (4,) domain box in SMEM
+               when `has_box`)
       outputs: n_state centre refs, then rec partials (1, T*chan, capr)
       scratch: one VMEM window per HBM ref — a pair of them per state
                field, between which the step loop ping-pongs — then a DMA
@@ -223,10 +229,10 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
     ns = len(physics.state_fields)
     nw = physics.num_windows
     hbm = refs[:nw]
-    src_coords_ref, src_vals_ref, rec_coords_ref, rec_w_ref = \
-        refs[nw:nw + 4]
-    nin = nw + 4 + has_box
-    box = ([refs[nw + 4][i] for i in range(4)] if has_box else None)
+    src_coords_ref, src_vals_ref, rec_coords_ref, rec_w_ref, counts_ref = \
+        refs[nw:nw + 5]
+    nin = nw + 5 + has_box
+    box = ([refs[nw + 5][i] for i in range(4)] if has_box else None)
     out_refs = refs[nin:nin + ns]
     rec_out_ref = refs[nin + ns]
     wins = refs[nin + ns + 1:nin + ns + 1 + nw]
@@ -266,6 +272,10 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
     lanes = jax.lax.broadcasted_iota(jnp.int32, (spec.T * nch, spec.rec_cap),
                                      1)
     inj = [physics.state_fields.index(f) for f in physics.inject_fields]
+    # the tile's live slots are the prefix 0..n-1 of its table rows
+    # (`tables.pack_slots`): the slot loops run those alone
+    n_src = jnp.minimum(counts_ref[0, 0, 0], spec.src_cap)
+    n_rec = jnp.minimum(counts_ref[0, 0, 1], spec.rec_cap)
 
     # ---- T in-VMEM timesteps: step k reads slot k % 2, writes the other ----
     def step(k, carry):
@@ -295,7 +305,7 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
                 nxt[i][pl.ds(x0, b)] = v.astype(spec.dtype)
 
             # fused grid-aligned source injection (paper Listing 4/5 ->
-            # masked vector adds; padding slots carry val = 0)
+            # masked vector adds) over the tile's live sources
             def inject(p, c):
                 x = src_coords_ref[0, 0, 3 * p] - x0
 
@@ -310,7 +320,7 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
                         nxt[i][pl.ds(x0, b)] = nxt[i][pl.ds(x0, b)] + add
                 return c
 
-            jax.lax.fori_loop(0, spec.src_cap, inject, 0)
+            jax.lax.fori_loop(0, n_src, inject, 0)
 
             # fused receiver interpolation partials (paper Fig. 3b); a
             # plane recomputed by the shifted last slab is owned by the
@@ -338,7 +348,7 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
                     rec_out_ref[0] = acc
                 return c
 
-            jax.lax.fori_loop(0, spec.rec_cap, record, 0)
+            jax.lax.fori_loop(0, n_rec, record, 0)
             return carry
 
         jax.lax.fori_loop(0, nslab, slab, 0)
@@ -353,7 +363,7 @@ def _tb_kernel(spec: TBKernelSpec, physics: phys.TBPhysics,
 
 def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
                  state_pads, param_pads,
-                 src_coords, src_vals, rec_coords, rec_w,
+                 src_coords, src_vals, rec_coords, rec_w, counts,
                  *, origins=None, dom_box=None,
                  interpret: Optional[bool] = None):
     """One depth-T time tile over the whole grid (one pallas_call).
@@ -365,6 +375,12 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
       src_coords: (ntiles, cap, 3) window-local int32.
       src_vals:   (ntiles, T, cap) f32, scale folded in, 0 on padding.
       rec_coords: (ntiles, capr, 3); rec_w: (ntiles, capr).
+      counts:     (ntiles, 2) int32: each tile's live (source, receiver)
+                  entries, which fill slots 0..n-1 of its rows
+                  (`TileSourceTable.nnz`, `TileReceiverTable.nnz`).  The
+                  kernel's inject and record loops run that many
+                  iterations, so empty slots cost nothing; a slot past
+                  the count is never read.
       origins:    optional static (ox, oy) per state then param operand:
                   where that operand's (nx + 2H, ny + 2H) padded grid
                   starts inside it, so a larger array is read in place
@@ -384,23 +400,25 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
                   up to the tile, halo = inner_T * r_step).
       interpret:  None picks by backend (`platform.resolve_interpret`).
 
-    Each tile's rows of the four tables reach SMEM as flattened blocks:
-    the kernel reads them one scalar at a time, and SMEM pads only a
-    block's minor dimension.  Natively the call takes no batch axis:
-    Mosaic lowers none on the manually DMA'd (ANY) operands, so a caller
-    with many shots loops over them (`jax.lax.map`, as the survey engine
-    does) instead of `jax.vmap`.
+    Each tile's rows of the four tables and its counts reach SMEM as
+    flattened blocks: the kernel reads them one scalar at a time, and
+    SMEM pads only a block's minor dimension.  Natively the call takes
+    no batch axis: Mosaic lowers none on the manually DMA'd (ANY)
+    operands, so a caller with many shots loops over them
+    (`jax.lax.map`, as the survey engine does) instead of `jax.vmap`.
     Returns (new_states tuple, rec_partials) with fields (nx, ny, nz) and
     rec_partials (ntx, nty, T, capr, rec_channels).
     """
     ntx, nty = spec.ntiles
-    tables = (src_coords.shape, src_vals.shape, rec_coords.shape, rec_w.shape)
+    tables = (src_coords.shape, src_vals.shape, rec_coords.shape, rec_w.shape,
+              counts.shape)
     want = ((ntx * nty, spec.src_cap, 3), (ntx * nty, spec.T, spec.src_cap),
-            (ntx * nty, spec.rec_cap, 3), (ntx * nty, spec.rec_cap))
+            (ntx * nty, spec.rec_cap, 3), (ntx * nty, spec.rec_cap),
+            (ntx * nty, 2))
     if tables != want:
         raise ValueError(f"tile tables {tables} do not match the spec's "
                          f"{want}")
-    tables = (src_coords, src_vals, rec_coords, rec_w)
+    tables = (src_coords, src_vals, rec_coords, rec_w, counts)
     ns = len(physics.state_fields)
     nw = physics.num_windows
     wx, wy, wz = spec.window
@@ -428,7 +446,7 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
     # one flattened row per tile, so the SMEM block pads only its length
     rows = [t.astype(dt).reshape(ntx * nty, 1, -1)
             for t, dt in zip(tables, (jnp.int32, jnp.float32, jnp.int32,
-                                      jnp.float32))]
+                                      jnp.float32, jnp.int32))]
 
     rec_shape = (ntx * nty, spec.T * nch, spec.rec_cap)
     boxes = [jnp.asarray(dom_box, jnp.int32)] if has_box else []
@@ -464,17 +482,6 @@ def tb_time_tile(spec: TBKernelSpec, physics: phys.TBPhysics,
     )(*hbm, *rows, *boxes)
     rec = outs[ns].reshape(ntx, nty, spec.T, nch, spec.rec_cap)
     return tuple(outs[:ns]), jnp.swapaxes(rec, 3, 4)
-
-
-def acoustic_tb_time_tile(spec: TBKernelSpec, u0_pad, u1_pad, m_pad, damp_pad,
-                          src_coords, src_vals, rec_coords, rec_w,
-                          *, interpret: Optional[bool] = None):
-    """Acoustic wrapper kept for compatibility: returns
-    (u0', u1', rec_partials (ntx, nty, T, capr))."""
-    (u0n, u1n), rec = tb_time_tile(
-        spec, phys.ACOUSTIC, (u0_pad, u1_pad), (m_pad, damp_pad),
-        src_coords, src_vals, rec_coords, rec_w, interpret=interpret)
-    return u0n, u1n, rec[..., 0]
 
 
 def kernel_cost(spec: TBKernelSpec,

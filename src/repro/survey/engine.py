@@ -292,12 +292,17 @@ class SurveyEngine:
     def _stack_batch(self, preps: List[_ShotArrays], pad_to: int
                      ) -> _ShotArrays:
         """Stack per-shot pytrees along a new shot axis; partial batches
-        replicate the last shot with a ZEROED wavelet table (a silent
-        shot — its outputs are computed and discarded)."""
+        replicate the last shot's tables with no live entry (a silent
+        shot: the kernel's slot loops run no iteration for it, and its
+        outputs are computed and discarded)."""
         short = pad_to - len(preps)
         if short > 0:
-            null = preps[-1]._replace(
-                src_dcmp=jnp.zeros_like(preps[-1].src_dcmp))
+            def silent(tab):
+                return (None if tab is None
+                        else tab._replace(nnz=np.zeros_like(tab.nnz)))
+            last = preps[-1]
+            null = last._replace(**{f: silent(getattr(last, f)) for f in (
+                "src_tab", "rec_tab", "rsrc_tab", "rrec_tab")})
             preps = preps + [null] * short
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *preps)
 

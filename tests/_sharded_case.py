@@ -3,7 +3,7 @@ need four devices: `test_sharded_entry.py` runs this file in a subprocess
 with four forced host devices (XLA fixes the device count at its first
 use).  Each check prints one JSON line.
 
-    python tests/_sharded_case.py parity|counts
+    python tests/_sharded_case.py parity|padded|counts
 """
 import json
 import sys
@@ -22,6 +22,7 @@ from repro.kernels import ops, ref
 from repro.kernels import stencil_tb as ker
 from repro.kernels import tb_physics as phys
 from repro.launch import mesh as mesh_lib
+from repro.telemetry import spans as tsp
 
 SHAPE = (32, 32, 16)        # a (16, 16) block a shard on the 2x2 mesh
 ORDER = 4
@@ -84,6 +85,69 @@ def parity():
         "donated": all(a.is_deleted() for a in state),
         "shards": sorted({tuple(s.data.shape)
                           for s in d1.addressable_shards})}))
+
+
+def padded():
+    """The entry with every pass table's caps raised 4-fold over its
+    fullest (shard, tile), against the entry at the caps the tables need
+    and against the reference."""
+    mesh = mesh_lib.make_xy_mesh()
+    nt = 7
+    grid, dt, m, damp, g, gr = case(nt=nt)
+    plan = the_1024_class(mesh, dt)
+    shard = NamedSharding(mesh, P("data", "model", None))
+    zero = jnp.zeros(SHAPE, jnp.float32)
+    (r0, r1), rrec = ref.acoustic_reference(nt, zero, zero, m, damp, dt,
+                                            grid.spacing, ORDER, g=g,
+                                            receivers=gr)
+    params = {"m": jax.device_put(m, shard),
+              "damp": jax.device_put(damp, shard)}
+
+    def run():
+        state = tuple(jax.device_put(zero, shard) for _ in range(2))
+        (d0, d1), drec = H.sharded_propagate(plan, nt, state, params, g, gr)
+        return d0, d1, drec[..., 0]
+
+    want = run()
+    caps = []
+
+    def padding(build):
+        def padded_build(plan, points, geom, cap=None):
+            cap = 4 * build(plan, points, geom)[0].shape[3]
+            caps.append(cap)
+            return build(plan, points, geom, cap=cap)
+        return padded_build
+
+    H._pass_source_tables = padding(H._pass_source_tables)
+    H._pass_receiver_tables = padding(H._pass_receiver_tables)
+    got = run()
+    errs = {}
+    for name, dv, rv in zip(("u_prev", "u", "rec"), got, (r0, r1, rrec)):
+        scale = float(jnp.max(jnp.abs(rv)))
+        errs[name] = [float(jnp.max(jnp.abs(dv - rv))), scale]
+    print(json.dumps({
+        "errs": errs, "caps": caps,
+        "bitwise": [bool(np.array_equal(np.asarray(a), np.asarray(b)))
+                    for a, b in zip(got, want)]}))
+
+
+def _slot_iters(plan, nt, tables):
+    """(live, at the caps) slot-loop iterations of the Pallas passes,
+    counted slab by slab: each (shard, tile)'s live sources (sid >= 0)
+    and receivers (rid >= 0), in each slab of each step."""
+    live = at_cap = 0
+    for d in (plan.T, nt % plan.T):
+        for geom, tab in zip(H._geoms(plan, d), tables[d][plan.overlap:]):
+            spec = ops.pass_inner_spec(geom, SHAPE[2], ORDER, plan.dt,
+                                       plan.spacing, 1, 1, jnp.float32,
+                                       plan.physics)
+            sid, rid = np.asarray(tab[1]), np.asarray(tab[6])
+            n = (sid >= 0).sum(-1) + (rid >= 0).sum(-1)
+            for k in range(geom.T):
+                for _s in range(ker.step_slabs(spec, k)[3]):
+                    live += int(n.sum())
+                    at_cap += n.size * (sid.shape[-1] + rid.shape[-1])
+    return live, at_cap
 
 
 def _ppermutes(jaxpr, mesh, out):
@@ -164,12 +228,21 @@ def counts():
         brute = sum((_split_points(plan, d * plan.r_step)
                      + _kernel_points(plan, d)) * px * py
                     for d in (plan.T, nt % plan.T))
+        c = tsp.enable(jax_profiler=False)
+        try:
+            tables = H._host_tables(plan, nt, g, gr, 4)
+        finally:
+            tsp.disable()
+        (span,) = [r.attrs for r in c.records() if r.name == "halo.tables"]
         out[name] = {"ppermutes": nperm, "bytes": nbytes,
                      "update_points": brute,
                      "useful_points": int(np.prod(SHAPE)) * nt,
+                     "slot_iters": _slot_iters(plan, nt, tables),
+                     "span_slot_iters": [span["slot_iters"],
+                                         span["slot_iters_at_cap"]],
                      "counters": H.sharded_counts(plan, nt)}
     print(json.dumps(out))
 
 
 if __name__ == "__main__":
-    {"parity": parity, "counts": counts}[sys.argv[1]]()
+    {"parity": parity, "padded": padded, "counts": counts}[sys.argv[1]]()

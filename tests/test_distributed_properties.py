@@ -258,8 +258,11 @@ def test_property_pass_source_binning_matches_windows(seed, pgrid, T_steps,
     g = _fake_sources(pts)
     for geom in nested_pass_geometry(block, (8, 8), T_steps,
                                      min(inner_T, T_steps), r):
-        coords, sid, mask = (np.asarray(a) for a in
-                             H._pass_source_tables(plan, g, geom))
+        coords, sid, mask, nnz = (np.asarray(a) for a in
+                                  H._pass_source_tables(plan, g, geom))
+        # the live entries are each window's slots 0..nnz-1
+        assert (nnz == mask.sum(axis=-1)).all()
+        assert (mask == (np.arange(coords.shape[3]) < nnz[..., None])).all()
         ntx, nty = geom.ntiles
         d, hp = geom.d_out, geom.halo
         pad = 0 if geom.include_halo else hp

@@ -8,6 +8,9 @@ the same trapezoidal VMEM schedule that passes the acoustic parity suite
 and the coupled-field TTI references exactly, with sources and receivers
 active, across multiple time tiles and through the remainder-tile path.
 """
+import dataclasses
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -180,3 +183,126 @@ def test_multiphysics_kernel_cost():
     assert c["flops"] > c["useful_flops"] > 0
     ca = ker.kernel_cost(spec, phys.ACOUSTIC)
     assert c["hbm_bytes"] > ca["hbm_bytes"]  # elastic moves more data
+
+
+# ---------------------------------------------------------------------------
+# Slot loops bounded by each tile's live count
+# ---------------------------------------------------------------------------
+
+SLOT_SHAPE = (24, 12, 8)      # 4 x 2 tiles of (6, 6)
+
+
+def _slot_case(name, nt):
+    """State and model of `name` on SLOT_SHAPE, with one source near the
+    x = 0 face and receivers spread over the middle tiles: the tables
+    hold tiles with no live entry, with some, and (sized by cap=None) a
+    full one."""
+    physics = phys.PHYSICS[name]
+    if name == "acoustic":
+        grid, rng, vp, damp, dt, _, _ = _geometry(SLOT_SHAPE, 4, nt)
+        state = tuple(jnp.asarray(0.01 * rng.randn(*SLOT_SHAPE), jnp.float32)
+                      for _ in range(2))
+        params = {"m": jnp.asarray(1.0 / vp ** 2, jnp.float32),
+                  "damp": damp}
+
+        def reference(g, gr):
+            return ref.acoustic_reference(nt, *state, params["m"], damp, dt,
+                                          grid.spacing, 4, g=g,
+                                          receivers=gr)[1]
+    else:
+        setup = _elastic_setup if name == "elastic" else _tti_setup
+        grid, p, st, dt, _, _ = setup(shape=SLOT_SHAPE, nt=nt)
+        state = tuple(getattr(st, f) for f in physics.state_fields)
+        params = {f: getattr(p, f) for f in physics.param_fields}
+        oracle = (ref.elastic_reference if name == "elastic"
+                  else ref.tti_reference)
+
+        def reference(g, gr):
+            return oracle(nt, st, p, dt, grid.spacing, 4, g=g,
+                          receivers=gr)[1]
+    src = S.SparseOperator([[14.0, 33.0, 35.0]])
+    rec = S.SparseOperator([[72.0, 27.0, 41.0], [101.0, 36.0, 33.0],
+                            [113.0, 64.0, 42.0], [84.0, 58.0, 22.0]])
+    g = S.precompute(src, grid, 1e2 * S.ricker_wavelet(nt, dt, f0=12.0))
+    gr = S.precompute_receivers(rec, grid)
+    return physics, grid, dt, state, params, g, gr, reference
+
+
+def _run_prepared(physics, nt, state, params, g, gr, plan, dt, spacing,
+                  pad=1, silent=False):
+    """The jitted one-chip propagate on `_prepare`'s inputs, with every
+    table's caps raised `pad`-fold over its fullest tile, or (`silent`)
+    every tile's live count set to 0.  Returns (outputs, tables)."""
+    static, (st, prm, dcmp, tables) = ops._prepare(
+        physics, nt, state, params, g, gr, plan, 4, dt, spacing)
+    _, _, spec, rspec, nrec = static
+    if pad > 1:
+        specs, tables = [], ()
+        for s in (spec, rspec):
+            if s is not None:
+                s = dataclasses.replace(s, src_cap=pad * s.src_cap,
+                                        rec_cap=pad * s.rec_cap)
+                tables += ops.build_tables(s, g, gr, params, physics,
+                                           src_cap=s.src_cap,
+                                           rec_cap=s.rec_cap)
+            else:
+                tables += (None, None)
+            specs.append(s)
+        spec, rspec = specs
+    if silent:
+        tables = tuple(None if t is None else t._replace(
+            nnz=np.zeros_like(t.nnz)) for t in tables)
+    out = ops._tb_propagate_jit(physics, nt, spec, rspec, nrec, None, st,
+                                prm, dcmp, tables)
+    return out, tables
+
+
+@pytest.mark.parametrize("name", ["acoustic", "tti", "elastic"])
+def test_padded_caps_are_bit_identical(name):
+    """Tables padded to 4x their fullest tile give the bits of the tables
+    sized to it (cap=None), wavefields and traces, main and remainder
+    tiles: the slot loops run each tile's live entries and nothing
+    else, over tiles with none, some, and a full row of them; the
+    traces are the reference's."""
+    nt = 5
+    physics, grid, dt, state, params, g, gr, reference = _slot_case(name,
+                                                                    nt)
+    plan = _plan(physics, 4, (6, 6), 2)
+    want, tight = _run_prepared(physics, nt, state, params, g, gr, plan,
+                                dt, grid.spacing)
+    got, padded = _run_prepared(physics, nt, state, params, g, gr, plan,
+                                dt, grid.spacing, pad=4)
+    fills = set()
+    for t, p in zip(tight, padded):
+        cap = t.coords.shape[1]
+        assert p.coords.shape[1] == 4 * cap
+        np.testing.assert_array_equal(t.nnz, p.nnz)
+        fills |= {"none" if n == 0 else "full" if n == cap else "some"
+                  for n in t.nnz}
+    assert fills == {"none", "some", "full"}
+    assert float(np.max(np.abs(np.asarray(want[1])))) > 0.0
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    recs = np.asarray(want[1])
+    if physics.rec_channels == 1:
+        recs = recs[..., 0]
+    np.testing.assert_allclose(recs, np.asarray(reference(g, gr)),
+                               rtol=RTOL, atol=ATOL)
+
+
+def test_kernel_reads_no_slot_past_the_count():
+    """Live tables whose counts read 0 inject and record nothing: the
+    wavefields are those of a propagate with no sources or receivers,
+    and the traces are zero."""
+    nt = 5
+    physics, grid, dt, state, params, g, gr, _ = _slot_case("acoustic", nt)
+    plan = _plan(physics, 4, (6, 6), 2)
+    (got, recs), tables = _run_prepared(physics, nt, state, params, g, gr,
+                                        plan, dt, grid.spacing, silent=True)
+    assert all(np.sum(t.nnz) == 0 and t.coords.shape[1] > 1
+               for t in tables)
+    (want, _), _ = _run_prepared(physics, nt, state, params, None, None,
+                                 plan, dt, grid.spacing)
+    assert recs.shape == (nt, 4, 1) and not np.any(np.asarray(recs))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

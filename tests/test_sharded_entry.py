@@ -45,16 +45,34 @@ def test_sharded_entry_equals_reference():
     assert out["shards"] == [[16, 16, 16]]
 
 
+def test_sharded_padded_caps_equal_reference():
+    """The entry with every pass table's caps raised 4-fold over its
+    fullest (shard, tile) gives the bits of the entry at the caps its
+    tables need, and equals the plain reference within the tolerance
+    above: the kernel's slot loops run each tile's live entries alone."""
+    out = _case("padded")
+    assert out["caps"] and all(c >= 4 for c in out["caps"])
+    assert out["bitwise"] == [True, True, True]
+    for name, (err, scale) in out["errs"].items():
+        assert scale > 0.0, name
+        assert err <= 5e-4 * scale + 1e-6, (name, err, scale)
+
+
 @pytest.mark.parametrize("plan", ["flat", "nested"])
 def test_sharded_counters_equal_brute_force(plan):
     """`sharded_counts` against counts taken another way, over one main
     tile and one remainder tile, for the flat 1024^3 class and for nested
     passes after the split first step: the ppermutes and the bytes they
     send, from the traced program itself; the update points from the
-    kernel's slab loops and the split step's index boxes."""
+    kernel's slab loops and the split step's index boxes; the slot-loop
+    iterations of the `halo.tables` span, live and at the caps, from the
+    tables' live slots, slab by slab."""
     got = _case("counts")[plan]
     c = got["counters"]
     assert c["exchanges"] == got["ppermutes"] > 0
     assert c["exchange_bytes"] == got["bytes"] > 0
     assert sum(c["update_points"]) == got["update_points"]
     assert sum(c["useful_points"]) == got["useful_points"]
+    live, at_cap = got["slot_iters"]
+    assert got["span_slot_iters"] == [live, at_cap]
+    assert 0 < live < at_cap

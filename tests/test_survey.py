@@ -113,6 +113,50 @@ def test_batched_parity(physics_name):
         assert err <= 5e-4 * scale + 1e-6, (i, err, scale)
 
 
+@pytest.mark.parametrize("physics_name", ["acoustic", "tti", "elastic"])
+def test_null_padded_batch_equals_sequential(physics_name, monkeypatch):
+    """One shot in a batch of two: the null shot that pads it carries no
+    live table entry (its slot loops run no iteration), and the shot's
+    traces and wavefields, run at the bucket's worst-case caps, equal a
+    sequential propagate at the caps its own tables need (to rounding:
+    the two programs are compiled apart)."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+
+    grid, dt, params = _case(physics_name, n=8)
+    physics = phys.PHYSICS[physics_name]
+    plan = TBPlan(tile=(8, 8), T=2, radius=physics.step_radius(ORDER))
+    shot = _shot(grid, dt, 2, 4, seed=3)    # a bucket shape: no padding
+    engine = SurveyEngine(physics_name, grid, params, NT, dt, order=ORDER,
+                          plan=plan, plan_cache=PlanCache(), bucket_cap=2)
+    batches = []
+    stack = engine._stack_batch
+    monkeypatch.setattr(engine, "_stack_batch",
+                        lambda *a: batches.append(stack(*a)) or batches[-1])
+    result = engine.run([shot], return_wavefields=True)
+    (batch,) = batches
+    for tab in (batch.src_tab, batch.rec_tab, batch.rsrc_tab,
+                batch.rrec_tab):
+        nnz = np.asarray(tab.nnz)
+        assert nnz[0].sum() > 0 and nnz[1].sum() == 0
+
+    g = S.precompute(S.SparseOperator(shot.src_coords), grid, shot.wavelet)
+    gr = S.precompute_receivers(S.SparseOperator(shot.rec_coords), grid)
+    zero = tuple(jnp.zeros(grid.shape, jnp.float32)
+                 for _ in physics.state_fields)
+    state, recs = ops._tb_propagate(physics, NT, zero, params, g, gr, plan,
+                                    ORDER, dt, grid.spacing)
+    recs = np.asarray(recs)
+    if physics.rec_channels == 1:
+        recs = recs[..., 0]
+    for got, want in ((result.traces[0], recs),
+                      *zip(result.wavefields[0], map(np.asarray, state))):
+        scale = float(np.max(np.abs(want)))
+        assert got.shape == want.shape and scale > 0.0
+        assert float(np.max(np.abs(got - want))) <= 1e-5 * scale
+
+
 # ---------------------------------------------------------------------------
 # The amortization contract (acceptance criterion)
 # ---------------------------------------------------------------------------
@@ -241,6 +285,7 @@ def test_survey_spans_reach_trace_annotation(monkeypatch):
         assert t["rec_slots"] == [rec_cap] * 2
         assert all(0 < n <= src_cap for n in t["src_live"])
         assert all(0 < n <= rec_cap for n in t["rec_live"])
+        assert 0 < t["slot_iters"] <= t["slot_iters_at_cap"]
 
 
 def test_sharded_route_matches_map_route():

@@ -280,6 +280,18 @@ def test_ops_tables_slot_fill_hand_count(tiny_propagate):
         "rec_slots": [8, 8], "steps": [2, 1]}
 
 
+def test_ops_tables_slot_iters_hand_count(tiny_propagate):
+    c, _, _ = tiny_propagate
+    (t,) = [r for r in c.records() if r.name == "ops.tables"]
+    # main call (T 2, window 16 planes in x, slabs of 8): step 0 keeps
+    # planes [2, 14) in 2 slabs, step 1 [4, 12) in 1, so each of the 2
+    # tiles runs 3 slabs; the remainder (T 1, window 12) keeps [2, 10)
+    # in 1.  Live: (8 + 8) x 3 + (8 + 8) x 1; caps: 2 tiles x (8 + 4) x
+    # 3 + 2 tiles x (8 + 4) x 1
+    assert (t.attrs["slot_iters"], t.attrs["slot_iters_at_cap"]) == \
+        (16 * 3 + 16 * 1, 24 * 3 + 24 * 1)
+
+
 def test_sources_precompute_records_host_bytes(tiny_propagate):
     c, _, _ = tiny_propagate
     by = {r.name: r.attrs for r in c.records()}

@@ -82,17 +82,18 @@ def test_tb_time_tile_compiles_for_v5e(one_chip, monkeypatch, physics,
             arg(lead + (ntiles, SRC_CAP, 3), jnp.int32),
             arg(lead + (ntiles, plan.T, SRC_CAP)),
             arg(lead + (ntiles, REC_CAP, 3), jnp.int32),
-            arg(lead + (ntiles, REC_CAP)))
+            arg(lead + (ntiles, REC_CAP)),
+            arg(lead + (ntiles, 2), jnp.int32))
 
-    def tile(state, params, sc, sv, rc, rw):
-        def one(state, sc, sv, rc, rw):
+    def tile(state, params, sc, sv, rc, rw, n):
+        def one(state, sc, sv, rc, rw, n):
             return ker.tb_time_tile(spec, physics, state, params, sc, sv,
-                                    rc, rw, interpret=False)
+                                    rc, rw, n, interpret=False)
         if batch is None:
-            return one(state, sc, sv, rc, rw)
+            return one(state, sc, sv, rc, rw, n)
         # the survey engine maps the kernel over the shots of a
         # batch, with the model shared
-        return jax.lax.map(lambda a: one(*a), (state, sc, sv, rc, rw))
+        return jax.lax.map(lambda a: one(*a), (state, sc, sv, rc, rw, n))
 
     compiled = jax.jit(tile).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
